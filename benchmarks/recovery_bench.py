@@ -133,9 +133,12 @@ def scenario_sigkill(futures_seen: list) -> dict:
     snapshot+journal union with zero model evals and zero lost futures."""
     default, bad = _knobs()
     with tempfile.TemporaryDirectory() as td:
+        # the child tests persistence, not the device: pinned to the CPU,
+        # it never competes with this process for a chip
         proc = subprocess.Popen(
             [sys.executable, __file__, "--child", td],
-            stdout=subprocess.PIPE, text=True)
+            stdout=subprocess.PIPE, text=True,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"})
         writing = False
         assert proc.stdout is not None
         for line in proc.stdout:

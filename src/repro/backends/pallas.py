@@ -1,9 +1,13 @@
 """The ``pallas`` backend: the repo's Pallas TPU kernels (kernels.ops).
 
-On a TPU host the kernels run compiled; on CPU-only hosts they run in
-interpret mode (still jit-compiled, so post-warmup wall-clock is meaningful
-for calibration at small scales).  The mode is auto-detected and can be
-forced via the constructor.
+On a TPU the kernels run compiled; where JAX's backend is the CPU they run
+in interpret mode (still jit-compiled, so post-warmup wall-clock is
+meaningful for calibration at small scales).  The mode is decided on the
+first execution, not at construction: building the backend (which happens
+when ``repro.backends`` is imported) must not initialise a JAX backend and
+claim the chip.  Any other platform is refused, and a failed backend
+initialisation propagates — it never silently becomes interpret mode.  The
+mode can be forced via the constructor.
 """
 
 from __future__ import annotations
@@ -17,12 +21,26 @@ from .base import Backend
 __all__ = ["PallasBackend"]
 
 
-def _host_has_tpu() -> bool:
-    try:
-        import jax
-        return any(d.platform == "tpu" for d in jax.devices())
-    except Exception:
-        return False
+def _interpret_mode() -> bool:
+    """Compiled on a TPU, interpreted on the CPU; raises on anything else.
+
+    JAX starts the TPU quietly: when ``JAX_PLATFORMS`` does not name the
+    platforms, a failed TPU start (the chip held by another process, say)
+    is only recorded, and the default backend becomes the CPU.  That CPU is
+    refused here, so a host whose chip failed never runs the interpreter.
+    """
+    import jax
+    from jax._src import xla_bridge
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(f"the pallas backend runs on a TPU (compiled) or "
+                           f"the CPU (interpret mode), not on {platform!r}")
+    if platform == "cpu" and "tpu" in xla_bridge._backend_errors:
+        raise RuntimeError(
+            f"the TPU failed to start ({xla_bridge._backend_errors['tpu']}); "
+            f"set JAX_PLATFORMS=cpu to run the pallas kernels in interpret "
+            f"mode on purpose")
+    return platform == "cpu"
 
 
 class PallasBackend(Backend):
@@ -31,8 +49,14 @@ class PallasBackend(Backend):
     jit_stacked = True          # vmap compiles per (shape, width)
 
     def __init__(self, *, interpret: bool | None = None) -> None:
-        self.interpret = (not _host_has_tpu()) if interpret is None \
-            else interpret
+        self._interpret = interpret
+
+    @property
+    def interpret(self) -> bool:
+        """Whether the kernels run in interpret mode (decided on first use)."""
+        if self._interpret is None:
+            self._interpret = _interpret_mode()
+        return self._interpret
 
     def knob_space(self, op: str, *,
                    sizes: tuple[int, ...] | None = None) -> KnobSpace:
@@ -53,13 +77,15 @@ class PallasBackend(Backend):
     def execute(self, op: str, operands: tuple, knob: Knob | None = None,
                 **kw):
         from repro.kernels.ops import PALLAS_OPS
-        kw.setdefault("interpret", self.interpret)
+        if "interpret" not in kw:
+            kw["interpret"] = self.interpret
         return PALLAS_OPS[op](*operands, knob=knob, **kw)
 
     def execute_stacked(self, op: str, operands: tuple,
                         knob: Knob | None = None, **kw):
         from repro.kernels.ops import PALLAS_OPS
-        kw.setdefault("interpret", self.interpret)
+        if "interpret" not in kw:
+            kw["interpret"] = self.interpret
         # the kernels take the leading batch axis natively — it becomes the
         # leading (parallel) grid dimension of ONE pallas_call, replacing
         # the old jax.vmap lift; the knob decision still runs once at trace

@@ -16,11 +16,10 @@ __all__ = ["time_callable", "median_time"]
 
 
 def _block(x) -> None:
-    try:
-        import jax
-        jax.block_until_ready(x)
-    except Exception:
-        pass
+    # a failed device computation raises here: a timing must never quietly
+    # measure the enqueue instead (leaves that are not jax arrays pass)
+    import jax
+    jax.block_until_ready(x)
 
 
 def time_callable(fn: Callable[[], object], *, warmup: int = 1,

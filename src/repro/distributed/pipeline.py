@@ -15,21 +15,10 @@ GPipe schedule runs S + M - 1 ticks for M microbatches; bubble fraction
 from __future__ import annotations
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                       # older jax: pre-promotion API
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep → check_vma across jax
-_CHECK_KW = ("check_vma"
-             if "check_vma" in inspect.signature(_shard_map).parameters
-             else "check_rep")
 
 __all__ = ["gpipe_forward", "bubble_fraction"]
 
@@ -53,10 +42,10 @@ def gpipe_forward(stage_fn, params_per_stage, x, *, mesh: Mesh,
     micro = x.reshape(n_microbatches, mb, *x.shape[1:])
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        **{_CHECK_KW: False})
+        check_vma=False)
     def run(stage_params, micro_all):
         stage_params = jax.tree.map(lambda t: t[0], stage_params)
         sid = jax.lax.axis_index(stage_axis)

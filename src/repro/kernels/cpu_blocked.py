@@ -164,7 +164,8 @@ def make_operands(op: str, dims: tuple[int, ...], dtype=np.float32,
         m, n = dims
         a = rand(m, m)
         if op == "trsm":  # diagonally dominant → well-conditioned solve
-            a = a + m * np.eye(m, dtype=dtype)
+            # (cast back: numpy promotes bfloat16 + int-scaled eye to f32)
+            a = (a + m * np.eye(m, dtype=dtype)).astype(dtype)
         return (a, rand(m, n))
     raise ValueError(op)
 

@@ -34,9 +34,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._batching import with_batch_axis
-from ._compat import CompilerParams
 
-__all__ = ["gemm_pallas"]
+__all__ = ["gemm_pallas", "mxu_dot", "compiler_params"]
+
+#: scoped VMEM a kernel may use.  The compiler's default (16 MiB) is too
+#: little for the full-f32 contraction of 512-blocks (syr2k's two
+#: accumulating products need 16.2 MiB); a v5e core has 128 MiB.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+
+def compiler_params(semantics) -> pltpu.CompilerParams:
+    """Mosaic parameters shared by every kernel of this package."""
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def mxu_dot(a, b):
+    """``a @ b`` accumulated in float32 on the MXU.  Two float32 operands
+    are contracted at full float32 precision: Mosaic's default contracts
+    them in one bf16 pass, a relative error near 2e-3 at k=2048 on a TPU
+    v5e, which is not an SGEMM.  Other operands keep the default (Mosaic
+    refuses full precision for a mixed f32 x bf16 product)."""
+    precision = (jax.lax.Precision.HIGHEST
+                 if a.dtype == b.dtype == jnp.float32 else None)
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=precision)
 
 
 def mask_cols(x, block: int, step, dim: int):
@@ -74,7 +96,7 @@ def _gemm_kernel(*refs, alpha, beta, k, bk, has_c, off, shared_b):
         # undefined, and 0 * garbage is still garbage when garbage is NaN)
         a = mask_cols(a, bk, l, k)
         b = mask_rows(b, bk, l, k)
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(a, b)
 
     @pl.when(l == pl.num_programs(off + 2) - 1)
     def _flush():
@@ -128,6 +150,6 @@ def gemm_pallas(a, b, c=None, *, bm: int = 128, bk: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec(out_block, out_map),
         out_shape=jax.ShapeDtypeStruct(out_shape, a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(dimension_semantics=semantics),
+        compiler_params=compiler_params(semantics),
         interpret=interpret,
     )(*operands)

@@ -29,8 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._batching import with_batch_axis
-from ._compat import CompilerParams
-from .gemm import mask_cols, mask_rows
+from .gemm import compiler_params, mask_cols, mask_rows, mxu_dot
 
 __all__ = ["symm_pallas"]
 
@@ -56,7 +55,7 @@ def _symm_kernel(*refs, alpha, beta, m, bm, has_c, off):
         # ragged contraction tail (the contraction dim of symm is m itself)
         a = mask_cols(a, bm, l, m)
         b = mask_rows(b, bm, l, m)
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(a, b)
 
     @pl.when(l == pl.num_programs(off + 2) - 1)
     def _flush():
@@ -103,6 +102,6 @@ def symm_pallas(a, b, c=None, *, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec(out_block, out_map),
         out_shape=jax.ShapeDtypeStruct(out_shape, a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(dimension_semantics=semantics),
+        compiler_params=compiler_params(semantics),
         interpret=interpret,
     )(*operands)

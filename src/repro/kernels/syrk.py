@@ -40,8 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._batching import with_batch_axis
-from ._compat import CompilerParams
-from .gemm import mask_cols
+from .gemm import compiler_params, mask_cols, mxu_dot
 
 __all__ = ["syrk_pallas", "syr2k_pallas", "detri", "tri_count"]
 
@@ -101,13 +100,10 @@ def _rank_k_kernel(*refs, alpha, beta, k, bk, tri, two, has_c, off):
             if k % bk:
                 b_i = mask_cols(b_i, bk, l, k)
                 b_j = mask_cols(b_j, bk, l, k)
-            acc_ref[...] += jnp.dot(a_i, b_j.T,
-                                    preferred_element_type=jnp.float32)
-            acc_ref[...] += jnp.dot(b_i, a_j.T,
-                                    preferred_element_type=jnp.float32)
+            acc_ref[...] += mxu_dot(a_i, b_j.T)
+            acc_ref[...] += mxu_dot(b_i, a_j.T)
         else:
-            acc_ref[...] += jnp.dot(a_i, a_j.T,
-                                    preferred_element_type=jnp.float32)
+            acc_ref[...] += mxu_dot(a_i, a_j.T)
 
     @pl.when(l == pl.num_programs(off + 2) - 1)
     def _flush():
@@ -161,13 +157,10 @@ def _rank_k_packed_kernel(*refs, alpha, beta, k, bk, nk, two, has_c, off):
             if k % bk:
                 b_i = mask_cols(b_i, bk, l, k)
                 b_j = mask_cols(b_j, bk, l, k)
-            acc_ref[...] += jnp.dot(a_i, b_j.T,
-                                    preferred_element_type=jnp.float32)
-            acc_ref[...] += jnp.dot(b_i, a_j.T,
-                                    preferred_element_type=jnp.float32)
+            acc_ref[...] += mxu_dot(a_i, b_j.T)
+            acc_ref[...] += mxu_dot(b_i, a_j.T)
         else:
-            acc_ref[...] += jnp.dot(a_i, a_j.T,
-                                    preferred_element_type=jnp.float32)
+            acc_ref[...] += mxu_dot(a_i, a_j.T)
 
     @pl.when(l == nk - 1)
     def _flush():
@@ -264,7 +257,7 @@ def _rank_k_call(a, b, c, *, bm, bk, alpha, beta, variant, interpret, two):
         out_specs=pl.BlockSpec(out_block, out_map),
         out_shape=jax.ShapeDtypeStruct(out_shape, a.dtype),
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(dimension_semantics=semantics),
+        compiler_params=compiler_params(semantics),
         interpret=interpret,
     )(*ops_)
     if variant == "tri":

@@ -37,8 +37,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._batching import with_batch_axis
-from ._compat import CompilerParams
-from .gemm import mask_cols, mask_rows
+from .gemm import compiler_params, mask_cols, mask_rows, mxu_dot
 from .syrk import detri, tri_count
 
 __all__ = ["trmm_pallas"]
@@ -71,7 +70,7 @@ def _trmm_kernel(a_ref, b_ref, o_ref, acc_ref, *, alpha, m, bm, tri, off):
         a = _tril_block(a, i, l, m, bm)
         if m % bm:
             b = mask_rows(b, bm, l, m)
-        acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+        acc_ref[...] += mxu_dot(a, b)
 
     @pl.when(l == pl.num_programs(off + 2) - 1)
     def _flush():
@@ -98,7 +97,7 @@ def _trmm_packed_kernel(a_ref, b_ref, o_ref, acc_ref, *, alpha, m, bm, off):
     a = _tril_block(a, i, l, m, bm)
     if m % bm:
         b = mask_rows(b, bm, l, m)
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(a, b)
 
     @pl.when(l == i)
     def _flush():
@@ -149,6 +148,6 @@ def trmm_pallas(a, b, *, bm: int = 128, bn: int = 128, alpha: float = 1.0,
         out_specs=pl.BlockSpec(out_block, out_map),
         out_shape=jax.ShapeDtypeStruct(out_shape, a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(dimension_semantics=semantics),
+        compiler_params=compiler_params(semantics),
         interpret=interpret,
     )(a, b)

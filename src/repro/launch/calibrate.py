@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.backends import get_backend
 from repro.core import (ModelRegistry, install_subroutine)
+from repro.launch.compile_cache import enable_compile_cache
 
 PRECISIONS = {"s": np.float32, "d": np.float64}
 DEFAULT_BACKEND = "cpu_blocked"
@@ -102,6 +103,7 @@ def main(argv=None) -> None:
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
 
+    enable_compile_cache()
     out = Path(args.out)
     out.mkdir(parents=True, exist_ok=True)
     sizes = tuple(int(s) for s in args.sizes.split(","))

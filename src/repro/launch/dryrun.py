@@ -152,8 +152,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
         hlo = compiled.as_text()
     coll = parse_collectives(hlo)
     chips = int(np.prod(list(mesh.shape.values())))
-    if isinstance(cost, (list, tuple)):   # older jax returns [dict]
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     mf = model_flops(cfg, shape, _active_params(cfg))

@@ -21,12 +21,29 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import (decode_step, init_decode_state, init_params,
                           prefill)
 from repro.models.transformer import _run_encoder
 from repro.models.layers import Ctx
 
-__all__ = ["ServeSession", "main"]
+__all__ = ["ServeSession", "init_serving_params", "main"]
+
+
+def init_serving_params(cfg, *, seed: int = 0, mesh=None, rules=None):
+    """Random parameters for serving, initialised under ``jit`` so no host
+    or float32 copy of a stacked per-layer leaf is materialised.  Stored in
+    ``cfg.param_dtype``; on a ``mesh`` each leaf is placed by
+    :func:`repro.launch.specs.param_specs` under ``rules``."""
+    def init():
+        return init_params(jax.random.PRNGKey(seed), cfg)
+
+    if mesh is None:
+        return jax.jit(init)()
+    from repro.launch.specs import param_specs, tree_shardings
+    shapes = jax.eval_shape(init)
+    shardings = tree_shardings(shapes, param_specs(shapes, rules, mesh), mesh)
+    return jax.jit(init, out_shardings=shardings)()
 
 
 @dataclasses.dataclass
@@ -48,11 +65,11 @@ class ServeSession:
                                            rules=self.rules, enc_out=e),
             donate_argnums=(2,), static_argnums=())
 
-    def generate(self, prompts: np.ndarray, *, max_new: int = 32,
-                 temperature: float = 0.0, seed: int = 0,
-                 frames: np.ndarray | None = None,
-                 vision: np.ndarray | None = None) -> np.ndarray:
-        """prompts: (B, S_prompt) int32 → (B, max_new) int32."""
+    def prefill(self, prompts: np.ndarray, *,
+                frames: np.ndarray | None = None,
+                vision: np.ndarray | None = None):
+        """prompts: (B, S_prompt) int32 → (last-token logits (B, 1, V),
+        filled caches, encoder output or None)."""
         cfg = self.cfg
         B = prompts.shape[0]
         caches = init_decode_state(cfg, B, self.max_len,
@@ -65,6 +82,15 @@ class ServeSession:
         if cfg.family == "vlm":
             batch["vision"] = jnp.asarray(vision)
         logits, caches = self._prefill(self.params, batch, caches)
+        return logits, caches, enc_out
+
+    def generate(self, prompts: np.ndarray, *, max_new: int = 32,
+                 temperature: float = 0.0, seed: int = 0,
+                 frames: np.ndarray | None = None,
+                 vision: np.ndarray | None = None) -> np.ndarray:
+        """prompts: (B, S_prompt) int32 → (B, max_new) int32."""
+        logits, caches, enc_out = self.prefill(prompts, frames=frames,
+                                               vision=vision)
         key = jax.random.PRNGKey(seed)
         out = []
         tok = self._sample(logits[:, -1], temperature, key)
@@ -93,8 +119,12 @@ def main(argv=None):
     p.add_argument("--temperature", type=float, default=0.0)
     args = p.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    # parameters held in the compute dtype: a full-width 4B model then
+    # takes 8 GB instead of 16, and fits one 16 GB chip
+    cfg = dataclasses.replace(cfg, param_dtype=cfg.compute_dtype)
+    params = init_serving_params(cfg)
     sess = ServeSession(cfg=cfg, params=params,
                         max_len=args.prompt_len + args.max_new + 8)
     rng = np.random.default_rng(0)

@@ -29,6 +29,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.data import SyntheticLMDataset, make_global_batch
 from repro.distributed import (PreemptionGuard, RetryPolicy,
                                StragglerDetector, best_mesh)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import batch_axes
 from repro.launch.specs import (abstract_train_state, param_specs,
                                 rules_for, tree_shardings)
@@ -153,6 +154,7 @@ def main(argv=None):
     p.add_argument("--max-retries", type=int, default=2)
     args = p.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     dataset = SyntheticLMDataset(vocab=cfg.vocab, seq_len=args.seq,
                                  global_batch=args.batch)

@@ -83,10 +83,15 @@ def mesh_axis_size(mesh: Mesh, axes: Sequence[str]) -> int:
 def logical_spec(rules: ShardingRules, mesh: Mesh,
                  names: Sequence[str | None],
                  dims: Sequence[int] | None = None) -> P:
-    """PartitionSpec from logical names; non-divisible dims replicate."""
+    """PartitionSpec from logical names; non-divisible dims replicate.
+
+    A mesh axis shards at most one positional dim: an axis already taken by
+    an earlier dim is dropped from later ones (e.g. FSDP's 'data' on a
+    weight's embed dim after the batch dim of a gathered activation)."""
     parts = []
+    used: set[str] = set()
     for i, name in enumerate(names):
-        axes = rules.axes_for(name)
+        axes = tuple(a for a in rules.axes_for(name) if a not in used)
         if not axes:
             parts.append(None)
             continue
@@ -101,6 +106,7 @@ def logical_spec(rules: ShardingRules, mesh: Mesh,
             if not axes:
                 parts.append(None)
                 continue
+        used.update(axes)
         parts.append(axes if len(axes) > 1 else axes[0])
     # trailing Nones can be dropped but keep explicit for readability
     return P(*parts)

@@ -152,8 +152,6 @@ def _compile_cost(fn, args, mesh) -> dict:
         compiled = jax.jit(fn).lower(*args).compile()
         ca = compiled.cost_analysis()
         coll = parse_collectives(compiled.as_text())
-    if isinstance(ca, (list, tuple)):   # older jax returns [dict]
-        ca = ca[0] if ca else {}
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),
             "coll": float(coll["total_operand_bytes"]),

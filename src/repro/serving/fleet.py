@@ -54,7 +54,18 @@ from repro.serving.service import (BlasService, ExecutionFailedError,
                                    ServeConfig, _resolve_exc,
                                    _resolve_result)
 
-__all__ = ["FleetConfig", "FleetService", "ExecutorDiedError"]
+__all__ = ["FleetConfig", "FleetService", "ExecutorDiedError",
+           "ExecutorBackendError"]
+
+
+#: backends whose kernels belong on the chip: an executor (a CPU process)
+#: refuses them instead of running the Pallas interpreter
+DEVICE_BACKENDS = ("pallas",)
+
+
+class ExecutorBackendError(RuntimeError):
+    """An executor was asked to run a device backend; it runs on the CPU
+    only.  Reaches callers inside :class:`ExecutionFailedError`."""
 
 
 class ExecutorDiedError(RuntimeError):
@@ -124,7 +135,22 @@ def _executor_main(conn, spec: dict) -> None:
     from the shared snapshot + journal — so the parent's measured window
     never includes jax import or model load time.
     """
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # Executors are CPU processes, whatever the host: a chip belongs to one
+    # process at a time, and a child that asks for one its parent holds
+    # fails or hangs.  JAX read JAX_PLATFORMS when it was imported (under
+    # spawn, by re-importing the parent's __main__; under fork, in the
+    # parent), so the pin goes through jax.config before any backend starts.
+    # Device backends are refused per request below.
+    import jax
+    from jax._src import xla_bridge
+    os.environ["JAX_PLATFORMS"] = "cpu"       # for processes this one starts
+    if not xla_bridge.backends_are_initialized():
+        jax.config.update("jax_platforms", "cpu")
+    platform = jax.default_backend()
+    if platform != "cpu":                     # forked from a device holder
+        conn.send(("refused", f"executor runs on {platform!r}, not the CPU: "
+                              f"use mp_context='spawn'"))
+        return
     from repro.core.runtime import AdsalaRuntime
 
     rt = AdsalaRuntime(cache_size=int(spec.get("cache_size", 256)))
@@ -132,7 +158,8 @@ def _executor_main(conn, spec: dict) -> None:
     membership = None
     member = str(spec.get("member", f"executor-{os.getpid()}"))
     info: dict = {"pid": os.getpid(), "member": member, "loaded": 0,
-                  "warm_started": 0, "resolution": {}}
+                  "warm_started": 0, "resolution": {}, "platform": platform,
+                  "jax_platforms": jax.config.jax_platforms}
     root = spec.get("registry_root")
     if root:
         from repro.core.registry import ModelRegistry, host_fingerprint
@@ -166,7 +193,7 @@ def _executor_main(conn, spec: dict) -> None:
 
     def stats() -> dict:
         s = rt.stats
-        return {"pid": os.getpid(), "member": member,
+        return {"pid": os.getpid(), "member": member, "platform": platform,
                 "model_evals": s.model_evals, "cache_hits": s.cache_hits,
                 "calls": s.calls, "default_calls": s.default_calls,
                 "journal_absorbed": s.journal_absorbed,
@@ -207,6 +234,11 @@ def _executor_main(conn, spec: dict) -> None:
                 continue
             _, _, op, backend, columns, kw, width = msg
             try:
+                if backend in DEVICE_BACKENDS:
+                    raise ExecutorBackendError(
+                        f"fleet executors run on the CPU; backend "
+                        f"{backend!r} needs the chip (serve it in-process "
+                        f"with BlasService)")
                 # absorb BEFORE selecting: a peer may have decided this
                 # very shape — that is the zero-eval fleet warm path
                 absorb()
@@ -253,7 +285,8 @@ class _Executor:
         tag, payload = self.conn.recv()
         if tag != "ready":
             self.kill()
-            raise ExecutorDiedError(f"{name}: bad handshake {tag!r}")
+            raise ExecutorDiedError(f"{name}: bad handshake {tag!r}: "
+                                    f"{payload}")
         self.ready_info = payload
 
     def alive(self) -> bool:

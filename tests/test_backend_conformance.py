@@ -92,4 +92,17 @@ def test_oracle_self_consistent(op):
 
 
 def test_tolerances_are_per_dtype():
-    assert tolerance_for(np.float64) < tolerance_for(np.float32)
+    import jax.numpy as jnp
+    assert tolerance_for(np.float64) < tolerance_for(np.float32) \
+        < tolerance_for(jnp.bfloat16)
+
+
+@pytest.mark.parametrize("op", L3_OPS)
+@pytest.mark.parametrize("dtype", ("bfloat16", "float32", "float64"))
+def test_operands_have_the_requested_dtype(op, dtype):
+    """A mixed pair (trsm's diagonal shift once promoted a bf16 ``a`` to
+    f32) would test a different kernel than the one asked for."""
+    import jax.numpy as jnp
+    want = jnp.dtype(dtype)
+    operands = get_backend("ref").make_operands(op, DEFAULT_DIMS[op], want)
+    assert [x.dtype for x in operands] == [want] * len(operands)

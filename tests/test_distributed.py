@@ -27,6 +27,9 @@ def _run(body: str, timeout=600) -> dict:
         import jax
         import jax.numpy as jnp
         import numpy as np
+        from jax.sharding import AxisType
+        from repro.launch.mesh import make_host_mesh
+        AUTO = AxisType.Auto
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = _SRC
@@ -47,7 +50,7 @@ def test_sharded_train_step_dp_tp():
         from repro.optim import AdamWConfig
         import tempfile
         import jax
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh(data=4, model=2)
         cfg = get_smoke_config("llama3-8b")
         ds = SyntheticLMDataset(vocab=cfg.vocab, seq_len=32, global_batch=8)
         loop = TrainLoop(cfg=cfg, adamw=AdamWConfig(total_steps=8),
@@ -73,7 +76,7 @@ def test_moe_expert_parallel_runs_sharded():
         from repro.models import init_params, loss_fn
         from repro.launch.specs import rules_for
         import dataclasses, jax
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh(data=2, model=4)
         cfg = get_smoke_config("granite_moe_3b")   # 8 experts over 4-way EP
         rules = rules_for(mesh, "train")
         params = init_params(jax.random.PRNGKey(0), cfg)
@@ -96,7 +99,7 @@ def test_elastic_checkpoint_reshard_8_to_2():
         import tempfile, jax
         import numpy as np
         devs = jax.devices()
-        mesh8 = jax.make_mesh((8,), ("data",))
+        mesh8 = jax.make_mesh((8,), ("data",), axis_types=(AUTO,))
         x = jax.device_put(np.arange(64, dtype=np.float32).reshape(8, 8),
                            NamedSharding(mesh8, P("data", None)))
         ck = Checkpointer(tempfile.mkdtemp())
@@ -119,7 +122,7 @@ def test_gpipe_pipeline_matches_sequential():
         from repro.distributed import gpipe_forward, bubble_fraction
         import functools, jax
         import numpy as np
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = jax.make_mesh((4,), ("stage",), axis_types=(AUTO,))
         S, B, D = 4, 8, 16
         rng = np.random.default_rng(0)
         Ws = jnp.asarray(rng.standard_normal((S, D, D)) / np.sqrt(D),
@@ -160,7 +163,7 @@ def test_grad_compression_reduces_collective_operand_dtype():
                                  init_error_feedback, compress_decompress)
         from repro.launch.specs import rules_for
         import jax
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_host_mesh(data=8, model=1)
         cfg = get_smoke_config("qwen1.5-4b")
         rules = rules_for(mesh, "train")
         params = init_params(jax.random.PRNGKey(0), cfg)

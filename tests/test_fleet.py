@@ -330,6 +330,35 @@ def test_fleet_round_trip_and_close(fleet_cls):
 
 
 @pytestmark_slow
+def test_fleet_executor_refuses_device_backend(fleet_cls, monkeypatch):
+    """Executors are CPU processes: each pins JAX to the CPU itself (the
+    parent's environment here does not), a pallas bucket fails typed instead
+    of running the Pallas interpreter, and the executor keeps serving."""
+    FleetService, FleetConfig = fleet_cls
+    from repro.serving import ExecutionFailedError, ServeConfig
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    svc = FleetService(
+        fleet=FleetConfig(processes=1, membership=False),
+        config=ServeConfig(backend="cpu_blocked", max_batch=1,
+                           linger_ms=1.0))
+    try:
+        info = svc._executors[0].ready_info
+        assert (info["platform"], info["jax_platforms"]) == ("cpu", "cpu")
+        assert [d["platform"] for d in svc.fleet_stats()] == ["cpu"]
+        a = np.eye(16, dtype=np.float32)
+        fut = svc.submit("gemm", (a, a), backend="pallas")
+        with pytest.raises(ExecutionFailedError,
+                           match="ExecutorBackendError"):
+            fut.result(timeout=120)
+        ok = svc.submit("gemm", (a, a))
+        np.testing.assert_allclose(ok.result(timeout=120), a, atol=1e-5)
+        assert svc.stats.failed == 1 and svc.stats.completed == 1
+        assert svc.stats.fallback_executions == 0
+    finally:
+        svc.close()
+
+
+@pytestmark_slow
 def test_fleet_executor_death_respawns_and_requeues(fleet_cls):
     FleetService, FleetConfig = fleet_cls
     from repro.serving import ServeConfig

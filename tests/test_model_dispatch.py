@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.backends import resolve_backend
+from repro.backends import conformance, resolve_backend
 from repro.configs import get_smoke_config
 from repro.core.oracle import oracle_time
 from repro.core.registry import ModelRegistry
@@ -149,7 +149,11 @@ def test_run_op_stacked_shared_weight():
     a = jax.random.normal(jax.random.PRNGKey(0), (4, 17, 64))
     b = jax.random.normal(jax.random.PRNGKey(1), (64, 48))
     got = ops.run_op("gemm", (a, b), interpret=True)
-    assert jnp.array_equal(got, a @ b)   # k=64 ≤ 128 → bitwise
+    # the float32 reference's accumulation order is XLA's, not ours: hold
+    # the kernel to the float64 oracle within the f32 conformance tolerance
+    want = conformance.oracle("gemm", (np.asarray(a), np.asarray(b)))
+    assert conformance.rel_err(got, want) < conformance.tolerance_for(
+        np.float32)
 
 
 def test_run_op_stacked_both_batched():

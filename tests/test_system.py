@@ -60,6 +60,8 @@ def test_measured_speedup_vs_default_on_holdout(tuned_gemm):
     # dims ≥96 keep op time ≳10× the eval time — below that regime the
     # memo cache is the amortiser (see EXPERIMENTS.md Table VII note)
     dims_list = sample_dims(8, 3, lo=96, hi=256, seed=99)
+    # a median of 5 drops the runs a loaded host preempts (a median of 2
+    # is their mean)
     t_def = t_tuned = 0.0
     for drow in dims_list:
         dims = tuple(int(v) for v in drow)
@@ -69,10 +71,10 @@ def test_measured_speedup_vs_default_on_holdout(tuned_gemm):
         t_eval = time.perf_counter() - t0
         t_def += time_callable(
             lambda: run_blocked("gemm", operands, default), warmup=1,
-            repeats=2)
+            repeats=5)
         t_tuned += time_callable(
             lambda: run_blocked("gemm", operands, knob), warmup=1,
-            repeats=2) + t_eval
+            repeats=5) + t_eval
     agg = t_def / t_tuned
     # single-core CI timing is noisy; this guards against gross regressions
     assert agg > 0.7, f"aggregate speedup {agg:.2f} unexpectedly poor"
@@ -120,7 +122,7 @@ import jax
 import repro.launch.dryrun as dr
 import repro.launch.mesh as mesh_mod
 mesh_mod.make_production_mesh = \\
-    lambda *, multi_pod=False: jax.make_mesh((4, 2), ("data", "model"))
+    lambda *, multi_pod=False: mesh_mod.make_host_mesh(data=4, model=2)
 dr.make_production_mesh = mesh_mod.make_production_mesh
 import repro.configs as C
 small = C.get_smoke_config("llama3-8b")

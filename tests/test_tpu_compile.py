@@ -1,0 +1,138 @@
+"""Compile rehearsal for one TPU v5e chip, without the chip.
+
+The TPU compiler is installed with jaxlib and compiles for a described
+topology: it refuses what interpret mode accepts (unaligned slices, kernels
+that need more VMEM than a core has, programs that do not fit HBM).  These
+tests compile the Pallas kernels of the main path at real widths, and one
+whole routed qwen1.5-4b prefill step at full width, for ``v5e:2x2``'s first
+chip.  Nothing executes.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.knobs import Knob
+from repro.kernels import ops
+
+HBM_BYTES = 16e9      # one v5e chip (Google Cloud, "TPU v5e")
+
+#: (op, dtype, dims, bm, variant) — the six ops in both dtypes at 4096,
+#: every variant at 128- and 512-blocks, a ragged gemm
+KERNEL_CELLS = (
+    [(op, dt, 4096, 128, "full")
+     for op in ("gemm", "symm", "syrk", "syr2k", "trmm", "trsm")
+     for dt in ("float32", "bfloat16")]
+    + [(op, "float32", 4096, bm, v)
+       for op in ("syrk", "syr2k", "trmm")
+       for bm in (128, 512) for v in ("tri", "tri_packed")]
+    + [(op, "float32", 4096, 512, "full")
+       for op in ("gemm", "symm", "syrk", "syr2k", "trmm", "trsm")]
+    + [("gemm", "float32", (4000, 3000, 2500), 128, "full")]
+)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """The first chip, with JAX's persistent compilation cache off: an
+    entry written for a described device cannot be read back without it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _operand_shapes(op: str, dims) -> list[tuple[int, ...]]:
+    if op == "gemm":
+        m, k, n = (dims,) * 3 if isinstance(dims, int) else dims
+        return [(m, k), (k, n)]
+    return [(dims, dims)] * (1 if op == "syrk" else 2)
+
+
+def _knob(bm: int, variant: str) -> Knob:
+    return Knob(tuple(sorted({"bm": bm, "bk": bm, "bn": bm,
+                              "variant": variant}.items())))
+
+
+def _compile_kernel(one_chip, op, dtype, shapes, knob, **kw):
+    args = [jax.ShapeDtypeStruct(s, jnp.dtype(dtype), sharding=one_chip)
+            for s in shapes]
+    fn = jax.jit(lambda *x: ops.run_op(op, x, knob=knob, interpret=False,
+                                       **kw))
+    return fn.lower(*args).compile()
+
+
+@pytest.mark.parametrize(
+    "op,dtype,dims,bm,variant", KERNEL_CELLS,
+    ids=[f"{c[0]}-{c[1]}-{c[2] if isinstance(c[2], int) else 'ragged'}"
+         f"-b{c[3]}-{c[4]}" for c in KERNEL_CELLS])
+def test_kernel_compiles_for_v5e(one_chip, op, dtype, dims, bm, variant):
+    compiled = _compile_kernel(one_chip, op, dtype,
+                               _operand_shapes(op, dims), _knob(bm, variant))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_shaped_stacked_gemm_compiles_for_v5e(one_chip):
+    """(B, 1, d) activations against a shared (d, n) weight: one stacked
+    pallas_call (the routed decode step's projection)."""
+    compiled = _compile_kernel(one_chip, "gemm", "bfloat16",
+                               [(4, 1, 2560), (2560, 6912)],
+                               _knob(128, "full"), stacked=True)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_routed_qwen15_4b_prefill_fits_one_v5e(one_chip):
+    """The whole routed prefill step of qwen1.5-4b at its published width
+    and depth (bf16 weights, 4 x 128 prompt tokens), compiled from
+    ``jax.eval_shape`` shapes: the kernels compile and the program's
+    arguments, outputs and temporaries fit one chip's HBM."""
+    from repro.configs import get_config
+    from repro.models import init_decode_state, init_params, prefill
+
+    cfg = dataclasses.replace(get_config("qwen1.5-4b"),
+                              param_dtype="bfloat16", use_pallas_gemm=True,
+                              gemm_interpret=False)
+    assert (cfg.n_layers, cfg.d_model, cfg.vocab) == (40, 2560, 151936)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    caches = on_chip(jax.eval_shape(
+        lambda: init_decode_state(cfg, 4, 152, dtype=jnp.bfloat16)))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((4, 128), jnp.int32)})
+    step = jax.jit(lambda p, b, c: prefill(p, b, c, cfg),
+                   donate_argnums=(2,))
+    compiled = step.lower(params, batch, caches).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 7.5e9     # the bf16 weights
+    assert total < HBM_BYTES
