@@ -38,6 +38,10 @@ Hot-path design (this is the most-called code in the serving stack):
 * **select_many** batches the misses of several pending decisions sharing a
   subroutine into ONE fused feature-build + model-predict call — the
   serving layer routes bucket flushes through it.
+* **Model evaluations are traced.**  Each miss-path evaluation (one key, or
+  one fused ``select_many`` group) runs under the ``adsala.model_eval``
+  profiler span (``jax.profiler.TraceAnnotation``, inert without a profiler
+  session); hits open no span.
 * **Models can be hot-swapped while serving.**  :meth:`AdsalaRuntime.swap`
   replaces a subroutine's model, bumps its swap epoch, and invalidates its
   decision-cache entries in one critical section; miss-path evaluations
@@ -56,6 +60,8 @@ import dataclasses
 import sys
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from .fastpath import compile_predictor
 from .knobs import Knob
@@ -788,7 +794,9 @@ class AdsalaRuntime:
             self._faults.fire("predictor_eval", backend=key[0], op=key[1],
                               dtype_bytes=key[2], dims=key[3])
         t0 = time.perf_counter()
-        knob = fast.select(key[3]) if fast is not None else sub.select(key[3])
+        with TraceAnnotation("adsala.model_eval"):
+            knob = (fast.select(key[3]) if fast is not None
+                    else sub.select(key[3]))
         shard.count_eval(time.perf_counter() - t0)
         knob, store_ok = self._apply_quarantine(sub_key, knob)
         stored = False
@@ -947,10 +955,11 @@ class AdsalaRuntime:
                             op=sub_key[1], dtype_bytes=sub_key[2],
                             n=len(keys))
                     t0 = time.perf_counter()
-                    if fast is not None:
-                        knobs = fast.select_many([k[3] for k in keys])
-                    else:
-                        knobs = [sub.select(k[3]) for k in keys]
+                    with TraceAnnotation("adsala.model_eval"):
+                        if fast is not None:
+                            knobs = fast.select_many([k[3] for k in keys])
+                        else:
+                            knobs = [sub.select(k[3]) for k in keys]
                 except Exception:
                     # a failed fused evaluation degrades only its own group:
                     # the keys stay unresolved (callers treat None like the

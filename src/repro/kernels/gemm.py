@@ -111,14 +111,16 @@ def _gemm_kernel(*refs, alpha, beta, k, bk, has_c, off, shared_b):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "alpha",
-                                             "beta", "interpret"))
+                                             "beta", "interpret", "name"))
 def gemm_pallas(a, b, c=None, *, bm: int = 128, bk: int = 128, bn: int = 128,
                 alpha: float = 1.0, beta: float = 0.0,
-                interpret: bool = False):
+                interpret: bool = False, name: str = "gemm"):
     """alpha*A@B + beta*C for arbitrary (ragged) shapes; a leading batch
     axis executes as one batched grid.  A 2-D B against a batched A is
     treated as a weight shared across the stack (the model-serving linear:
-    ``(B, S, d) @ (d, n)`` with no host reshape)."""
+    ``(B, S, d) @ (d, n)`` with no host reshape).  ``name`` is the kernel's
+    name in the compiled program, which a profiler trace shows: callers
+    that use the gemm as a step of another op name that step."""
     *lead, m, k = a.shape
     k2, n = b.shape[-2:]
     assert k == k2, (a.shape, b.shape)
@@ -152,4 +154,5 @@ def gemm_pallas(a, b, c=None, *, bm: int = 128, bk: int = 128, bn: int = 128,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=compiler_params(semantics),
         interpret=interpret,
+        name=name,
     )(*operands)

@@ -11,6 +11,11 @@ Each op:
      pad-to-block-multiple path is gone).  Operands carrying a leading batch
      axis execute as one batched grid — one pallas_call per stack.
 
+Each call writes profiler spans (``jax.profiler.TraceAnnotation``, inert
+without a profiler session) on the device trace's clock: ``blas.run_op``
+around the whole call, ``adsala.select`` around the knob decision and
+``blas.launch`` around the enqueue of the jitted kernel program.
+
 The knob spaces used by install-time calibration live here too, so the tuner
 and the executor can never disagree about the candidate set.
 """
@@ -25,6 +30,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.knobs import Knob, KnobSpace, block_knob_space
 from repro.core.runtime import AdsalaRuntime, global_runtime
@@ -279,11 +285,19 @@ def _select(op: str, dims: tuple[int, ...], dtype,
         return knob
     rt = runtime if runtime is not None else global_runtime()
     batcher = _TRACE_BATCHER
-    if batcher is not None:
-        return batcher.select_or_default(rt, op, dims, DTYPE_BYTES(dtype),
-                                         default_knob(op), "pallas")
-    return rt.select_or_default(op, dims, DTYPE_BYTES(dtype),
-                                default_knob(op), backend="pallas")
+    with TraceAnnotation("adsala.select"):
+        if batcher is not None:
+            return batcher.select_or_default(rt, op, dims, DTYPE_BYTES(dtype),
+                                             default_knob(op), "pallas")
+        return rt.select_or_default(op, dims, DTYPE_BYTES(dtype),
+                                    default_knob(op), backend="pallas")
+
+
+def _launch(kernel, *args, **kw):
+    """Enqueue the jitted kernel program ``kernel(*args, **kw)`` (inside a
+    trace: stage it) under the ``blas.launch`` span."""
+    with TraceAnnotation("blas.launch"):
+        return kernel(*args, **kw)
 
 
 def _rup(v: int, b: int) -> int:
@@ -302,8 +316,8 @@ def gemm(a, b, c=None, *, alpha=1.0, beta=0.0, knob=None, runtime=None,
     kb = _select("gemm", (m, k, n), a.dtype, knob, runtime).dict
     bm, bk, bn = (min(kb["bm"], _rup(m, 128)), min(kb["bk"], _rup(k, 128)),
                   min(kb["bn"], _rup(n, 128)))
-    return gemm_pallas(a, b, c, bm=bm, bk=bk, bn=bn, alpha=alpha, beta=beta,
-                       interpret=interpret)
+    return _launch(gemm_pallas, a, b, c, bm=bm, bk=bk, bn=bn, alpha=alpha,
+                   beta=beta, interpret=interpret)
 
 
 def symm(a, b, c=None, *, alpha=1.0, beta=0.0, knob=None, runtime=None,
@@ -311,8 +325,8 @@ def symm(a, b, c=None, *, alpha=1.0, beta=0.0, knob=None, runtime=None,
     m, n = a.shape[-2], b.shape[-1]
     kb = _select("symm", (m, n), a.dtype, knob, runtime).dict
     bm, bn = min(kb["bm"], _rup(m, 128)), min(kb["bn"], _rup(n, 128))
-    return symm_pallas(a, b, c, bm=bm, bn=bn, alpha=alpha, beta=beta,
-                       interpret=interpret)
+    return _launch(symm_pallas, a, b, c, bm=bm, bn=bn, alpha=alpha,
+                   beta=beta, interpret=interpret)
 
 
 def syrk(a, c=None, *, alpha=1.0, beta=0.0, knob=None, runtime=None,
@@ -320,8 +334,8 @@ def syrk(a, c=None, *, alpha=1.0, beta=0.0, knob=None, runtime=None,
     n, k = a.shape[-2:]
     kb = _select("syrk", (n, k), a.dtype, knob, runtime).dict
     bm, bk = min(kb["bm"], _rup(n, 128)), min(kb["bn"], _rup(k, 128))
-    return syrk_pallas(a, c, bm=bm, bk=bk, alpha=alpha, beta=beta,
-                       variant=kb.get("variant", "full"), interpret=interpret)
+    return _launch(syrk_pallas, a, c, bm=bm, bk=bk, alpha=alpha, beta=beta,
+                   variant=kb.get("variant", "full"), interpret=interpret)
 
 
 def syr2k(a, b, c=None, *, alpha=1.0, beta=0.0, knob=None, runtime=None,
@@ -329,9 +343,9 @@ def syr2k(a, b, c=None, *, alpha=1.0, beta=0.0, knob=None, runtime=None,
     n, k = a.shape[-2:]
     kb = _select("syr2k", (n, k), a.dtype, knob, runtime).dict
     bm, bk = min(kb["bm"], _rup(n, 128)), min(kb["bn"], _rup(k, 128))
-    return syr2k_pallas(a, b, c, bm=bm, bk=bk, alpha=alpha, beta=beta,
-                        variant=kb.get("variant", "full"),
-                        interpret=interpret)
+    return _launch(syr2k_pallas, a, b, c, bm=bm, bk=bk, alpha=alpha,
+                   beta=beta, variant=kb.get("variant", "full"),
+                   interpret=interpret)
 
 
 def trmm(a, b, *, alpha=1.0, knob=None, runtime=None,
@@ -339,8 +353,8 @@ def trmm(a, b, *, alpha=1.0, knob=None, runtime=None,
     m, n = a.shape[-2], b.shape[-1]
     kb = _select("trmm", (m, n), a.dtype, knob, runtime).dict
     bm, bn = min(kb["bm"], _rup(m, 128)), min(kb["bn"], _rup(n, 128))
-    return trmm_pallas(a, b, bm=bm, bn=bn, alpha=alpha,
-                       variant=kb.get("variant", "full"), interpret=interpret)
+    return _launch(trmm_pallas, a, b, bm=bm, bn=bn, alpha=alpha,
+                   variant=kb.get("variant", "full"), interpret=interpret)
 
 
 def trsm(a, b, *, alpha=1.0, knob=None, runtime=None,
@@ -348,7 +362,8 @@ def trsm(a, b, *, alpha=1.0, knob=None, runtime=None,
     m, n = a.shape[-2], b.shape[-1]
     kb = _select("trsm", (m, n), a.dtype, knob, runtime).dict
     bm, bn = min(kb["bm"], _rup(m, 128)), min(kb["bn"], _rup(n, 128))
-    return trsm_pallas(a, b, bm=bm, bn=bn, alpha=alpha, interpret=interpret)
+    return _launch(trsm_pallas, a, b, bm=bm, bn=bn, alpha=alpha,
+                   interpret=interpret)
 
 
 #: the pallas-path executors (what the ``pallas`` backend dispatches to)
@@ -376,7 +391,23 @@ def run_op(op: str, operands: tuple, *, backend: str = "pallas",
     batched activations — the model-serving linear) broadcast across the
     stack without a host reshape or copy.  ``stacked`` forces the
     interpretation when auto-detection by rank is ambiguous.
+
+    The whole call runs under the ``blas.run_op`` profiler span; while a
+    profiler session is on, the span carries the op and its dims.
     """
+    if TraceAnnotation.is_enabled():
+        span = TraceAnnotation("blas.run_op", op=op, dims=dims_of(
+            op, tuple(x.shape for x in operands)))
+    else:
+        span = TraceAnnotation("blas.run_op")
+    with span:
+        return _run_op(op, operands, backend=backend, knob=knob,
+                       runtime=runtime, stacked=stacked, **kw)
+
+
+def _run_op(op: str, operands: tuple, *, backend: str,
+            knob: Optional[Knob], runtime: Optional[AdsalaRuntime],
+            stacked: Optional[bool], **kw):
     be = _backend_resolver()(backend)
     if stacked is None:
         stacked = getattr(operands[0], "ndim", 2) == 3
@@ -397,8 +428,10 @@ def run_op(op: str, operands: tuple, *, backend: str = "pallas",
     if knob is None:
         rt = runtime if runtime is not None else global_runtime()
         dims = dims_of(op, tuple(x.shape for x in operands))
-        knob = rt.select_or_default(op, dims, DTYPE_BYTES(operands[0].dtype),
-                                    be.default_knob(op), backend=be.name)
+        with TraceAnnotation("adsala.select"):
+            knob = rt.select_or_default(op, dims,
+                                        DTYPE_BYTES(operands[0].dtype),
+                                        be.default_knob(op), backend=be.name)
     if faults is not None:
         faults.fire("kernel_execute", backend=be.name, op=op,
                     stacked=bool(stacked), knob=knob)

@@ -104,4 +104,5 @@ def symm_pallas(a, b, c=None, *, bm: int = 128, bn: int = 128,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=compiler_params(semantics),
         interpret=interpret,
+        name="symm",
     )(*operands)

@@ -259,6 +259,7 @@ def _rank_k_call(a, b, c, *, bm, bk, alpha, beta, variant, interpret, two):
         scratch_shapes=scratch,
         compiler_params=compiler_params(semantics),
         interpret=interpret,
+        name=f"{'syr2k' if two else 'syrk'}_{variant}",
     )(*ops_)
     if variant == "tri":
         out = jnp.tril(out) + jnp.tril(out, -1).swapaxes(-1, -2)

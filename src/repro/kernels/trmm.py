@@ -150,4 +150,5 @@ def trmm_pallas(a, b, *, bm: int = 128, bn: int = 128, alpha: float = 1.0,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=compiler_params(semantics),
         interpret=interpret,
+        name=f"trmm_{variant}",
     )(a, b)

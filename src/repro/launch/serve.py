@@ -8,6 +8,12 @@ A minimal production-shaped server core: a request queue, batched prefill
 with greedy/temperature sampling and per-sequence stop handling.  The same
 ``prefill`` / ``decode_step`` functions are what the dry-run lowers for the
 ``prefill_32k`` / ``decode_32k`` / ``long_500k`` cells.
+
+The session writes profiler spans (``jax.profiler.TraceAnnotation``, inert
+without a profiler session) on the device trace's clock: ``serve.prefill``
+around a whole prefill, with ``serve.init_cache`` and ``serve.prefill_step``
+inside it; ``serve.decode_step`` around each decode step's dispatch; and
+``serve.sample`` around sampling.
 """
 
 from __future__ import annotations
@@ -19,6 +25,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_config, get_smoke_config
 from repro.launch.compile_cache import enable_compile_cache
@@ -46,6 +53,22 @@ def init_serving_params(cfg, *, seed: int = 0, mesh=None, rules=None):
     return jax.jit(init, out_shardings=shardings)()
 
 
+class _Spanned:
+    """A jitted step whose every call runs under the profiler span ``name``.
+    Any other attribute (``lower``, ``trace``, ...) is the jitted
+    function's own."""
+
+    def __init__(self, name: str, step) -> None:
+        self._name, self._step = name, step
+
+    def __call__(self, *args, **kw):
+        with TraceAnnotation(self._name):
+            return self._step(*args, **kw)
+
+    def __getattr__(self, attr):
+        return getattr(self._step, attr)
+
+
 @dataclasses.dataclass
 class ServeSession:
     cfg: object
@@ -56,33 +79,35 @@ class ServeSession:
 
     def __post_init__(self):
         cfg = self.cfg
-        self._prefill = jax.jit(
+        self._prefill = _Spanned("serve.prefill_step", jax.jit(
             lambda p, b, c: prefill(p, b, c, cfg, mesh=self.mesh,
                                     rules=self.rules),
-            donate_argnums=(2,))
-        self._decode = jax.jit(
+            donate_argnums=(2,)))
+        self._decode = _Spanned("serve.decode_step", jax.jit(
             lambda p, t, c, e: decode_step(p, t, c, cfg, mesh=self.mesh,
                                            rules=self.rules, enc_out=e),
-            donate_argnums=(2,), static_argnums=())
+            donate_argnums=(2,), static_argnums=()))
 
     def prefill(self, prompts: np.ndarray, *,
                 frames: np.ndarray | None = None,
                 vision: np.ndarray | None = None):
         """prompts: (B, S_prompt) int32 → (last-token logits (B, 1, V),
         filled caches, encoder output or None)."""
-        cfg = self.cfg
-        B = prompts.shape[0]
-        caches = init_decode_state(cfg, B, self.max_len,
-                                   dtype=jnp.dtype(cfg.compute_dtype))
-        batch = {"tokens": jnp.asarray(prompts)}
-        enc_out = None
-        if cfg.family == "audio":
-            batch["frames"] = jnp.asarray(frames)
-            enc_out = _run_encoder(self.params, batch["frames"], Ctx(cfg))
-        if cfg.family == "vlm":
-            batch["vision"] = jnp.asarray(vision)
-        logits, caches = self._prefill(self.params, batch, caches)
-        return logits, caches, enc_out
+        with TraceAnnotation("serve.prefill"):
+            cfg = self.cfg
+            B = prompts.shape[0]
+            with TraceAnnotation("serve.init_cache"):
+                caches = init_decode_state(cfg, B, self.max_len,
+                                           dtype=jnp.dtype(cfg.compute_dtype))
+            batch = {"tokens": jnp.asarray(prompts)}
+            enc_out = None
+            if cfg.family == "audio":
+                batch["frames"] = jnp.asarray(frames)
+                enc_out = _run_encoder(self.params, batch["frames"], Ctx(cfg))
+            if cfg.family == "vlm":
+                batch["vision"] = jnp.asarray(vision)
+            logits, caches = self._prefill(self.params, batch, caches)
+            return logits, caches, enc_out
 
     def generate(self, prompts: np.ndarray, *, max_new: int = 32,
                  temperature: float = 0.0, seed: int = 0,
@@ -103,10 +128,11 @@ class ServeSession:
 
     @staticmethod
     def _sample(logits, temperature, key):
-        if temperature <= 0.0:
-            return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-        return jax.random.categorical(
-            key, logits / temperature, axis=-1)[:, None].astype(jnp.int32)
+        with TraceAnnotation("serve.sample"):
+            if temperature <= 0.0:
+                return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+            return jax.random.categorical(
+                key, logits / temperature, axis=-1)[:, None].astype(jnp.int32)
 
 
 def main(argv=None):

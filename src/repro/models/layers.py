@@ -301,9 +301,12 @@ def attention(p: dict, x, ctx: Ctx, *, kv_x=None, causal: bool = True,
     B, S, _ = x.shape
     hd = cfg.hd()
     kv_in = x if kv_x is None else kv_x
-    q = linear(p["wq"], x, ctx).reshape(B, S, cfg.n_heads, hd)
-    k = linear(p["wk"], kv_in, ctx).reshape(B, kv_in.shape[1], cfg.kv_heads, hd)
-    v = linear(p["wv"], kv_in, ctx).reshape(B, kv_in.shape[1], cfg.kv_heads, hd)
+    with jax.named_scope("attn_qkv"):
+        q = linear(p["wq"], x, ctx).reshape(B, S, cfg.n_heads, hd)
+        k = linear(p["wk"], kv_in, ctx).reshape(B, kv_in.shape[1],
+                                                cfg.kv_heads, hd)
+        v = linear(p["wv"], kv_in, ctx).reshape(B, kv_in.shape[1],
+                                                cfg.kv_heads, hd)
     # head-parallel region: seq deliberately unsharded here (under SP rules
     # this boundary is the all-gather / reduce-scatter pair).  batch_attn
     # may span ('data','model') when heads don't divide the TP axis.
@@ -349,8 +352,9 @@ def attention(p: dict, x, ctx: Ctx, *, kv_x=None, causal: bool = True,
                               causal_skip=cfg.causal_skip,
                               unroll=cfg.unroll_attn)
     out = ctx.cons(out, "batch_attn", None, "heads", None)
-    out = linear(p["wo"], out.reshape(B, S, cfg.n_heads * hd), ctx,
-                 out_logical="embed")
+    with jax.named_scope("attn_out"):
+        out = linear(p["wo"], out.reshape(B, S, cfg.n_heads * hd), ctx,
+                     out_logical="embed")
     return (out, new_cache) if cache is not None else (out, None)
 
 
@@ -371,11 +375,15 @@ def init_mlp(key, d: int, d_ff: int, *, mlp_type: str = "swiglu",
 
 def mlp(p: dict, x, ctx: Ctx):
     if "wg" in p:
-        h = jax.nn.silu(linear(p["wg"], x, ctx, out_logical="mlp")) * \
-            linear(p["wu"], x, ctx, out_logical="mlp")
-        return linear(p["wd"], h, ctx, out_logical="embed")
-    h = jax.nn.gelu(linear(p["w1"], x, ctx, out_logical="mlp"))
-    return linear(p["w2"], h, ctx, out_logical="embed")
+        with jax.named_scope("mlp_gate_up"):
+            h = jax.nn.silu(linear(p["wg"], x, ctx, out_logical="mlp")) * \
+                linear(p["wu"], x, ctx, out_logical="mlp")
+        with jax.named_scope("mlp_down"):
+            return linear(p["wd"], h, ctx, out_logical="embed")
+    with jax.named_scope("mlp_gate_up"):
+        h = jax.nn.gelu(linear(p["w1"], x, ctx, out_logical="mlp"))
+    with jax.named_scope("mlp_down"):
+        return linear(p["w2"], h, ctx, out_logical="embed")
 
 
 # ---------------------------------------------------------------------------

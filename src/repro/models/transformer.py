@@ -322,7 +322,8 @@ def _logits(params, x, ctx: Ctx):
         w = ctx.cast(params["lm_head"]["w"])
     else:
         w = ctx.cast(params["embed"]["table"]).T
-    logits = routed_matmul(x, w, ctx)
+    with jax.named_scope("lm_head"):
+        logits = routed_matmul(x, w, ctx)
     return ctx.cons(logits, "batch", None, "vocab")
 
 
