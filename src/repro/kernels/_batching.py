@@ -9,10 +9,9 @@ their ``off`` parameter (grid-axis indices shift by one) and read/write
 ``ref[0]`` instead of ``ref[...]``.
 
 Operands *shared* across the stack (a 2-D weight against a batched
-activation — the model-serving linear) keep their original index map and
-block via the per-operand ``broadcast`` flags: every batch grid step reads
-the same weight tile, so the stack executes without materialising a
-broadcast copy of the weight.
+activation) keep their original index map and block via the per-operand
+``broadcast`` flags: every batch grid step reads the same weight tile, so
+the stack executes without materialising a broadcast copy of the weight.
 
 One implementation — gemm, symm, syrk/syr2k, and trmm all apply the same
 transformation, and a divergent copy would compile but mis-index.

@@ -117,10 +117,11 @@ def gemm_pallas(a, b, c=None, *, bm: int = 128, bk: int = 128, bn: int = 128,
                 interpret: bool = False, name: str = "gemm"):
     """alpha*A@B + beta*C for arbitrary (ragged) shapes; a leading batch
     axis executes as one batched grid.  A 2-D B against a batched A is
-    treated as a weight shared across the stack (the model-serving linear:
-    ``(B, S, d) @ (d, n)`` with no host reshape).  ``name`` is the kernel's
-    name in the compiled program, which a profiler trace shows: callers
-    that use the gemm as a step of another op name that step."""
+    treated as a weight shared across the stack: each stack item re-reads
+    it (a model's routed linear folds its batch into M instead).  ``name``
+    is the kernel's name in the compiled program, which a profiler trace
+    shows: callers that use the gemm as a step of another op name that
+    step."""
     *lead, m, k = a.shape
     k2, n = b.shape[-2:]
     assert k == k2, (a.shape, b.shape)

@@ -388,9 +388,9 @@ def run_op(op: str, operands: tuple, *, backend: str = "pallas",
     ``(m, k)``) execute as one stacked call via ``Backend.execute_stacked``
     — all items share dims/dtype, so a single knob decision covers the whole
     stack.  Trailing operands of one-lower rank (a shared 2-D weight against
-    batched activations — the model-serving linear) broadcast across the
-    stack without a host reshape or copy.  ``stacked`` forces the
-    interpretation when auto-detection by rank is ambiguous.
+    batched activations) broadcast across the stack without a host reshape
+    or copy.  ``stacked`` forces the interpretation when auto-detection by
+    rank is ambiguous.
 
     The whole call runs under the ``blas.run_op`` profiler span; while a
     profiler session is on, the span carries the op and its dims.

@@ -10,9 +10,13 @@ Contracts:
   * ``run_op``/the kernels take leading-batch activations *natively*
     (3-D a against a shared 2-D weight — no reshape-collapse, no per-item
     loop over copies);
+  * a routed matmul whose items have fewer rows than one row tile folds
+    every leading axis into the gemm's M: one 2-D ``run_op`` call, so a
+    decode step's ``(B, 1, d)`` activation reads each weight once, not
+    once per sequence;
   * the ahead-of-time harvest (``roofline.harvest``) sees every decision
     key the routed programs will request — including the skinny
-    ``(1, d, n)`` decode GEMMs — with zero model evaluations;
+    ``(B, d, n)`` decode GEMMs — with zero model evaluations;
   * install → ``select_many`` → ``save_decision_cache`` offline, then a
     fresh runtime hydrated from the registry serves prefill + decode with
     **zero** runtime model evaluations.
@@ -119,16 +123,59 @@ def test_routing_respects_config_gates():
     assert jnp.array_equal(routed_matmul(x, w, rctx), x @ w)
 
 
-def test_routed_matmul_high_rank_leading_batch():
-    """≥2 leading axes fold into one stack axis outside any jit loop."""
-    rcfg = dataclasses.replace(_cfg("qwen15_4b"), use_pallas_gemm=True)
+def _routed_ctx():
     from repro.models.sharding import DEFAULT_RULES
-    ctx = Ctx(rcfg, None, DEFAULT_RULES)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 3, 8, 64))
+    rcfg = dataclasses.replace(_cfg("qwen15_4b"), use_pallas_gemm=True,
+                               gemm_interpret=True)
+    return Ctx(rcfg, None, DEFAULT_RULES)
+
+
+#: (activation shape, the one gemm operand ``routed_matmul`` hands
+#: ``run_op``): items under one 128-row tile fold into M; items that fill
+#: row tiles stay one stack
+ROUTED_SHAPES = [((4, 1, 64), (4, 64)), ((2, 3, 8, 64), (48, 64)),
+                 ((2, 128, 64), (2, 128, 64)),
+                 ((2, 3, 128, 64), (6, 128, 64))]
+ROUTED_IDS = ["decode-3d", "fold-4d", "stack-3d", "stack-4d"]
+
+
+@pytest.mark.parametrize("shape,_", ROUTED_SHAPES, ids=ROUTED_IDS)
+def test_routed_matmul_high_rank_leading_batch(shape, _):
+    """Leading axes fold into M (or, for items that fill row tiles, into
+    one stack axis) outside any jit loop: the result matches ``x @ w`` bit
+    for bit (one k-tile) in interpret mode."""
+    x = jax.random.normal(jax.random.PRNGKey(0), shape)
     w = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
-    got = routed_matmul(x, w, ctx)
-    assert got.shape == (2, 3, 8, 32)
+    if shape[-2] >= 128:
+        # XLA's CPU dot sums a 128-row operand in another order than the
+        # kernel's tile: small integers make every order exact
+        x, w = jnp.round(4 * x), jnp.round(4 * w)
+    got = routed_matmul(x, w, _routed_ctx())
+    assert got.shape == shape[:-1] + (32,)
     assert jnp.array_equal(got, x @ w)
+
+
+@pytest.mark.parametrize("shape,operand", ROUTED_SHAPES, ids=ROUTED_IDS)
+def test_routed_matmul_is_one_gemm(monkeypatch, shape, operand):
+    """One ``run_op`` gemm a routed matmul: a ``(B, 1, d)`` decode
+    activation is a plain 2-D gemm of ``(B, d)`` rows against the shared
+    weight, never a stack of one-row items."""
+    calls = []
+    real = ops.run_op
+
+    def spy(op, operands, **kw):
+        calls.append((op, tuple(x.shape for x in operands), kw))
+        return real(op, operands, **kw)
+
+    monkeypatch.setattr(ops, "run_op", spy)
+    x = jax.random.normal(jax.random.PRNGKey(0), shape)
+    w = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
+    got = routed_matmul(x, w, _routed_ctx())
+    assert got.shape == shape[:-1] + (32,)
+    assert len(calls) == 1
+    op, shapes, kw = calls[0]
+    assert op == "gemm" and shapes == (operand, (64, 32))
+    assert "stacked" not in kw
 
 
 # ---------------------------------------------------------------------------
@@ -189,9 +236,11 @@ def test_harvest_covers_decode_gemms(arch):
     keys = harvest_decision_keys(cfg, batch_size=2, seq_len=16)
     assert keys, "routed model harvested no decision keys"
     assert all(k[0] == "pallas" and k[1] == "gemm" for k in keys)
-    # the skinny decode-step GEMMs (m = one token) must be present —
-    # missing them means the first decode pays a cold model eval
-    assert any(k[3][0] == 1 for k in keys)
+    # the skinny decode-step GEMMs (m = one token per sequence, folded:
+    # m = batch_size) must be present — missing them means the first
+    # decode pays a cold model eval.  The output head is left out: prefill's
+    # last-token head has the same key.
+    assert any(k[3][0] == 2 and k[3][2] != cfg.vocab for k in keys)
     # deterministic: same trace → same keys, no duplicates
     assert keys == harvest_decision_keys(cfg, batch_size=2, seq_len=16)
     assert len(set(keys)) == len(keys)

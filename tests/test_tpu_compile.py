@@ -98,10 +98,22 @@ def test_kernel_compiles_for_v5e(one_chip, op, dtype, dims, bm, variant):
 
 def test_decode_shaped_stacked_gemm_compiles_for_v5e(one_chip):
     """(B, 1, d) activations against a shared (d, n) weight: one stacked
-    pallas_call (the routed decode step's projection)."""
+    pallas_call (``run_op(..., stacked=True)``'s shared-weight path)."""
     compiled = _compile_kernel(one_chip, "gemm", "bfloat16",
                                [(4, 1, 2560), (2560, 6912)],
                                _knob(128, "full"), stacked=True)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k,n", [(2560, 6912), (6912, 2560)],
+                         ids=["up", "down"])
+def test_decode_shaped_folded_gemm_compiles_for_v5e(one_chip, k, n):
+    """The routed decode step's projection after the fold: 4 one-row
+    sequences as one (4, k) x (k, n) bf16 gemm at the installed decode
+    knob (bm 128, bk 128, bn 512)."""
+    knob = Knob((("bk", 128), ("bm", 128), ("bn", 512), ("variant", "full")))
+    compiled = _compile_kernel(one_chip, "gemm", "bfloat16",
+                               [(4, k), (k, n)], knob)
     assert "tpu_custom_call" in compiled.as_text()
 
 
