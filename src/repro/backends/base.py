@@ -31,13 +31,29 @@ from repro.core.features import SUBROUTINE_NDIMS
 from repro.core.knobs import Knob, KnobSpace
 from repro.core.timing import time_callable
 
-__all__ = ["Backend", "L3_OPS"]
+__all__ = ["Backend", "L3_OPS", "grouped_operands"]
 
 #: the six BLAS L3 subroutines of paper Table I
 L3_OPS = ("gemm", "symm", "syrk", "syr2k", "trmm", "trsm")
 
 #: dims used to rank candidate parallelism when picking the baseline knob
 _BASELINE_DIMS = (4096, 4096, 4096)
+
+
+def grouped_operands(dims: tuple[int, ...], dtype=np.float32,
+                     seed: int = 0) -> tuple:
+    """``(x (m, k), w (g, k, n), group_sizes (g,) int32)`` for the grouped
+    gemm at ``dims = (m, k, n, g)``: uneven group sizes from the seed (a
+    heavy-tailed share per group, about one group in eight empty, the rest
+    drawn multinomially), summing to m — a skewed MoE routing."""
+    m, k, n, g = dims
+    rng = np.random.default_rng(seed)
+    share = rng.pareto(1.5, g) + 0.05
+    if g > 1:
+        share[rng.permutation(g)[: max(1, g // 8)]] = 0.0
+    sizes = rng.multinomial(m, share / share.sum()).astype(np.int32)
+    return (rng.standard_normal((m, k)).astype(dtype),
+            rng.standard_normal((g, k, n)).astype(dtype), sizes)
 
 
 class Backend(abc.ABC):
@@ -117,6 +133,8 @@ class Backend(abc.ABC):
         """Random operands of the right shapes (calibration inputs).  Seeded
         identically across backends so cross-backend checks compare the same
         problem instance."""
+        if op == "grouped_gemm":
+            return grouped_operands(dims, dtype, seed)
         from repro.kernels.cpu_blocked import make_operands
         return make_operands(op, dims, dtype, seed)
 

@@ -73,6 +73,13 @@ def oracle(op: str, operands: tuple) -> np.ndarray:
     xs = [np.asarray(x, np.float64) for x in operands]
     if op == "gemm":
         return xs[0] @ xs[1]
+    if op == "grouped_gemm":          # rows sorted by group, one weight each
+        ends = np.cumsum(np.asarray(operands[2], np.int64))
+        out = np.zeros((xs[0].shape[0], xs[1].shape[2]))
+        for g, (lo, hi) in enumerate(zip(ends - np.asarray(operands[2]),
+                                         ends)):
+            out[lo:hi] = xs[0][lo:hi] @ xs[1][g]
+        return out
     if op == "symm":
         return _sym_lower(xs[0]) @ xs[1]
     if op == "syrk":

@@ -11,7 +11,21 @@ from __future__ import annotations
 import dataclasses
 from typing import Literal
 
-__all__ = ["ModelConfig", "Shape", "SHAPES", "shape_applicable"]
+__all__ = ["ModelConfig", "RopeScaling", "Shape", "SHAPES",
+           "shape_applicable"]
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling``, type "yarn"):
+    blended inverse frequencies, a cos/sin multiplier and a softmax-scale
+    factor, all from these published values (``models/layers.py:yarn``)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -28,6 +42,7 @@ class ModelConfig:
     head_dim: int = 0                 # 0 → d_model // n_heads
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    rope_scaling: RopeScaling | None = None   # None: plain rotary
     tie_embeddings: bool = False
     mlp_type: Literal["swiglu", "gelu"] = "swiglu"
 
@@ -37,7 +52,8 @@ class ModelConfig:
     n_shared_experts: int = 0
     moe_d_ff: int = 0                 # per-expert FFN width
     first_dense_layers: int = 0       # leading dense-FFN layers (deepseek)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # the sharded path's capacity slab
+    norm_topk_prob: bool = True       # renormalise the top-k router weights
 
     # --- MLA (deepseek) ------------------------------------------------------
     use_mla: bool = False

@@ -9,6 +9,11 @@ subroutine:
   2-dim (SYMM/SYRK/SYR2K/TRMM/TRSM):
                                m, n, nt, m*n, footprint,
                                m/nt, n/nt, m*n/nt, footprint/nt
+  4-dim (grouped GEMM, rows m over g groups, one (k, n) weight each):
+                               m, k, n, g, nt, m*k, m*n, k*n, g*k*n,
+                               m*k*n, footprint, m/nt, k/nt, n/nt,
+                               m*k/nt, m*n/nt, k*n/nt, m*k*n/nt,
+                               footprint/nt
 
 ``nt`` is the parallelism measure of the execution config (thread count on
 CPU; number of parallel Pallas grid cells on TPU — see DESIGN.md §2).
@@ -36,8 +41,11 @@ SUBROUTINE_NDIMS = {
     "syr2k": 2,
     "trmm": 2,
     "trsm": 2,
+    "grouped_gemm": 4,
 }
-SUBROUTINES = tuple(SUBROUTINE_NDIMS)
+#: the paper's six Level-3 subroutines (the grouped gemm is the model path's
+#: own op, with a feature row of its own)
+SUBROUTINES = ("gemm", "symm", "syrk", "syr2k", "trmm", "trsm")
 
 
 def footprint_words(op: str, dims: tuple[int, ...]) -> int:
@@ -45,6 +53,9 @@ def footprint_words(op: str, dims: tuple[int, ...]) -> int:
     if op == "gemm":
         m, k, n = dims
         return m * k + k * n + m * n
+    if op == "grouped_gemm":
+        m, k, n, g = dims
+        return m * k + g * (k * n) + m * n   # rows, every weight, out
     if op == "symm":
         m, n = dims
         return m * m + 2 * m * n           # A(mxm) + B(mxn) + C(mxn)
@@ -66,6 +77,9 @@ def footprint_words_vec(op: str, dims: np.ndarray) -> np.ndarray:
     if op == "gemm":
         m, k, n = d[:, 0], d[:, 1], d[:, 2]
         return m * k + k * n + m * n
+    if op == "grouped_gemm":
+        m, k, n, g = d[:, 0], d[:, 1], d[:, 2], d[:, 3]
+        return m * k + g * (k * n) + m * n
     a, b = d[:, 0], d[:, 1]
     if op == "symm":
         return a * a + 2 * a * b
@@ -77,6 +91,13 @@ def footprint_words_vec(op: str, dims: np.ndarray) -> np.ndarray:
 
 
 def feature_names(ndims: int) -> list[str]:
+    if ndims == 4:
+        return [
+            "m", "k", "n", "g", "nt",
+            "m*k", "m*n", "k*n", "g*k*n", "m*k*n", "footprint",
+            "m/nt", "k/nt", "n/nt",
+            "m*k/nt", "m*n/nt", "k*n/nt", "m*k*n/nt", "footprint/nt",
+        ]
     if ndims == 3:
         return [
             "m", "k", "n", "nt",
@@ -89,7 +110,7 @@ def feature_names(ndims: int) -> list[str]:
             "m", "n", "nt", "m*n", "footprint",
             "m/nt", "n/nt", "m*n/nt", "footprint/nt",
         ]
-    raise ValueError(f"ndims must be 2 or 3, got {ndims}")
+    raise ValueError(f"ndims must be 2, 3 or 4, got {ndims}")
 
 
 def build_features(op: str, dims: np.ndarray, nt: np.ndarray) -> np.ndarray:
@@ -104,7 +125,15 @@ def build_features(op: str, dims: np.ndarray, nt: np.ndarray) -> np.ndarray:
     ndims = SUBROUTINE_NDIMS[op]
     assert dims.shape[1] == ndims, (op, dims.shape)
     fp = footprint_words_vec(op, dims)
-    if ndims == 3:
+    if ndims == 4:
+        m, k, n, g = dims[:, 0], dims[:, 1], dims[:, 2], dims[:, 3]
+        cols = [
+            m, k, n, g, nt,
+            m * k, m * n, k * n, g * (k * n), m * k * n, fp,
+            m / nt, k / nt, n / nt,
+            m * k / nt, m * n / nt, k * n / nt, m * k * n / nt, fp / nt,
+        ]
+    elif ndims == 3:
         m, k, n = dims[:, 0], dims[:, 1], dims[:, 2]
         cols = [
             m, k, n, nt,
@@ -140,6 +169,15 @@ def _term_spec(op: str, d: tuple) -> tuple:
     association order, float64 throughout), so filled columns are
     bit-identical to the reference matrix's.
     """
+    if SUBROUTINE_NDIMS[op] == 4:
+        m, k, n, g = d
+        mk = m * k
+        mn = m * n
+        kn = k * n
+        mkn = mk * n
+        fp = mk + g * kn + mn
+        return (m, k, n, g, _NT, mk, mn, kn, g * kn, mkn, fp,
+                (m,), (k,), (n,), (mk,), (mn,), (kn,), (mkn,), (fp,))
     if SUBROUTINE_NDIMS[op] == 3:
         m, k, n = d
         mk = m * k

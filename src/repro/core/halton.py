@@ -22,6 +22,8 @@ __all__ = ["halton_sequence", "scrambled_halton", "sample_dims"]
 # for any integer base >= 2).
 BASES_3D = (2, 3, 4)
 BASES_2D = (2, 3)
+#: the grouped gemm's four free dims (m, k, n, g): the next primes
+BASES_4D = (2, 3, 5, 7)
 
 
 def _radical_inverse(indices: np.ndarray, base: int,
@@ -85,7 +87,7 @@ def sample_dims(
 
     Returns an (n, ndims) int64 array.
     """
-    bases = BASES_3D[:ndims] if ndims == 3 else BASES_2D[:ndims]
+    bases = {2: BASES_2D, 3: BASES_3D, 4: BASES_4D}[ndims]
     out = np.empty((0, ndims), dtype=np.int64)
     start = 1
     attempts = 0

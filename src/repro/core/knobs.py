@@ -23,7 +23,8 @@ from typing import Any, Sequence
 
 import numpy as np
 
-__all__ = ["Knob", "KnobSpace", "block_knob_space", "thread_knob_space"]
+__all__ = ["Knob", "KnobSpace", "block_knob_space", "grouped_knob_space",
+           "thread_knob_space"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,3 +141,23 @@ def block_knob_space(
         if vmem <= vmem_limit_bytes:
             cands.append({"bm": bm, "bk": bk, "bn": bn, "variant": var})
     return KnobSpace("blocks", cands, parallelism_fn=_grid_parallelism)
+
+
+def _grouped_parallelism(knob: Knob, dims: tuple[int, ...]) -> float:
+    """Grid cells of the grouped gemm (``kernels/grouped_gemm.py``) at dims
+    ``(m, k, n, g)``: row-tile visits times column tiles, with the visits at
+    their bound ``ceil(m/bm) + g - 1`` (each group boundary inside a tile
+    adds one).  The group sizes are data, not part of the key, so the bound
+    is what a decision can see."""
+    d = knob.dict
+    m, _, n, g = dims
+    return (math.ceil(m / d["bm"]) + g - 1) * math.ceil(n / d["bn"])
+
+
+def grouped_knob_space(sizes: Sequence[int] = (128, 256, 512)) -> KnobSpace:
+    """The grouped gemm's (bm, bk, bn) tiles: the gemm's candidates under
+    the name ``grouped_blocks``, whose parallelism is
+    :func:`_grouped_parallelism` (restored by that name on load)."""
+    space = block_knob_space(bms=sizes, bks=sizes, bns=sizes)
+    return KnobSpace("grouped_blocks", [k.dict for k in space],
+                     parallelism_fn=_grouped_parallelism)

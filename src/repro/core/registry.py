@@ -116,6 +116,9 @@ def load_subroutine(path: str | Path) -> TunedSubroutine:
     if knobs.name == "blocks":
         from .knobs import _grid_parallelism
         knobs._parallelism_fn = _grid_parallelism
+    elif knobs.name == "grouped_blocks":
+        from .knobs import _grouped_parallelism
+        knobs._parallelism_fn = _grouped_parallelism
     pipeline = PreprocessPipeline()
     pipeline.set_state(state["pipeline"])
     model = make_model(state["model_name"])

@@ -61,17 +61,28 @@ def mxu_dot(a, b):
                    precision=precision)
 
 
+def _where_below(ids, dim: int, x):
+    """``x`` where ``ids < dim``, else 0.  A packed (sub-32-bit) tile is
+    selected in float32: Mosaic cannot select a bf16 tile of fewer rows
+    than a packed sublane group ("Not implemented: Sublane broadcast", a
+    decode step's few-row gemms with k not a multiple of bk)."""
+    if x.dtype.itemsize < 4:
+        return jnp.where(ids < dim, x.astype(jnp.float32),
+                         0.0).astype(x.dtype)
+    return jnp.where(ids < dim, x, jnp.zeros_like(x))
+
+
 def mask_cols(x, block: int, step, dim: int):
     """Zero the columns of tile ``x`` whose global index (``step``-th block
     of width ``block``) falls at or beyond ``dim`` — the ragged tail mask."""
     ids = block * step + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    return jnp.where(ids < dim, x, jnp.zeros_like(x))
+    return _where_below(ids, dim, x)
 
 
 def mask_rows(x, block: int, step, dim: int):
     """Row-axis twin of :func:`mask_cols`."""
     ids = block * step + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    return jnp.where(ids < dim, x, jnp.zeros_like(x))
+    return _where_below(ids, dim, x)
 
 
 def _gemm_kernel(*refs, alpha, beta, k, bk, has_c, off, shared_b):

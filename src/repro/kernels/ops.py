@@ -32,17 +32,19 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.core.knobs import Knob, KnobSpace, block_knob_space
+from repro.core.knobs import (Knob, KnobSpace, block_knob_space,
+                              grouped_knob_space)
 from repro.core.runtime import AdsalaRuntime, global_runtime
 
 from .gemm import gemm_pallas
+from .grouped_gemm import grouped_gemm_pallas
 from .symm import symm_pallas
 from .syrk import syr2k_pallas, syrk_pallas
 from .trmm import trmm_pallas
 from .trsm import trsm_pallas
 
 __all__ = [
-    "gemm", "symm", "syrk", "syr2k", "trmm", "trsm",
+    "gemm", "symm", "syrk", "syr2k", "trmm", "trsm", "grouped_gemm",
     "knob_space_for", "default_knob", "dims_of", "run_op", "DTYPE_BYTES",
     "PALLAS_OPS", "trace_batching", "enable_trace_batching",
     "disable_trace_batching",
@@ -94,6 +96,8 @@ def knob_space_for(op: str, *, small: bool = False,
         sizes = (128, 256) if small else (128, 256, 512)
     if op == "gemm":
         return block_knob_space(bms=sizes, bks=sizes, bns=sizes)
+    if op == "grouped_gemm":
+        return grouped_knob_space(sizes)
     variants = ("full", "tri", "tri_packed") \
         if op in ("syrk", "syr2k", "trmm") else ("full",)
     space = block_knob_space(bms=sizes, bks=(128,), bns=sizes,
@@ -118,9 +122,10 @@ def default_knob(op: str) -> Knob:
     space used to recompute on every call — including every cache-hit
     call, where it dominated the remaining decision latency."""
     space = knob_space_for(op)
+    dims = {"gemm": (4096, 4096, 4096),
+            "grouped_gemm": (4096, 4096, 4096, 64)}.get(op, (4096, 4096))
     return space.candidates[int(np.argmax(
-        [space.parallelism(c, (4096, 4096, 4096)[: 3 if op == "gemm" else 2])
-         for c in space.candidates]))]
+        [space.parallelism(c, dims) for c in space.candidates]))]
 
 
 def dims_of(op: str, shapes: tuple[tuple[int, ...], ...]) -> tuple[int, ...]:
@@ -133,6 +138,9 @@ def dims_of(op: str, shapes: tuple[tuple[int, ...], ...]) -> tuple[int, ...]:
     if op == "gemm":
         (m, k), (_, n) = shapes[0][-2:], shapes[1][-2:]
         return (m, k, n)
+    if op == "grouped_gemm":          # x (m, k), w (g, k, n); sizes are data
+        (m, k), (g, _, n) = shapes[0], shapes[1]
+        return (m, k, n, g)
     if op == "symm":
         (m, _), (_, n) = shapes[0][-2:], shapes[1][-2:]
         return (m, n)
@@ -366,9 +374,23 @@ def trsm(a, b, *, alpha=1.0, knob=None, runtime=None,
                    interpret=interpret)
 
 
+def grouped_gemm(x, w, group_sizes, *, knob=None, runtime=None,
+                 interpret: bool = False):
+    """``out[r] = x[r] @ w[g(r)]``: rows of ``x (m, k)`` sorted by group,
+    ``w (g, k, n)``, ``group_sizes (g,)`` summing to m.  The decision key is
+    ``(m, k, n, g)``; the group sizes are data and never part of it."""
+    m, k = x.shape
+    g, _, n = w.shape
+    kb = _select("grouped_gemm", (m, k, n, g), x.dtype, knob, runtime).dict
+    bm, bk, bn = (min(kb["bm"], _rup(m, 128)), min(kb["bk"], _rup(k, 128)),
+                  min(kb["bn"], _rup(n, 128)))
+    return _launch(grouped_gemm_pallas, x, w, group_sizes, bm=bm, bk=bk,
+                   bn=bn, interpret=interpret)
+
+
 #: the pallas-path executors (what the ``pallas`` backend dispatches to)
 PALLAS_OPS = {"gemm": gemm, "symm": symm, "syrk": syrk, "syr2k": syr2k,
-              "trmm": trmm, "trsm": trsm}
+              "trmm": trmm, "trsm": trsm, "grouped_gemm": grouped_gemm}
 _OPS = PALLAS_OPS   # back-compat alias
 
 

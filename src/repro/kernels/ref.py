@@ -9,6 +9,7 @@ variants this library implements on TPU:
   syr2k: C := alpha*(A@B^T + B@A^T) + beta*C (lower)  A,B(n,k) C(n,n)
   trmm : B := alpha*tril(A)@B          (left, lower, non-unit)  A(m,m) B(m,n)
   trsm : solve tril(A)@X = alpha*B     (left, lower, non-unit)
+  grouped_gemm: out[r] = X[r]@W[g(r)], rows sorted by group  X(m,k) W(g,k,n)
 
 Symmetric operands are *stored* in the lower triangle (the upper triangle of
 the input array is ignored, as a real BLAS would).  Outputs of syrk/syr2k are
@@ -20,7 +21,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-__all__ = ["gemm", "symm", "syrk", "syr2k", "trmm", "trsm", "REFS"]
+__all__ = ["gemm", "symm", "syrk", "syr2k", "trmm", "trsm", "grouped_gemm",
+           "REFS"]
 
 
 def _sym_lower(a):
@@ -67,5 +69,13 @@ def trsm(a, b, *, alpha=1.0):
     return x.astype(a.dtype)
 
 
+def grouped_gemm(x, w, group_sizes):
+    """XLA's ragged dot, accumulated in float32."""
+    import jax
+    return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32),
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype)
+
+
 REFS = {"gemm": gemm, "symm": symm, "syrk": syrk, "syr2k": syr2k,
-        "trmm": trmm, "trsm": trsm}
+        "trmm": trmm, "trsm": trsm, "grouped_gemm": grouped_gemm}

@@ -14,6 +14,12 @@ without a profiler session) on the device trace's clock: ``serve.prefill``
 around a whole prefill, with ``serve.init_cache`` and ``serve.prefill_step``
 inside it; ``serve.decode_step`` around each decode step's dispatch; and
 ``serve.sample`` around sampling.
+
+Counter: ``ServeSession.routed_rows`` holds the last prefill's token-slots
+per expert of each MoE layer, ``(L_moe, E)`` int32, as the prefill program
+returned it: left on the device (read it after the work it counts, it costs
+the step no host sync), None for a model without MoE layers or before the
+first prefill.
 """
 
 from __future__ import annotations
@@ -79,9 +85,10 @@ class ServeSession:
 
     def __post_init__(self):
         cfg = self.cfg
+        self.routed_rows = None
         self._prefill = _Spanned("serve.prefill_step", jax.jit(
             lambda p, b, c: prefill(p, b, c, cfg, mesh=self.mesh,
-                                    rules=self.rules),
+                                    rules=self.rules, return_rows=True),
             donate_argnums=(2,)))
         self._decode = _Spanned("serve.decode_step", jax.jit(
             lambda p, t, c, e: decode_step(p, t, c, cfg, mesh=self.mesh,
@@ -106,7 +113,8 @@ class ServeSession:
                 enc_out = _run_encoder(self.params, batch["frames"], Ctx(cfg))
             if cfg.family == "vlm":
                 batch["vision"] = jnp.asarray(vision)
-            logits, caches = self._prefill(self.params, batch, caches)
+            logits, caches, self.routed_rows = self._prefill(
+                self.params, batch, caches)
             return logits, caches, enc_out
 
     def generate(self, prompts: np.ndarray, *, max_new: int = 32,

@@ -20,7 +20,8 @@ from repro.configs.base import ModelConfig
 from .sharding import ShardingRules, DEFAULT_RULES, constrain
 
 __all__ = ["Ctx", "init_linear", "linear", "routed_matmul", "init_norm",
-           "rmsnorm", "init_embedding", "embed", "rope", "init_attention",
+           "rmsnorm", "init_embedding", "embed", "rope", "yarn",
+           "yarn_softmax_factor", "init_attention",
            "attention", "init_mlp", "mlp", "cross_entropy",
            "flash_attention"]
 
@@ -140,14 +141,62 @@ def embed(p: dict, ids, ctx: Ctx):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope(x, positions, *, theta: float = 1e4):
-    """x: (..., S, H, D) rotated by ``positions`` (..., S)."""
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn(scaling, dim: int, theta: float):
+    """DeepSeek-V2's YaRN rotary embedding for rotary width ``dim``:
+    ``(inverse frequencies (dim/2,), cos/sin multiplier)``.  The frequencies
+    blend the interpolated ``θ^(-i/half)/factor`` and the original
+    ``θ^(-i/half)`` with a linear ramp over the correction range that
+    ``beta_fast``/``beta_slow`` rotations give over the original context;
+    the multiplier is ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``, with ``mscale(s, m) = 0.1·m·ln s + 1``."""
+    half = dim // 2
+    extra = 1.0 / (theta ** (np.arange(0, half) / half))
+    inter = extra / scaling.factor
+
+    def correction_dim(rotations):
+        return (dim * math.log(scaling.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    keep = 1.0 - np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    freqs = inter * (1.0 - keep) + extra * keep
+    mult = (_yarn_mscale(scaling.factor, scaling.mscale)
+            / _yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+    return freqs, mult
+
+
+def yarn_softmax_factor(scaling) -> float:
+    """What YaRN multiplies the softmax scale by: ``mscale(factor,
+    mscale_all_dim)²`` (1 when ``mscale_all_dim`` is 0)."""
+    if not scaling.mscale_all_dim:
+        return 1.0
+    return _yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+
+
+def rope(x, positions, *, theta: float = 1e4, scaling=None):
+    """x: (..., S, H, D) rotated by ``positions`` (..., S); ``scaling`` (a
+    :class:`~repro.configs.base.RopeScaling`) selects YaRN's frequencies
+    and cos/sin multiplier."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (theta ** (np.arange(0, half) / half))
+    mult = 1.0
+    if scaling is None:
+        freqs = 1.0 / (theta ** (np.arange(0, half) / half))
+    else:
+        freqs, mult = yarn(scaling, d, theta)
     ang = positions[..., None].astype(jnp.float32) * freqs      # (..., S, half)
     cos = jnp.cos(ang)[..., None, :]                            # (..., S, 1, half)
     sin = jnp.sin(ang)[..., None, :]
+    if mult != 1.0:
+        cos, sin = cos * mult, sin * mult
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
@@ -160,7 +209,7 @@ def rope(x, positions, *, theta: float = 1e4):
 def flash_attention(q, k, v, *, causal: bool, q_offset: int | jax.Array = 0,
                     q_chunk: int = 1024, k_chunk: int = 1024,
                     kv_valid_len=None, causal_skip: bool = False,
-                    unroll: int = 1):
+                    unroll: int = 1, scale: float | None = None):
     """Online-softmax attention over kv chunks.
 
     q: (B, S, H, D); k, v: (B, T, KH, D) with H = G·KH (GQA groups).
@@ -168,6 +217,7 @@ def flash_attention(q, k, v, *, causal: bool, q_offset: int | jax.Array = 0,
     ``kv_valid_len`` — optional (B,) number of valid cache entries.
     ``causal_skip`` — unrolled-q variant that skips fully-masked kv blocks
     (≈½ the FLOPs at long context; §Perf hillclimb knob).
+    ``scale`` — the softmax scale (``None`` → ``1/√D``).
 
     Never materialises more than (B, Cq, H, Ck) scores.
     """
@@ -175,7 +225,8 @@ def flash_attention(q, k, v, *, causal: bool, q_offset: int | jax.Array = 0,
     T, KH = k.shape[1], k.shape[2]
     Dv = v.shape[-1]                     # may differ from D (MLA)
     G = H // KH
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     q_chunk = min(q_chunk, S)
     k_chunk = min(k_chunk, T)
     nq = -(-S // q_chunk)

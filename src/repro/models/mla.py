@@ -9,6 +9,11 @@ Two execution forms, as in production DeepSeek serving:
     attention context is expanded through W_vb only once per step.  The KV
     cache holds kv_lora + qk_rope floats/token — 576 vs. 2·H·192 = 6144 for
     an equivalent GQA cache (the paper-V2 compression claim).
+
+With ``cfg.rope_scaling`` (YaRN, as DeepSeek-V2 publishes it) both forms
+rotate with YaRN's blended frequencies and multiply the softmax scale
+``(qk_nope + qk_rope)^-0.5`` by ``mscale(factor, mscale_all_dim)²``.  The
+whole layer runs under the ``mla_attn`` name scope.
 """
 
 from __future__ import annotations
@@ -20,7 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from .layers import Ctx, init_linear, init_norm, linear, rmsnorm, rope, \
-    flash_attention
+    flash_attention, yarn_softmax_factor
 
 __all__ = ["init_mla", "mla_attention", "init_mla_cache"]
 
@@ -60,11 +65,20 @@ def _project_q(p, x, cfg, ctx):
 
 def mla_attention(p: dict, x, ctx: Ctx, *, cache: dict | None = None):
     """Returns (out, new_cache|None)."""
+    with jax.named_scope("mla_attn"):
+        return _mla(p, x, ctx, cache)
+
+
+def _mla(p: dict, x, ctx: Ctx, cache):
     cfg = ctx.cfg
     B, S, _ = x.shape
     h, nope, rp, vd = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, \
         cfg.v_head_dim
     scale = 1.0 / math.sqrt(nope + rp)
+    flash_scale = None            # flash_attention's own 1/√(nope+rp)
+    ys = cfg.rope_scaling
+    if ys is not None:
+        scale = flash_scale = scale * yarn_softmax_factor(ys)
 
     kv_a = linear(p["wkv_a"], x, ctx)
     c_kv = rmsnorm(p["kv_norm"], kv_a[..., :cfg.kv_lora])
@@ -73,9 +87,9 @@ def mla_attention(p: dict, x, ctx: Ctx, *, cache: dict | None = None):
 
     if cache is None:
         positions = jnp.arange(S)[None, :]
-        q_rope = rope(q_rope, positions, theta=cfg.rope_theta)
+        q_rope = rope(q_rope, positions, theta=cfg.rope_theta, scaling=ys)
         k_rope = rope(k_rope_new[:, :, None, :], positions,
-                      theta=cfg.rope_theta)[:, :, 0]
+                      theta=cfg.rope_theta, scaling=ys)[:, :, 0]
         # expand latent → per-head K/V, dense attention (prefill/train form)
         kv = linear(p["wkv_b"], c_kv, ctx).reshape(B, S, h, nope + vd)
         k_nope, v = kv[..., :nope], kv[..., nope:]
@@ -87,7 +101,7 @@ def mla_attention(p: dict, x, ctx: Ctx, *, cache: dict | None = None):
                               q_chunk=cfg.attn_q_chunk,
                               k_chunk=cfg.attn_k_chunk,
                               causal_skip=cfg.causal_skip,
-                              unroll=cfg.unroll_attn)
+                              unroll=cfg.unroll_attn, scale=flash_scale)
         out = linear(p["wo"], out.reshape(B, S, h * vd), ctx,
                      out_logical="embed")
         return out, None
@@ -95,9 +109,9 @@ def mla_attention(p: dict, x, ctx: Ctx, *, cache: dict | None = None):
     # ---- cached path: update the latent cache, then attend ------------------
     start = cache["len"]
     positions = start + jnp.arange(S)[None, :]
-    q_rope = rope(q_rope, positions, theta=cfg.rope_theta)
+    q_rope = rope(q_rope, positions, theta=cfg.rope_theta, scaling=ys)
     k_rope_new = rope(k_rope_new[:, :, None, :], positions,
-                      theta=cfg.rope_theta)[:, :, 0]
+                      theta=cfg.rope_theta, scaling=ys)[:, :, 0]
     c = jax.lax.dynamic_update_slice_in_dim(
         cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), start, axis=1)
     kr = jax.lax.dynamic_update_slice_in_dim(
@@ -119,7 +133,7 @@ def mla_attention(p: dict, x, ctx: Ctx, *, cache: dict | None = None):
                               q_chunk=cfg.attn_q_chunk,
                               k_chunk=cfg.attn_k_chunk,
                               kv_valid_len=jnp.full((B,), start + S),
-                              unroll=cfg.unroll_attn)
+                              unroll=cfg.unroll_attn, scale=flash_scale)
         out = linear(p["wo"], out.reshape(B, S, h * vd), ctx,
                      out_logical="embed")
         return out, new_cache

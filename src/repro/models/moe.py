@@ -1,6 +1,18 @@
-"""Mixture-of-Experts FFN with sort-based capacity dispatch (EP-shardable).
+"""Mixture-of-Experts FFN: dropless on one device, capacity slab on a mesh.
 
-Dispatch avoids the O(tokens·E·capacity) one-hot einsums of the classic
+Routing (both paths) runs in float32: softmax over the E experts, greedy
+top-k, the top-k weights renormalised only when ``cfg.norm_topk_prob``.
+
+Without a mesh the layer is **dropless**: the ``T·top_k`` token-slots are
+sorted by expert, the group sizes taken, and gate/up and down run as grouped
+gemms over the sorted rows (``run_op("grouped_gemm")`` when the config
+routes, ``jax.lax.ragged_dot`` when not), each expert applied to exactly the
+tokens routed to it; the rows are un-sorted, weighted and summed over k.
+Nothing is padded and nothing is dropped, so a skewed prompt computes what
+the published model computes.
+
+On a mesh (the sharded path) the layer keeps its capacity slab.  Dispatch
+there avoids the O(tokens·E·capacity) one-hot einsums of the classic
 Mesh-TF formulation (which would *double* the model's FLOPs at 32k context —
 see DESIGN.md roofline notes): tokens are routed by argsort over expert ids,
 position-in-expert comes from segment arithmetic on the sorted array, and
@@ -9,8 +21,9 @@ dispatch/combine are scatter/gather (data movement, no FLOPs).
 Per-sequence grouping keeps dispatch local to the data shard; the expert
 einsum's (experts → 'model') sharding constraint induces the all-to-all.
 Fixed capacity C = ⌈S·top_k/E · capacity_factor⌉ with token dropping
-(standard at scale); the router's load-balance auxiliary loss is returned
-for the trainer to add.
+(standard at scale).  Both paths return the router's load-balance auxiliary
+loss, for the trainer to add, and the rows each expert computed (the routed-
+rows counter).
 """
 
 from __future__ import annotations
@@ -21,7 +34,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from .layers import Ctx, init_linear, init_mlp, linear, mlp
 
-__all__ = ["init_moe", "moe_ffn"]
+__all__ = ["init_moe", "moe_ffn", "moe_layer"]
 
 
 def init_moe(key, cfg: ModelConfig) -> dict:
@@ -74,8 +87,65 @@ def _positions_in_expert(e_flat: jax.Array) -> jax.Array:
     return jnp.take_along_axis(pos_sorted, inv, axis=-1)
 
 
-def moe_ffn(p: dict, x, ctx: Ctx):
-    """x: (B, S, D) → (out (B, S, D), aux_loss scalar)."""
+def _route(p: dict, x, cfg: ModelConfig):
+    """(probs (B,S,E), top-k weights (B,S,K), top-k experts (B,S,K)), f32."""
+    logits = jnp.matmul(x.astype(jnp.float32), p["router"]["w"],
+                        precision=jax.lax.Precision.HIGHEST)       # (B,S,E)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, cfg.top_k)                # greedy
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    return probs, top_p, top_e
+
+
+def _aux_loss(probs, top_e, E: int):
+    """Switch-style load-balance loss: E · Σ_e f_e · p̄_e."""
+    density = jnp.mean(jax.nn.one_hot(top_e[..., 0], E, dtype=jnp.float32),
+                       axis=(0, 1))
+    return E * jnp.sum(density * probs.mean(axis=(0, 1)))
+
+
+def _grouped_matmul(rows, w, sizes, ctx: Ctx):
+    """``rows[r] @ w[expert(r)]`` over rows sorted by expert."""
+    if not ctx.routes_gemm(rows):
+        return jax.lax.ragged_dot(rows, w, sizes,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(rows.dtype)
+    from repro.kernels import ops as kops
+    kw = {}
+    if ctx.cfg.gemm_interpret is not None:
+        kw["interpret"] = ctx.cfg.gemm_interpret
+    return kops.run_op("grouped_gemm", (rows, w, sizes),
+                       backend=ctx.cfg.gemm_backend, runtime=ctx.runtime,
+                       **kw)
+
+
+def _dropless(p: dict, x, top_p, top_e, ctx: Ctx):
+    """Every token-slot through its expert: (out (B,S,D), rows (E,))."""
+    cfg = ctx.cfg
+    B, S, D = x.shape
+    E, K = cfg.n_experts, cfg.top_k
+    with jax.named_scope("moe_dispatch"):
+        e_flat = top_e.reshape(-1)                                # (T·K,)
+        order = jnp.argsort(e_flat, stable=True)
+        sizes = jnp.zeros((E,), jnp.int32).at[e_flat].add(1)
+        rows = x.reshape(B * S, D)[order // K]                   # by expert
+    with jax.named_scope("moe_experts"):
+        wg, wu, wd = ctx.cast(p["wg"]), ctx.cast(p["wu"]), ctx.cast(p["wd"])
+        h = jax.nn.silu(_grouped_matmul(rows, wg, sizes, ctx)) * \
+            _grouped_matmul(rows, wu, sizes, ctx)
+        y = _grouped_matmul(h, wd, sizes, ctx)
+    with jax.named_scope("moe_combine"):
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        y = y[inv].reshape(B, S, K, D)
+        out = jnp.einsum("bskd,bsk->bsd", y.astype(jnp.float32),
+                         top_p).astype(x.dtype)
+    return out, sizes
+
+
+def _capacity(p: dict, x, top_p, top_e, ctx: Ctx):
+    """The sharded path's capacity slab: (out (B,S,D), rows kept (E,))."""
     cfg = ctx.cfg
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.top_k
@@ -83,24 +153,13 @@ def moe_ffn(p: dict, x, ctx: Ctx):
     if S > 1:
         C = -(-C // 64) * 64      # align for capacity ("slot") sharding
 
-    # --- routing (f32) ------------------------------------------------------
-    logits = (x.astype(jnp.float32) @ p["router"]["w"])          # (B,S,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, K)                        # (B,S,K)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
-
-    # load-balance aux loss (Switch-style): E · Σ_e f_e · p̄_e
-    density = jnp.mean(jax.nn.one_hot(top_e[..., 0], E, dtype=jnp.float32),
-                       axis=(0, 1))
-    p_mean = probs.mean(axis=(0, 1))
-    aux = E * jnp.sum(density * p_mean)
-
     # --- slot bookkeeping ----------------------------------------------------
     e_flat = top_e.reshape(B, S * K)                              # (B, SK)
     w_flat = top_p.reshape(B, S * K)
     pos = _positions_in_expert(e_flat)                            # (B, SK)
     keep = (pos < C)
     dest = jnp.where(keep, e_flat * C + pos, E * C)               # drop → pad row
+    kept = jnp.zeros((E,), jnp.int32).at[e_flat].add(keep.astype(jnp.int32))
 
     # --- dispatch (scatter, batch-local) --------------------------------------
     x_slots = jnp.repeat(x, K, axis=1).reshape(B, S * K, D)       # token s → K slots
@@ -129,8 +188,26 @@ def moe_ffn(p: dict, x, ctx: Ctx):
     gathered = jnp.take_along_axis(y, dest[..., None], axis=1)    # (B,SK,D)
     gathered = gathered * (w_flat * keep)[..., None].astype(y.dtype)
     out = gathered.reshape(B, S, K, D).sum(axis=2)
-    out = ctx.cons(out, "batch", "seq", "embed")
+    return ctx.cons(out, "batch", "seq", "embed"), kept
 
+
+def moe_layer(p: dict, x, ctx: Ctx):
+    """x: (B, S, D) → (out (B, S, D), aux_loss scalar, rows (E,) int32: the
+    token-slots each expert computed)."""
+    cfg = ctx.cfg
+    with jax.named_scope("moe_router"):
+        probs, top_p, top_e = _route(p, x, cfg)
+        aux = _aux_loss(probs, top_e, cfg.n_experts)
+    if ctx.mesh is None:
+        out, rows = _dropless(p, x, top_p, top_e, ctx)
+    else:
+        out, rows = _capacity(p, x, top_p, top_e, ctx)
     if "shared" in p:
         out = out + mlp(p["shared"], x, ctx)
+    return out, aux, rows
+
+
+def moe_ffn(p: dict, x, ctx: Ctx):
+    """x: (B, S, D) → (out (B, S, D), aux_loss scalar)."""
+    out, aux, _ = moe_layer(p, x, ctx)
     return out, aux

@@ -32,7 +32,7 @@ from .layers import (Ctx, attention, cross_entropy, embed, init_attention,
                      rmsnorm, routed_matmul)
 from .mamba2 import init_mamba2, init_mamba2_state, mamba2_mixer
 from .mla import init_mla, init_mla_cache, mla_attention
-from .moe import init_moe, moe_ffn
+from .moe import init_moe, moe_layer
 from .rwkv6 import init_rwkv6, init_rwkv6_state, rwkv6_block
 
 __all__ = ["init_params", "forward", "loss_fn", "init_decode_state",
@@ -120,7 +120,8 @@ def param_count(params) -> int:
 
 
 # ---------------------------------------------------------------------------
-# per-block apply — returns (x, new_cache, aux)
+# per-block apply — returns (x, new_cache, aux, rows): rows is an MoE block's
+# token-slots per expert (E,), None for every other block
 # ---------------------------------------------------------------------------
 
 def _shared_attn_block(shared_p, in_proj, x, x0, ctx, cache):
@@ -147,7 +148,7 @@ def _apply_block(kind: str, p: dict, x, ctx: Ctx, cache, *, shared=None,
                               use_rope=(cfg.family != "audio"))
         x = x + a
         x = x + mlp(p["mlp"], rmsnorm(p["ln2"], x), ctx)
-        return x, nc, zero
+        return x, nc, zero, None
     if kind == "moe":
         if cfg.use_mla:
             a, nc = mla_attention(p["attn"], rmsnorm(p["ln1"], x), ctx,
@@ -156,15 +157,15 @@ def _apply_block(kind: str, p: dict, x, ctx: Ctx, cache, *, shared=None,
             a, nc = attention(p["attn"], rmsnorm(p["ln1"], x), ctx,
                               cache=cache)
         x = x + a
-        m, aux = moe_ffn(p["moe"], rmsnorm(p["ln2"], x), ctx)
-        return x + m, nc, aux
+        m, aux, rows = moe_layer(p["moe"], rmsnorm(p["ln2"], x), ctx)
+        return x + m, nc, aux, rows
     if kind == "mamba2":
         m, ns = mamba2_mixer(p["mixer"], rmsnorm(p["ln"], x), ctx,
                              state=cache)
-        return x + m, ns, zero
+        return x + m, ns, zero, None
     if kind == "rwkv6":
         y, ns = rwkv6_block(p, x, ctx, state=cache)
-        return y, ns, zero
+        return y, ns, zero, None
     if kind == "zamba_super":
         mamba_cache = cache["mamba"] if cache is not None else None
 
@@ -172,7 +173,7 @@ def _apply_block(kind: str, p: dict, x, ctx: Ctx, cache, *, shared=None,
             h = carry
             pp = xs[0] if cache is not None else xs
             cc = xs[1] if cache is not None else None
-            h, nc2, _ = _apply_block("mamba2", pp, h, ctx, cc)
+            h, nc2, _, _ = _apply_block("mamba2", pp, h, ctx, cc)
             return h, nc2
 
         xs = (p["mamba"], mamba_cache) if cache is not None else p["mamba"]
@@ -182,7 +183,7 @@ def _apply_block(kind: str, p: dict, x, ctx: Ctx, cache, *, shared=None,
                                          attn_cache)
         nc = ({"mamba": new_mamba, "attn": new_attn}
               if cache is not None else None)
-        return x, nc, zero
+        return x, nc, zero, None
     if kind == "dec_cross":
         a, nc = attention(p["attn"], rmsnorm(p["ln1"], x), ctx, cache=cache,
                           use_rope=False)
@@ -191,7 +192,7 @@ def _apply_block(kind: str, p: dict, x, ctx: Ctx, cache, *, shared=None,
                          kv_x=enc_out, causal=False, use_rope=False)
         x = x + c
         x = x + mlp(p["mlp"], rmsnorm(p["ln2"], x), ctx)
-        return x, nc, zero
+        return x, nc, zero, None
     raise ValueError(kind)
 
 
@@ -241,10 +242,13 @@ def _scan_stack(body, carry, xs, repeat: int, cfg: ModelConfig):
 
 def _run_segments(params, x, ctx: Ctx, caches=None, *, x0=None,
                   enc_out=None):
-    """Scan every segment; returns (x, new_caches|None, aux_sum)."""
+    """Scan every segment; returns (x, new_caches|None, aux_sum, rows):
+    ``rows`` holds each MoE layer's token-slots per expert, ``(L_moe, E)``
+    int32 over the MoE segments in order, or None without one."""
     cfg = ctx.cfg
     aux_total = jnp.zeros((), jnp.float32)
     new_caches = [] if caches is not None else None
+    rows = []
     shared = params.get("shared_attn")
     for si, (kind, repeat) in enumerate(cfg.segments()):
         seg_p = params["segments"][si]
@@ -256,19 +260,22 @@ def _run_segments(params, x, ctx: Ctx, caches=None, *, x0=None,
                 pp, cc = xs
             else:
                 pp, cc = xs, None
-            h, nc, a = _apply_block(kind, pp, h, ctx, cc, shared=shared,
-                                    x0=x0, enc_out=enc_out)
+            h, nc, a, r = _apply_block(kind, pp, h, ctx, cc, shared=shared,
+                                       x0=x0, enc_out=enc_out)
             # inter-block activation layout (SP shards seq here) — this is
             # also the layout of the saved scan carries
             h = ctx.cons(h, "batch", "seq", "embed")
-            return (h, aux + a), nc
+            return (h, aux + a), (nc, r)
 
         xs = (seg_p, seg_c) if caches is not None else seg_p
-        (x, aux_total), seg_nc = _scan_stack(body, (x, aux_total), xs,
-                                             repeat, cfg)
+        (x, aux_total), (seg_nc, seg_rows) = _scan_stack(
+            body, (x, aux_total), xs, repeat, cfg)
         if caches is not None:
             new_caches.append(seg_nc)
-    return x, new_caches, aux_total
+        if seg_rows is not None:
+            rows.append(seg_rows)
+    rows = jnp.concatenate(rows) if rows else None
+    return x, new_caches, aux_total, rows
 
 
 # ---------------------------------------------------------------------------
@@ -291,7 +298,7 @@ def _run_encoder(params, frames, ctx: Ctx):
     x = x + _sinusoid(x.shape[1], x.shape[2]).astype(x.dtype)[None]
 
     def body(h, pp):
-        h, _, _ = _apply_block("enc", pp, h, ctx, None)
+        h, _, _, _ = _apply_block("enc", pp, h, ctx, None)
         return h, None
 
     x, _ = jax.lax.scan(_maybe_remat(body, ctx.cfg),
@@ -337,7 +344,7 @@ def forward(params, batch, cfg: ModelConfig, *, mesh=None, rules=None,
     x = _embed_inputs(params, batch, ctx)
     enc_out = (_run_encoder(params, batch["frames"], ctx)
                if cfg.family == "audio" else None)
-    x, _, aux = _run_segments(params, x, ctx, x0=x, enc_out=enc_out)
+    x, _, aux, _ = _run_segments(params, x, ctx, x0=x, enc_out=enc_out)
     return _logits(params, x, ctx), aux
 
 
@@ -349,7 +356,7 @@ def loss_fn(params, batch, cfg: ModelConfig, *, mesh=None, rules=None,
     x = _embed_inputs(params, batch, ctx)
     enc_out = (_run_encoder(params, batch["frames"], ctx)
                if cfg.family == "audio" else None)
-    x, _, aux = _run_segments(params, x, ctx, x0=x, enc_out=enc_out)
+    x, _, aux, _ = _run_segments(params, x, ctx, x0=x, enc_out=enc_out)
     labels = batch["labels"]
     if cfg.family == "vlm":   # vision prefix carries no LM loss
         pad = jnp.full(batch["vision"].shape[:2], -1, labels.dtype)
@@ -404,17 +411,21 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def prefill(params, batch, caches, cfg: ModelConfig, *, mesh=None,
-            rules=None, runtime=None):
+            rules=None, runtime=None, return_rows: bool = False):
     """Run the prompt through the model filling caches.
-    Returns (last-token logits, new caches)."""
+    Returns (last-token logits, new caches), and with ``return_rows`` also
+    each MoE layer's token-slots per expert, ``(L_moe, E)`` int32 (None for
+    a model without MoE layers)."""
     from .sharding import DEFAULT_RULES
     ctx = Ctx(cfg, mesh, rules or DEFAULT_RULES, runtime)
     x = _embed_inputs(params, batch, ctx)
     enc_out = (_run_encoder(params, batch["frames"], ctx)
                if cfg.family == "audio" else None)
-    x, new_caches, _ = _run_segments(params, x, ctx, caches=caches, x0=x,
-                                     enc_out=enc_out)
-    return _logits(params, x[:, -1:], ctx), new_caches
+    x, new_caches, _, rows = _run_segments(params, x, ctx, caches=caches,
+                                           x0=x, enc_out=enc_out)
+    logits = _logits(params, x[:, -1:], ctx)
+    return (logits, new_caches, rows) if return_rows else (logits,
+                                                           new_caches)
 
 
 def decode_step(params, token, caches, cfg: ModelConfig, *, mesh=None,
@@ -429,6 +440,6 @@ def decode_step(params, token, caches, cfg: ModelConfig, *, mesh=None,
     if cfg.family == "audio":
         x = x + _sinusoid(1, x.shape[2], offset=pos).astype(x.dtype)[None]
     x0 = x if x0 is None else x0
-    x, new_caches, _ = _run_segments(params, x, ctx, caches=caches, x0=x0,
-                                     enc_out=enc_out)
+    x, new_caches, _, _ = _run_segments(params, x, ctx, caches=caches,
+                                        x0=x0, enc_out=enc_out)
     return _logits(params, x, ctx), new_caches

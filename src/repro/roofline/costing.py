@@ -270,14 +270,14 @@ def unit_costs(cfg: ModelConfig, unit: Unit, shape: Shape, mesh,
             enc_v = it.pop(0) if unit.kind == "dec_cross" else None
 
             def f(pp_i, x_i):
-                h, _, aux = _apply_block(unit.kind, pp_i, x_i, ctx, cc_v,
-                                         enc_out=enc_v)
+                h, _, aux, _ = _apply_block(unit.kind, pp_i, x_i, ctx, cc_v,
+                                            enc_out=enc_v)
                 return jnp.sum(h.astype(jnp.float32)) + 0.0 * aux
 
             if train:
                 return jax.grad(jax.checkpoint(f), argnums=(0, 1))(pp_v, x_v)
-            h, nc2, _ = _apply_block(unit.kind, pp_v, x_v, ctx, cc_v,
-                                     enc_out=enc_v)
+            h, nc2, _, _ = _apply_block(unit.kind, pp_v, x_v, ctx, cc_v,
+                                        enc_out=enc_v)
             return (h, nc2) if nc2 is not None else h
 
         args = [pp, x]

@@ -57,6 +57,29 @@ def _cfg(arch):
                                capacity_factor=8.0, d_ff=128)
 
 
+@pytest.fixture
+def held_experts(monkeypatch):
+    """Holds the unrouted path's expert gemm to the grouped kernel (the
+    routed path's, at the default knob), so the whole-model comparison
+    below is bitwise: routing then changes no bit in any other linear.
+    The expert gemm's own exchange, the kernel for ``ragged_dot``, is
+    compared exactly on integer-valued operands in
+    ``test_grouped_kernel_equals_ragged_dot_exactly``: on real-valued ones
+    XLA's CPU ``ragged_dot`` sums each dot in another order."""
+    from repro.kernels.grouped_gemm import grouped_gemm_pallas
+    from repro.models import moe
+    real = moe._grouped_matmul
+    kb = ops.default_knob("grouped_gemm").dict
+
+    def held(rows, w, sizes, ctx):
+        if ctx.routes_gemm(rows):
+            return real(rows, w, sizes, ctx)
+        return grouped_gemm_pallas(rows, w, sizes, bm=kb["bm"], bk=kb["bk"],
+                                   bn=kb["bn"], interpret=True)
+
+    monkeypatch.setattr(moe, "_grouped_matmul", held)
+
+
 def _batch(cfg, B, S, seed=0):
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(seed),
                                           (B, S), 0, cfg.vocab)}
@@ -71,7 +94,7 @@ def _batch(cfg, B, S, seed=0):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("arch", ARCHS)
-def test_routed_forward_bit_identical(arch):
+def test_routed_forward_bit_identical(arch, held_experts):
     cfg = _cfg(arch)
     rcfg = dataclasses.replace(cfg, use_pallas_gemm=True)
     params = tf.init_params(jax.random.PRNGKey(0), cfg)
@@ -87,7 +110,7 @@ def test_routed_forward_bit_identical(arch):
 
 
 @pytest.mark.parametrize("arch", ARCHS)
-def test_routed_prefill_decode_bit_identical(arch):
+def test_routed_prefill_decode_bit_identical(arch, held_experts):
     cfg = _cfg(arch)
     rcfg = dataclasses.replace(cfg, use_pallas_gemm=True)
     params = tf.init_params(jax.random.PRNGKey(0), cfg)
@@ -103,6 +126,47 @@ def test_routed_prefill_decode_bit_identical(arch):
     du, _ = tf.decode_step(params, tok, cu, cfg)
     dr, _ = tf.decode_step(params, tok, cr, rcfg, runtime=rt)
     assert jnp.array_equal(du, dr)
+
+
+def _expert_gemm_keys():
+    """(arch, (m, k, n, E)) of every expert gemm the parity tests run:
+    gate/up and down, at prefill (2 × 16 tokens) and decode (2 tokens)."""
+    out = []
+    for arch in ARCHS:
+        cfg = _cfg(arch)
+        if cfg.family != "moe":
+            continue
+        for t in (32, 2):
+            m = t * cfg.top_k
+            out += [(arch, (m, cfg.d_model, cfg.moe_d_ff, cfg.n_experts)),
+                    (arch, (m, cfg.moe_d_ff, cfg.d_model, cfg.n_experts))]
+    return out
+
+
+@pytest.mark.parametrize("arch, dims", _expert_gemm_keys())
+def test_grouped_kernel_equals_ragged_dot_exactly(arch, dims):
+    """The routed expert gemm against the unrouted one, bit for bit, on
+    integer-valued operands (every partial sum exact, so no summation order
+    shows), with uneven groups and an empty one."""
+    from repro.models.layers import Ctx as _Ctx
+    from repro.models.moe import _grouped_matmul
+    from repro.models.sharding import DEFAULT_RULES
+    m, k, n, g = dims
+    rng = np.random.default_rng(m * k + n)
+    p = rng.random(g)
+    p[1] = 0.0                                         # an empty group
+    sizes = jnp.asarray(rng.multinomial(m, p / p.sum()), jnp.int32)
+    x = jnp.asarray(rng.integers(-4, 5, (m, k)), jnp.float32)
+    w = jnp.asarray(rng.integers(-4, 5, (g, k, n)), jnp.float32)
+    cfg = _cfg(arch)
+    rcfg = dataclasses.replace(cfg, use_pallas_gemm=True,
+                               gemm_interpret=True)
+    un = _grouped_matmul(x, w, sizes, _Ctx(cfg, None, DEFAULT_RULES))
+    rctx = _Ctx(rcfg, None, DEFAULT_RULES, AdsalaRuntime())
+    assert rctx.routes_gemm(x)
+    routed = _grouped_matmul(x, w, sizes, rctx)
+    assert jnp.array_equal(un, routed)
+    assert jnp.array_equal(un, jax.lax.ragged_dot(x, w, sizes))
 
 
 def test_routing_respects_config_gates():
@@ -235,12 +299,23 @@ def test_harvest_covers_decode_gemms(arch):
     cfg = _cfg(arch)
     keys = harvest_decision_keys(cfg, batch_size=2, seq_len=16)
     assert keys, "routed model harvested no decision keys"
-    assert all(k[0] == "pallas" and k[1] == "gemm" for k in keys)
+    assert all(k[0] == "pallas" and k[1] in ("gemm", "grouped_gemm")
+               for k in keys)
+    # the MoE archs' dropless expert gemms: (m = tokens·top_k, k, n, E)
+    grouped = [k[3] for k in keys if k[1] == "grouped_gemm"]
+    if cfg.family == "moe":
+        assert (2 * 16 * cfg.top_k, cfg.d_model, cfg.moe_d_ff,
+                cfg.n_experts) in grouped
+        assert (2 * cfg.top_k, cfg.moe_d_ff, cfg.d_model,
+                cfg.n_experts) in grouped          # decode: one token each
+    else:
+        assert not grouped
     # the skinny decode-step GEMMs (m = one token per sequence, folded:
     # m = batch_size) must be present — missing them means the first
     # decode pays a cold model eval.  The output head is left out: prefill's
     # last-token head has the same key.
-    assert any(k[3][0] == 2 and k[3][2] != cfg.vocab for k in keys)
+    assert any(k[3][0] == 2 and k[3][2] != cfg.vocab for k in keys
+               if k[1] == "gemm")
     # deterministic: same trace → same keys, no duplicates
     assert keys == harvest_decision_keys(cfg, batch_size=2, seq_len=16)
     assert len(set(keys)) == len(keys)

@@ -148,3 +148,70 @@ def test_routed_qwen15_4b_prefill_fits_one_v5e(one_chip):
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 7.5e9     # the bf16 weights
     assert total < HBM_BYTES
+
+
+@pytest.mark.parametrize("m,k,n,bm,bk,bn", [
+    (49152, 2048, 1408, 512, 512, 512),
+    (49152, 1408, 2048, 256, 512, 256),
+    (49152, 2048, 1408, 128, 128, 128),
+    (24, 1408, 2048, 128, 512, 512),
+], ids=["gate-b512", "down-ragged-k", "gate-b128", "decode-rows"])
+def test_grouped_gemm_compiles_for_v5e(one_chip, m, k, n, bm, bk, bn):
+    """The dropless expert gemm at DeepSeek-V2-Lite's prefill and decode
+    keys (64 experts, bf16), its group tables as scalar prefetch."""
+    knob = Knob((("bk", bk), ("bm", bm), ("bn", bn), ("variant", "full")))
+    args = [jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((64, k, n), jnp.bfloat16,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)]
+    fn = jax.jit(lambda *x: ops.run_op("grouped_gemm", x, knob=knob,
+                                       interpret=False))
+    assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
+
+
+def test_routed_deepseek_v2_lite_stage_prefill_fits_one_v5e(one_chip):
+    """The benchmark cell's program: the routed prefill of DeepSeek-V2-Lite's
+    first pipeline stage (9 layers at published widths, all 64 experts,
+    bf16) over 4 x 2048 tokens into a 2056-long latent cache; the grouped
+    and dense kernels compile and everything fits one chip's HBM."""
+    from repro.configs import get_config
+    from repro.models import init_decode_state, init_params, prefill
+
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"), n_layers=9,
+                              param_dtype="bfloat16", use_pallas_gemm=True,
+                              gemm_interpret=False)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    caches = on_chip(jax.eval_shape(
+        lambda: init_decode_state(cfg, 4, 2056, dtype=jnp.bfloat16)))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((4, 2048), jnp.int32)})
+    step = jax.jit(lambda p, b, c: prefill(p, b, c, cfg, return_rows=True),
+                   donate_argnums=(2,))
+    compiled = step.lower(params, batch, caches).compile()
+    assert 'name="grouped_gemm"' in compiled.as_text() or \
+        "grouped_gemm" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 10e9      # 5.2e9 bf16 parameters
+    assert total < HBM_BYTES
+
+
+@pytest.mark.parametrize("m,k,n,bk", [(4, 10944, 2048, 512),
+                                      (4, 2816, 2048, 512),
+                                      (3, 2036, 1146, 256)],
+                         ids=["dense-down", "shared-down", "install-point"])
+def test_few_row_bf16_gemm_with_ragged_k_compiles_for_v5e(one_chip, m, k, n,
+                                                          bk):
+    """A decode step's bf16 gemm of a few rows whose k is not a multiple of
+    bk: the ragged-tail mask selects in float32, where a bf16 select of
+    fewer rows than a packed sublane group is refused by Mosaic."""
+    knob = Knob((("bk", bk), ("bm", 128), ("bn", 512), ("variant", "full")))
+    compiled = _compile_kernel(one_chip, "gemm", "bfloat16",
+                               [(m, k), (k, n)], knob)
+    assert "tpu_custom_call" in compiled.as_text()
