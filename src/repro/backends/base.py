@@ -73,6 +73,11 @@ class Backend(abc.ABC):
     #: filler rows would just run as extra full ops), so they leave it False.
     jit_stacked: bool = False
 
+    #: True when ``execute`` takes ``layer=``: ``operands[1]`` is then a
+    #: stack of layer weights of which the kernel reads that layer in
+    #: place.  Dispatch slices the layer out for every other backend.
+    reads_weight_stacks: bool = False
+
     # -- capability ----------------------------------------------------------
     def ops(self) -> tuple[str, ...]:
         return L3_OPS

@@ -47,6 +47,7 @@ class PallasBackend(Backend):
     name = "pallas"
     selects_own_knob = True     # ops.py selects at jit trace time
     jit_stacked = True          # vmap compiles per (shape, width)
+    reads_weight_stacks = True  # gemm / grouped_gemm take ``layer=``
 
     def __init__(self, *, interpret: bool | None = None) -> None:
         self._interpret = interpret

@@ -236,6 +236,12 @@ class RuntimeStats:
     #: decisions/quarantines absorbed from a shared fleet journal (peer
     #: processes' entries imported via :meth:`AdsalaRuntime.absorb_journal`)
     journal_absorbed: int = 0
+    #: routed model matmuls traced with their weight read in place from a
+    #: segment's stacked layer weights (``layer=``) and with a weight array
+    #: (an unstacked weight, or a layer sliced out); counted at trace time
+    #: (:meth:`AdsalaRuntime.count_routed`)
+    routed_in_place: int = 0
+    routed_materialised: int = 0
     #: process-global resolve-time backend fallbacks, per
     #: (requested, resolved) pair (from repro.backends.registry) — how often
     #: dispatch silently degraded, e.g. pallas→ref when pallas is absent
@@ -368,6 +374,8 @@ class AdsalaRuntime:
                 import_drops_corrupt=base.import_drops_corrupt,
                 journal_failures=base.journal_failures,
                 journal_absorbed=base.journal_absorbed,
+                routed_in_place=base.routed_in_place,
+                routed_materialised=base.routed_materialised,
                 backends={n: dataclasses.replace(b)
                           for n, b in base.backends.items()},
                 buckets={k: dataclasses.replace(b)
@@ -396,6 +404,15 @@ class AdsalaRuntime:
         if reg is not None:
             merged.resolve_fallbacks = reg.fallback_counts()
         return merged
+
+    def count_routed(self, in_place: bool) -> None:
+        """Count one routed model matmul at trace time: its weight read in
+        place from a stack of layer weights, or handed over as an array."""
+        with self._lock:
+            if in_place:
+                self._base.routed_in_place += 1
+            else:
+                self._base.routed_materialised += 1
 
     def _stripe(self) -> _HitStripe:
         """This thread's hit stripe (registered for aggregation on first

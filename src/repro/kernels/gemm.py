@@ -22,6 +22,12 @@ serving layer's bucket primitive), replacing the old ``jax.vmap`` lift.
 The C operand is only an input when ``beta != 0`` and a C was given; the
 old path materialised a ``jnp.zeros`` C (and DMA'd it) even for the
 ``beta == 0`` common case.
+
+With a ``layer`` index, B is a stack of weights ``(L, k, n)`` (a model
+segment's stacked layer weights) and the kernel reads layer ``layer``'s
+tiles straight from it: the index arrives as scalar prefetch and B's index
+map selects the layer, so no per-layer copy of the weight is made before
+the call.  Without one the kernel is the plain gemm above.
 """
 
 from __future__ import annotations
@@ -85,11 +91,14 @@ def mask_rows(x, block: int, step, dim: int):
     return _where_below(ids, dim, x)
 
 
-def _gemm_kernel(*refs, alpha, beta, k, bk, has_c, off, shared_b):
-    """``refs`` = (a, b[, c], o, acc); ``off`` = 1 when a leading batch grid
-    dim is present (refs then carry a leading length-1 block axis).
-    ``shared_b`` — B is a single 2-D weight shared across the stack (its ref
-    never gained the batch block axis)."""
+def _gemm_kernel(*refs, alpha, beta, k, bk, has_c, off, shared_b, layered):
+    """``refs`` = ([layer,] a, b[, c], o, acc); ``off`` = 1 when a leading
+    batch grid dim is present (refs then carry a leading length-1 block
+    axis).  ``shared_b`` — B is a single weight shared across the stack (its
+    ref never gained the batch block axis).  ``layered`` — the first ref is
+    the scalar-prefetched layer index, which only B's index map reads."""
+    if layered:
+        refs = refs[1:]
     if has_c:
         a_ref, b_ref, c_ref, o_ref, acc_ref = refs
     else:
@@ -123,47 +132,63 @@ def _gemm_kernel(*refs, alpha, beta, k, bk, has_c, off, shared_b):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "alpha",
                                              "beta", "interpret", "name"))
-def gemm_pallas(a, b, c=None, *, bm: int = 128, bk: int = 128, bn: int = 128,
-                alpha: float = 1.0, beta: float = 0.0,
+def gemm_pallas(a, b, c=None, *, layer=None, bm: int = 128, bk: int = 128,
+                bn: int = 128, alpha: float = 1.0, beta: float = 0.0,
                 interpret: bool = False, name: str = "gemm"):
     """alpha*A@B + beta*C for arbitrary (ragged) shapes; a leading batch
     axis executes as one batched grid.  A 2-D B against a batched A is
     treated as a weight shared across the stack: each stack item re-reads
-    it (a model's routed linear folds its batch into M instead).  ``name``
-    is the kernel's name in the compiled program, which a profiler trace
-    shows: callers that use the gemm as a step of another op name that
-    step."""
+    it (a model's routed linear folds its batch into M instead).  ``layer``
+    (an int32 scalar) makes B a stack ``(L, k, n)`` of which the kernel
+    reads layer ``layer`` in place, as the 2-D weight it stands for.
+    ``name`` is the kernel's name in the compiled program, which a profiler
+    trace shows: callers that use the gemm as a step of another op name
+    that step."""
     *lead, m, k = a.shape
     k2, n = b.shape[-2:]
+    layered = layer is not None
+    b_lead = b.shape[1:-2] if layered else b.shape[:-2]
     assert k == k2, (a.shape, b.shape)
-    assert len(lead) <= 1 and b.shape[:-2] in (tuple(lead), ()), \
-        (a.shape, b.shape)
+    assert len(lead) <= 1 and b_lead in (tuple(lead), ()), (a.shape, b.shape)
+    assert not (layered and b_lead), (a.shape, b.shape)
     batch = lead[0] if lead else None
-    shared_b = batch is not None and b.ndim == 2
+    shared_b = batch is not None and not b_lead
     has_c = c is not None and beta != 0.0
     off = 1 if batch is not None else 0
 
+    # index maps take the scalar-prefetch refs (the layer) as trailing
+    # arguments; only B's reads them
+    if layered:
+        b_map, b_block = (lambda i, j, l, s: (s[0], l, j)), (None, bk, bn)
+    else:
+        b_map, b_block = (lambda i, j, l: (l, j)), (bk, bn)
     grid, in_maps, in_blocks, out_map, out_block, semantics, out_shape = \
         with_batch_axis(
             batch, (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(k, bk)),
-            [lambda i, j, l: (i, l), lambda i, j, l: (l, j),
-             lambda i, j, l: (i, j)],
-            [(bm, bk), (bk, bn), (bm, bn)],
-            lambda i, j, l: (i, j), (bm, bn),
+            [lambda i, j, l, *_: (i, l), b_map,
+             lambda i, j, l, *_: (i, j)],
+            [(bm, bk), b_block, (bm, bn)],
+            lambda i, j, l, *_: (i, j), (bm, bn),
             ("parallel", "parallel", "arbitrary"), (m, n),
             broadcast=(False, shared_b, False))
 
     operands = [a, b] + ([c] if has_c else [])
-    in_specs = [pl.BlockSpec(blk, f)
-                for blk, f in zip(in_blocks, in_maps)][: len(operands)]
+    specs = dict(
+        grid=grid,
+        in_specs=[pl.BlockSpec(blk, f)
+                  for blk, f in zip(in_blocks, in_maps)][: len(operands)],
+        out_specs=pl.BlockSpec(out_block, out_map),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)])
+    if layered:
+        specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **specs))
+        operands = [jnp.reshape(layer, (1,)).astype(jnp.int32)] + operands
     return pl.pallas_call(
         functools.partial(_gemm_kernel, alpha=alpha, beta=beta, k=k, bk=bk,
-                          has_c=has_c, off=off, shared_b=shared_b),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(out_block, out_map),
+                          has_c=has_c, off=off, shared_b=shared_b,
+                          layered=layered),
+        **specs,
         out_shape=jax.ShapeDtypeStruct(out_shape, a.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=compiler_params(semantics),
         interpret=interpret,
         name=name,

@@ -17,6 +17,10 @@ ragged contraction tail masked on both operands, out-of-bounds rows and
 columns dropped by Pallas on the store, ``pallas_call(name=...)``.  Grid
 ``(⌈N/bn⌉, visits, ⌈K/bk⌉)``: visits of one row tile are consecutive, so its
 output block stays in VMEM across the groups that share it.
+
+With a ``layer`` index, ``w`` is a stack ``(L, G, K, N)`` (an MoE segment's
+stacked expert weights) and the kernel reads layer ``layer``'s experts in
+place: the index is one more scalar prefetch, read by ``w``'s index map.
 """
 
 from __future__ import annotations
@@ -56,8 +60,11 @@ def group_metadata(group_sizes, m: int, bm: int):
     return offsets, group_ids, tile_ids.astype(jnp.int32), visits
 
 
-def _grouped_kernel(offsets, group_ids, tile_ids, visits, x_ref, w_ref,
-                    o_ref, acc_ref, *, k, bm, bk):
+def _grouped_kernel(offsets, group_ids, tile_ids, visits, *refs, k, bm, bk,
+                    layered):
+    """``refs`` = ([layer,] x, w, o, acc): the layer index, when given, is
+    read only by ``w``'s index map."""
+    x_ref, w_ref, o_ref, acc_ref = refs[1:] if layered else refs
     v, l = pl.program_id(1), pl.program_id(2)
 
     @pl.when(v < visits[0])
@@ -84,33 +91,50 @@ def _grouped_kernel(offsets, group_ids, tile_ids, visits, x_ref, w_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
-def grouped_gemm_pallas(x, w, group_sizes, *, bm: int = 128, bk: int = 128,
-                        bn: int = 128, interpret: bool = False):
+def grouped_gemm_pallas(x, w, group_sizes, *, layer=None, bm: int = 128,
+                        bk: int = 128, bn: int = 128,
+                        interpret: bool = False):
     """``x (M, K)`` rows sorted by group, ``w (G, K, N)``, ``group_sizes
-    (G,)`` int32 summing to M → ``(M, N)`` in ``x``'s dtype."""
+    (G,)`` int32 summing to M → ``(M, N)`` in ``x``'s dtype.  ``layer`` (an
+    int32 scalar) makes ``w`` a stack ``(L, G, K, N)`` of which the kernel
+    reads layer ``layer`` in place."""
     m, k = x.shape
-    G, k2, n = w.shape
+    G, k2, n = w.shape[-3:]
+    layered = layer is not None
+    assert w.ndim == 3 + layered, (w.shape, layered)
     assert k == k2 and group_sizes.shape == (G,), (x.shape, w.shape,
                                                     group_sizes.shape)
     offsets, group_ids, tile_ids, visits = group_metadata(group_sizes, m, bm)
     V = group_ids.shape[0]
+    prefetch = [offsets, group_ids, tile_ids, visits.reshape(1)]
 
-    def x_map(j, v, l, offsets, group_ids, tile_ids, visits):
+    def x_map(j, v, l, offsets, group_ids, tile_ids, visits, *_):
         return tile_ids[v], l
 
-    def w_map(j, v, l, offsets, group_ids, tile_ids, visits):
-        return group_ids[v], l, j
-
-    def o_map(j, v, l, offsets, group_ids, tile_ids, visits):
+    def o_map(j, v, l, offsets, group_ids, tile_ids, visits, *_):
         return tile_ids[v], j
 
+    if layered:
+        prefetch.append(jnp.reshape(layer, (1,)).astype(jnp.int32))
+
+        def w_map(j, v, l, offsets, group_ids, tile_ids, visits, layer):
+            return layer[0], group_ids[v], l, j
+
+        w_block = (None, 1, bk, bn)
+    else:
+        def w_map(j, v, l, offsets, group_ids, tile_ids, visits):
+            return group_ids[v], l, j
+
+        w_block = (1, bk, bn)
+
     return pl.pallas_call(
-        functools.partial(_grouped_kernel, k=k, bm=bm, bk=bk),
+        functools.partial(_grouped_kernel, k=k, bm=bm, bk=bk,
+                          layered=layered),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(prefetch),
             grid=(pl.cdiv(n, bn), V, pl.cdiv(k, bk)),
             in_specs=[pl.BlockSpec((bm, bk), x_map),
-                      pl.BlockSpec((1, bk, bn), w_map)],
+                      pl.BlockSpec(w_block, w_map)],
             out_specs=pl.BlockSpec((bm, bn), o_map),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
@@ -118,4 +142,4 @@ def grouped_gemm_pallas(x, w, group_sizes, *, bm: int = 128, bk: int = 128,
             ("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="grouped_gemm",
-    )(offsets, group_ids, tile_ids, visits.reshape(1), x, w)
+    )(*prefetch, x, w)

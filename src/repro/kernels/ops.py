@@ -9,7 +9,9 @@ Each op:
      the unpadded operands and ragged edge tiles are masked in-kernel, so no
      operand copy, pad, or result slice-back ever materializes (the old
      pad-to-block-multiple path is gone).  Operands carrying a leading batch
-     axis execute as one batched grid — one pallas_call per stack.
+     axis execute as one batched grid — one pallas_call per stack.  A
+     ``layer`` index makes the weight operand a stack of layer weights, of
+     which the kernel reads that layer in place (a model segment's scan).
 
 Each call writes profiler spans (``jax.profiler.TraceAnnotation``, inert
 without a profiler session) on the device trace's clock: ``blas.run_op``
@@ -28,6 +30,7 @@ import threading
 import time
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
@@ -133,13 +136,14 @@ def dims_of(op: str, shapes: tuple[tuple[int, ...], ...]) -> tuple[int, ...]:
 
     Leading batch axes are ignored: a stacked ``(B, m, k)`` operand yields
     the same dims as its per-item ``(m, k)`` slice, so stacked and unstacked
-    calls share one decision-cache key.
+    calls share one decision-cache key.  So is a weight's leading layer
+    axis (a call with ``layer``): it keys as the layer's own weight.
     """
     if op == "gemm":
         (m, k), (_, n) = shapes[0][-2:], shapes[1][-2:]
         return (m, k, n)
     if op == "grouped_gemm":          # x (m, k), w (g, k, n); sizes are data
-        (m, k), (g, _, n) = shapes[0], shapes[1]
+        (m, k), (g, _, n) = shapes[0], shapes[1][-3:]
         return (m, k, n, g)
     if op == "symm":
         (m, _), (_, n) = shapes[0][-2:], shapes[1][-2:]
@@ -317,15 +321,18 @@ def _rup(v: int, b: int) -> int:
 # a leading batch axis on every operand executes as one batched grid)
 # ---------------------------------------------------------------------------
 
-def gemm(a, b, c=None, *, alpha=1.0, beta=0.0, knob=None, runtime=None,
-         interpret: bool = False):
+def gemm(a, b, c=None, *, layer=None, alpha=1.0, beta=0.0, knob=None,
+         runtime=None, interpret: bool = False):
+    """``alpha·a@b + beta·c``; with ``layer``, ``b`` is a stack ``(L, k, n)``
+    and the kernel reads ``b[layer]`` in place.  The decision key is
+    ``(m, k, n)`` either way."""
     m, k = a.shape[-2:]
     n = b.shape[-1]
     kb = _select("gemm", (m, k, n), a.dtype, knob, runtime).dict
     bm, bk, bn = (min(kb["bm"], _rup(m, 128)), min(kb["bk"], _rup(k, 128)),
                   min(kb["bn"], _rup(n, 128)))
-    return _launch(gemm_pallas, a, b, c, bm=bm, bk=bk, bn=bn, alpha=alpha,
-                   beta=beta, interpret=interpret)
+    return _launch(gemm_pallas, a, b, c, layer=layer, bm=bm, bk=bk, bn=bn,
+                   alpha=alpha, beta=beta, interpret=interpret)
 
 
 def symm(a, b, c=None, *, alpha=1.0, beta=0.0, knob=None, runtime=None,
@@ -374,18 +381,20 @@ def trsm(a, b, *, alpha=1.0, knob=None, runtime=None,
                    interpret=interpret)
 
 
-def grouped_gemm(x, w, group_sizes, *, knob=None, runtime=None,
+def grouped_gemm(x, w, group_sizes, *, layer=None, knob=None, runtime=None,
                  interpret: bool = False):
     """``out[r] = x[r] @ w[g(r)]``: rows of ``x (m, k)`` sorted by group,
-    ``w (g, k, n)``, ``group_sizes (g,)`` summing to m.  The decision key is
-    ``(m, k, n, g)``; the group sizes are data and never part of it."""
+    ``w (g, k, n)``, ``group_sizes (g,)`` summing to m; with ``layer``,
+    ``w`` is a stack ``(L, g, k, n)`` read at ``layer`` in place.  The
+    decision key is ``(m, k, n, g)``; the group sizes are data and never
+    part of it."""
     m, k = x.shape
-    g, _, n = w.shape
+    g, _, n = w.shape[-3:]
     kb = _select("grouped_gemm", (m, k, n, g), x.dtype, knob, runtime).dict
     bm, bk, bn = (min(kb["bm"], _rup(m, 128)), min(kb["bk"], _rup(k, 128)),
                   min(kb["bn"], _rup(n, 128)))
-    return _launch(grouped_gemm_pallas, x, w, group_sizes, bm=bm, bk=bk,
-                   bn=bn, interpret=interpret)
+    return _launch(grouped_gemm_pallas, x, w, group_sizes, layer=layer,
+                   bm=bm, bk=bk, bn=bn, interpret=interpret)
 
 
 #: the pallas-path executors (what the ``pallas`` backend dispatches to)
@@ -397,7 +406,7 @@ _OPS = PALLAS_OPS   # back-compat alias
 def run_op(op: str, operands: tuple, *, backend: str = "pallas",
            knob: Optional[Knob] = None,
            runtime: Optional[AdsalaRuntime] = None,
-           stacked: Optional[bool] = None, **kw):
+           stacked: Optional[bool] = None, layer=None, **kw):
     """Execute ``op`` through the backend registry.
 
     Dispatch resolves the requested backend with a graceful fallback chain
@@ -414,6 +423,12 @@ def run_op(op: str, operands: tuple, *, backend: str = "pallas",
     or copy.  ``stacked`` forces the interpretation when auto-detection by
     rank is ambiguous.
 
+    ``layer`` (an int32 scalar, ``gemm`` and ``grouped_gemm`` only) says
+    that ``operands[1]`` is a stack of layer weights and the call uses
+    layer ``layer`` of it.  A backend that reads weight stacks (``pallas``)
+    reads that layer in place; any other gets the layer sliced out and runs
+    as without the stack.
+
     The whole call runs under the ``blas.run_op`` profiler span; while a
     profiler session is on, the span carries the op and its dims.
     """
@@ -424,13 +439,19 @@ def run_op(op: str, operands: tuple, *, backend: str = "pallas",
         span = TraceAnnotation("blas.run_op")
     with span:
         return _run_op(op, operands, backend=backend, knob=knob,
-                       runtime=runtime, stacked=stacked, **kw)
+                       runtime=runtime, stacked=stacked, layer=layer, **kw)
 
 
 def _run_op(op: str, operands: tuple, *, backend: str,
             knob: Optional[Knob], runtime: Optional[AdsalaRuntime],
-            stacked: Optional[bool], **kw):
+            stacked: Optional[bool], layer, **kw):
     be = _backend_resolver()(backend)
+    if layer is not None:
+        if be.reads_weight_stacks:
+            kw["layer"] = layer
+        else:
+            operands = (operands[0], jax.lax.dynamic_index_in_dim(
+                operands[1], layer, keepdims=False)) + tuple(operands[2:])
     if stacked is None:
         stacked = getattr(operands[0], "ndim", 2) == 3
     # chaos seam: a fault plan on the runtime can crash the dispatch exactly

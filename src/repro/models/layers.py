@@ -19,7 +19,8 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from .sharding import ShardingRules, DEFAULT_RULES, constrain
 
-__all__ = ["Ctx", "init_linear", "linear", "routed_matmul", "init_norm",
+__all__ = ["Ctx", "LayerSlice", "weight_array", "routed_operand",
+           "init_linear", "linear", "routed_matmul", "init_norm",
            "rmsnorm", "init_embedding", "embed", "rope", "yarn",
            "yarn_softmax_factor", "init_attention",
            "attention", "init_mlp", "mlp", "cross_entropy",
@@ -34,6 +35,12 @@ class Ctx:
     runtime: object = None            # AdsalaRuntime | None (None → global)
 
     def cast(self, x):
+        """``x`` in the compute dtype.  A :class:`LayerSlice` already in it
+        stays a view of its stack; one that needs a cast is sliced out."""
+        if isinstance(x, LayerSlice):
+            if x.dtype == jnp.dtype(self.cfg.compute_dtype):
+                return x
+            x = x.array()
         return x.astype(self.cfg.compute_dtype)
 
     def cons(self, x, *names):
@@ -47,6 +54,58 @@ class Ctx:
         matmuls so GSPMD can partition them)."""
         return (self.cfg.use_pallas_gemm and self.mesh is None
                 and x.ndim >= 2)
+
+
+class LayerSlice:
+    """Layer ``index`` of a segment's stacked weight ``stack`` ``(L, ...)``,
+    left unsliced: a routed matmul hands the stack and the index to its
+    kernel, which reads the layer's tiles in place.  ``shape``, ``ndim``
+    and ``dtype`` are the layer's; :meth:`array` slices it out for any other
+    consumer."""
+
+    __slots__ = ("stack", "index")
+
+    def __init__(self, stack, index):
+        self.stack, self.index = stack, index
+
+    @property
+    def shape(self):
+        return self.stack.shape[1:]
+
+    @property
+    def ndim(self):
+        return self.stack.ndim - 1
+
+    @property
+    def dtype(self):
+        return self.stack.dtype
+
+    def array(self):
+        return jax.lax.dynamic_index_in_dim(self.stack, self.index,
+                                            keepdims=False)
+
+
+def weight_array(w):
+    """``w`` as an array: a :class:`LayerSlice` sliced out of its stack."""
+    return w.array() if isinstance(w, LayerSlice) else w
+
+
+def routed_operand(w, ctx: Ctx) -> tuple:
+    """``(weight operand, run_op keywords)`` for a routed call on ``w``: a
+    :class:`LayerSlice` passes its whole stack with ``layer=``.  Counts the
+    call on the runtime's ``routed_in_place`` or ``routed_materialised``
+    (at trace time, once per traced call site)."""
+    from repro.core.runtime import global_runtime
+    rt = ctx.runtime if ctx.runtime is not None else global_runtime()
+    in_place = isinstance(w, LayerSlice)
+    rt.count_routed(in_place)
+    kw = {}
+    if ctx.cfg.gemm_interpret is not None:
+        kw["interpret"] = ctx.cfg.gemm_interpret
+    if in_place:
+        kw["layer"] = w.index
+        return w.stack, kw
+    return w, kw
 
 
 #: the routed gemm's least row block: ``kernels/ops.py:gemm`` rounds bm up
@@ -67,17 +126,16 @@ def routed_matmul(x, w, ctx: Ctx):
     stack against the shared weight: folding them reads it no fewer times
     when bm divides m, and XLA then lays out the ops around the 2-D result
     worse (relayout copies of q, k and v: 7% more device time in a
-    qwen1.5-4b prefill of 4 x 1024 on a TPU v5e).  The interpret/compiled
-    kernel mode comes from ``cfg.gemm_interpret`` (``None`` → the backend
-    auto-detects the host).  Falls back to plain ``x @ w`` when the config
-    does not route.
+    qwen1.5-4b prefill of 4 x 1024 on a TPU v5e).  ``w`` may be a
+    :class:`LayerSlice`, which the kernel reads in place.  The
+    interpret/compiled kernel mode comes from ``cfg.gemm_interpret``
+    (``None`` → the backend auto-detects the host).  Falls back to plain
+    ``x @ w`` when the config does not route.
     """
     if not ctx.routes_gemm(x) or w.ndim != 2:
-        return x @ w
+        return x @ weight_array(w)
     from repro.kernels import ops as kops
-    kw = {}
-    if ctx.cfg.gemm_interpret is not None:
-        kw["interpret"] = ctx.cfg.gemm_interpret
+    w, kw = routed_operand(w, ctx)
     m, d = x.shape[-2:]
     x2 = (x.reshape(-1, d) if m < _ROW_TILE or x.ndim == 2
           else x.reshape(-1, m, d))

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from .layers import Ctx, init_linear, init_norm, linear, rmsnorm, rope, \
-    flash_attention, yarn_softmax_factor
+    flash_attention, weight_array, yarn_softmax_factor
 
 __all__ = ["init_mla", "mla_attention", "init_mla_cache"]
 
@@ -139,7 +139,8 @@ def _mla(p: dict, x, ctx: Ctx, cache):
         return out, new_cache
 
     # ---- decode: absorbed form over the latent cache -----------------------
-    w_b = ctx.cast(p["wkv_b"]["w"]).reshape(cfg.kv_lora, h, nope + vd)
+    w_b = ctx.cast(weight_array(p["wkv_b"]["w"])).reshape(cfg.kv_lora, h,
+                                                          nope + vd)
     w_kb, w_vb = w_b[..., :nope], w_b[..., nope:]
     # absorb: q_c[b,s,h,l] = Σ_n q_nope·W_kb[l,h,n]
     q_c = jnp.einsum("bshn,lhn->bshl", q_nope, w_kb)

@@ -32,7 +32,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from .layers import Ctx, init_linear, init_mlp, linear, mlp
+from .layers import (Ctx, init_linear, init_mlp, linear, mlp,
+                     routed_operand, weight_array)
 
 __all__ = ["init_moe", "moe_ffn", "moe_layer"]
 
@@ -58,14 +59,14 @@ def _expert_matmul(t, w, ctx: Ctx):
     """Per-expert matmul ``einsum("becd,edf->becf", t, w)`` with optional
     ADSALA dispatch: when the config routes GEMMs, the (B,E,C,·) slab is
     folded to an expert-major stack (E, B·C, ·) and executed as one stacked
-    ``run_op("gemm", ...)`` call — one knob decision covers all experts."""
+    ``run_op("gemm", ...)`` call — one knob decision covers all experts.
+    A layer's expert stack is sliced out: the stacked gemm batches ``w``."""
+    w = weight_array(w)
     if not ctx.routes_gemm(t):
         return jnp.einsum("becd,edf->becf", t, w)
     from repro.kernels import ops as kops
     B, E, C, D = t.shape
-    kw = {}
-    if ctx.cfg.gemm_interpret is not None:
-        kw["interpret"] = ctx.cfg.gemm_interpret
+    w, kw = routed_operand(w, ctx)
     t3 = t.swapaxes(0, 1).reshape(E, B * C, D)
     y = kops.run_op("gemm", (t3, w), backend=ctx.cfg.gemm_backend,
                     runtime=ctx.runtime, stacked=True, **kw)
@@ -89,7 +90,8 @@ def _positions_in_expert(e_flat: jax.Array) -> jax.Array:
 
 def _route(p: dict, x, cfg: ModelConfig):
     """(probs (B,S,E), top-k weights (B,S,K), top-k experts (B,S,K)), f32."""
-    logits = jnp.matmul(x.astype(jnp.float32), p["router"]["w"],
+    logits = jnp.matmul(x.astype(jnp.float32),
+                        weight_array(p["router"]["w"]),
                         precision=jax.lax.Precision.HIGHEST)       # (B,S,E)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = jax.lax.top_k(probs, cfg.top_k)                # greedy
@@ -106,15 +108,14 @@ def _aux_loss(probs, top_e, E: int):
 
 
 def _grouped_matmul(rows, w, sizes, ctx: Ctx):
-    """``rows[r] @ w[expert(r)]`` over rows sorted by expert."""
+    """``rows[r] @ w[expert(r)]`` over rows sorted by expert; ``w`` may be
+    a :class:`~repro.models.layers.LayerSlice`, read in place."""
     if not ctx.routes_gemm(rows):
-        return jax.lax.ragged_dot(rows, w, sizes,
+        return jax.lax.ragged_dot(rows, weight_array(w), sizes,
                                   preferred_element_type=jnp.float32
                                   ).astype(rows.dtype)
     from repro.kernels import ops as kops
-    kw = {}
-    if ctx.cfg.gemm_interpret is not None:
-        kw["interpret"] = ctx.cfg.gemm_interpret
+    w, kw = routed_operand(w, ctx)
     return kops.run_op("grouped_gemm", (rows, w, sizes),
                        backend=ctx.cfg.gemm_backend, runtime=ctx.runtime,
                        **kw)

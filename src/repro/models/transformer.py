@@ -6,6 +6,14 @@ per-segment params are stacked along a leading layer axis and consumed by
 what keeps 512-device dry-run compiles tractable.  Decode caches are pytrees
 stacked the same way and threaded through the scan as xs/ys.
 
+Where the matmuls are routed (one host, ``use_pallas_gemm``) the scan runs
+over the layer index and the segment's params stay whole, loop-invariant:
+each routed weight reaches its kernel as a :class:`~.layers.LayerSlice`
+and is read in place, since a Pallas call cannot fuse a slice of its
+operand and XLA would otherwise copy every layer's weights out of the stack
+on every forward pass.  Everything else takes its layer with
+``dynamic_index_in_dim``, which XLA fuses into the consumer.
+
 Block kinds:
   attn        pre-LN GQA attention + MLP            (dense, vlm backbone)
   moe         pre-LN attention (GQA or MLA) + MoE   (granite-moe, deepseek)
@@ -27,9 +35,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from .layers import (Ctx, attention, cross_entropy, embed, init_attention,
-                     init_embedding, init_mlp, init_norm, linear, mlp,
-                     rmsnorm, routed_matmul)
+from .layers import (Ctx, LayerSlice, attention, cross_entropy, embed,
+                     init_attention, init_embedding, init_mlp, init_norm,
+                     linear, mlp, rmsnorm, routed_matmul)
 from .mamba2 import init_mamba2, init_mamba2_state, mamba2_mixer
 from .mla import init_mla, init_mla_cache, mla_attention
 from .moe import init_moe, moe_layer
@@ -240,12 +248,39 @@ def _scan_stack(body, carry, xs, repeat: int, cfg: ModelConfig):
     return jax.lax.scan(_maybe_remat(body, cfg), carry, xs)
 
 
+#: an MoE layer's expert stacks, ``(E, d, f)`` a layer: the grouped gemm's
+#: weights
+_EXPERT_STACKS = ("wg", "wu", "wd")
+
+
+def _routed_weight(path, stack) -> bool:
+    """Whether a routed matmul consumes this stacked leaf: a linear's 2-D
+    weight (``w``, stacked ``(L, k, n)``) or an MoE layer's expert stack
+    (stacked ``(L, E, k, n)``)."""
+    name = getattr(path[-1], "key", None)
+    return ((name == "w" and stack.ndim == 3)
+            or (name in _EXPERT_STACKS and stack.ndim == 4))
+
+
+def _layer_params(seg_p, i):
+    """Layer ``i`` of a segment's stacked params: each routed weight a
+    :class:`LayerSlice` of its stack, every other leaf sliced out."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, t: (LayerSlice(t, i) if _routed_weight(path, t)
+                         else jax.lax.dynamic_index_in_dim(t, i,
+                                                           keepdims=False)),
+        seg_p)
+
+
 def _run_segments(params, x, ctx: Ctx, caches=None, *, x0=None,
                   enc_out=None):
     """Scan every segment; returns (x, new_caches|None, aux_sum, rows):
     ``rows`` holds each MoE layer's token-slots per expert, ``(L_moe, E)``
-    int32 over the MoE segments in order, or None without one."""
+    int32 over the MoE segments in order, or None without one.  A routed
+    config scans the layer index with the params closed over (module
+    docstring); any other scans the stacked params."""
     cfg = ctx.cfg
+    in_place = ctx.routes_gemm(x)
     aux_total = jnp.zeros((), jnp.float32)
     new_caches = [] if caches is not None else None
     rows = []
@@ -254,12 +289,11 @@ def _run_segments(params, x, ctx: Ctx, caches=None, *, x0=None,
         seg_p = params["segments"][si]
         seg_c = caches[si] if caches is not None else None
 
-        def body(carry, xs, kind=kind):
+        def body(carry, xs, kind=kind, seg_p=seg_p):
             h, aux = carry
-            if caches is not None:
-                pp, cc = xs
-            else:
-                pp, cc = xs, None
+            pp, cc = xs
+            if in_place:
+                pp = _layer_params(seg_p, pp)
             h, nc, a, r = _apply_block(kind, pp, h, ctx, cc, shared=shared,
                                        x0=x0, enc_out=enc_out)
             # inter-block activation layout (SP shards seq here) — this is
@@ -267,7 +301,7 @@ def _run_segments(params, x, ctx: Ctx, caches=None, *, x0=None,
             h = ctx.cons(h, "batch", "seq", "embed")
             return (h, aux + a), (nc, r)
 
-        xs = (seg_p, seg_c) if caches is not None else seg_p
+        xs = (jnp.arange(repeat) if in_place else seg_p, seg_c)
         (x, aux_total), (seg_nc, seg_rows) = _scan_stack(
             body, (x, aux_total), xs, repeat, cfg)
         if caches is not None:

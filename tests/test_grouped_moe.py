@@ -64,6 +64,21 @@ def test_grouped_gemm_tiles_agree(bm, bk, bn):
     assert rel_err(got, oracle("grouped_gemm", (x, w, s))) < 5e-6
 
 
+@pytest.mark.parametrize("case", sorted(GROUPS))
+def test_grouped_gemm_reads_a_layer_of_a_stack(case):
+    """Given a stack of layers' expert weights ``(L, G, k, n)`` and a layer
+    index, the kernel equals the kernel on that layer's slice bit for bit,
+    empty groups included."""
+    sizes, k, n = GROUPS[case]
+    x, w, s = (jnp.asarray(v) for v in _operands(sizes, k, n))
+    stack = jnp.stack([w, -0.5 * w, w[::-1]])
+    for i in range(stack.shape[0]):
+        want = grouped_gemm_pallas(x, stack[i], s, interpret=True)
+        got = grouped_gemm_pallas(x, stack, s, layer=jnp.int32(i),
+                                  interpret=True)
+        assert jnp.array_equal(got, want), (case, i)
+
+
 def test_grouped_gemm_bfloat16_accumulates_in_float32():
     sizes, k, n = [700, 0, 324], 1024, 128
     xs = _operands(sizes, k, n, dtype=jnp.bfloat16)

@@ -19,7 +19,12 @@ Contracts:
     ``(B, d, n)`` decode GEMMs — with zero model evaluations;
   * install → ``select_many`` → ``save_decision_cache`` offline, then a
     fresh runtime hydrated from the registry serves prefill + decode with
-    **zero** runtime model evaluations.
+    **zero** runtime model evaluations;
+  * a routed segment's weights are read in place from their stack: the
+    gemm given a stack and a layer index equals the gemm on the sliced
+    layer bit for bit, asks the same decision key and runs the same knob,
+    and a routed decode trace counts its seven layer weights in place and
+    the output head materialised.
 """
 
 from __future__ import annotations
@@ -44,6 +49,7 @@ from repro.models.layers import Ctx, routed_matmul
 from repro.roofline.costing import prune_dominated_candidates
 from repro.roofline.harvest import (Recorder, dot_call_sites,
                                     harvest_decision_keys)
+from repro.core.runtime import DEFAULT_BACKEND
 
 #: dense / MoE / MLA — one routed arch per family
 ARCHS = ("qwen15_4b", "granite_moe_3b", "deepseek_v2_lite")
@@ -288,6 +294,144 @@ def test_execute_stacked_shared_weight_other_backends(backend):
 def test_dims_of_ignores_leading_batch():
     assert ops.dims_of("gemm", ((5, 33, 64), (64, 96))) == (33, 64, 96)
     assert ops.dims_of("gemm", ((33, 64), (64, 96))) == (33, 64, 96)
+
+
+# ---------------------------------------------------------------------------
+# a layer's weight read in place from its segment's stack
+# ---------------------------------------------------------------------------
+
+#: (activation shape, stacked weight (L, k, n)): a 2-D activation, a batched
+#: one against the shared stack, a ragged contraction (k not a multiple of
+#: bk), and ragged rows and columns
+STACK_CASES = {"2d": ((200, 256), (3, 256, 384)),
+               "batched-shared": ((2, 130, 256), (3, 256, 128)),
+               "ragged-k": ((64, 200), (2, 200, 256)),
+               "ragged-mn": ((33, 128), (4, 128, 160))}
+
+
+@pytest.mark.parametrize("case", sorted(STACK_CASES))
+def test_gemm_pallas_reads_a_layer_of_a_stack(case):
+    """The gemm given the stack and a layer index equals the gemm given
+    that layer's slice, bit for bit, for every layer."""
+    a_shape, w_shape = STACK_CASES[case]
+    a = jax.random.normal(jax.random.PRNGKey(0), a_shape)
+    stack = jax.random.normal(jax.random.PRNGKey(1), w_shape)
+    for i in range(w_shape[0]):
+        want = gemm_pallas(a, stack[i], bm=128, bk=128, bn=128,
+                           interpret=True)
+        got = gemm_pallas(a, stack, layer=jnp.int32(i), bm=128, bk=128,
+                          bn=128, interpret=True)
+        assert jnp.array_equal(got, want), (case, i)
+
+
+class _KeyedKnobs(Recorder):
+    """Records each decision key and answers with a knob that depends on
+    the key, so a call that keyed differently would run other tiles."""
+
+    def select_or_default(self, op, dims, dtype_bytes, default, *,
+                          backend=DEFAULT_BACKEND):
+        super().select_or_default(op, dims, dtype_bytes, default,
+                                  backend=backend)
+        cands = ops.knob_space_for(op).candidates
+        return cands[sum(dims) % len(cands)]
+
+
+def _stack_operands(op):
+    rng = np.random.default_rng(0)
+    if op == "gemm":
+        return (rng.standard_normal((40, 200)).astype(np.float32),
+                rng.standard_normal((3, 200, 300)).astype(np.float32))
+    x, w, sizes = resolve_backend("pallas").make_operands(
+        "grouped_gemm", (300, 136, 200, 6))
+    return x, np.stack([w, 2 * w]), sizes
+
+
+@pytest.mark.parametrize("op", ["gemm", "grouped_gemm"])
+def test_stacked_weight_call_keys_and_runs_as_its_slice(op, monkeypatch):
+    """``run_op`` given a stack and ``layer=`` asks the decision key of the
+    sliced call, runs the knob chosen for it, and returns its result bit for
+    bit: the frozen installs choose as before."""
+    launched = []
+    real = ops._launch
+
+    def spy(kernel, *args, **kw):
+        launched.append((kw["bm"], kw["bk"], kw["bn"]))
+        return real(kernel, *args, **kw)
+
+    monkeypatch.setattr(ops, "_launch", spy)
+    x, stack, *rest = (jnp.asarray(v) for v in _stack_operands(op))
+    layer = jnp.int32(1)
+    assert ops.dims_of(op, tuple(v.shape for v in (x, stack, *rest))) == \
+        ops.dims_of(op, tuple(v.shape for v in (x, stack[1], *rest)))
+    sliced, in_place = _KeyedKnobs(), _KeyedKnobs()
+    want = ops.run_op(op, (x, stack[1], *rest), runtime=sliced,
+                      interpret=True)
+    got = ops.run_op(op, (x, stack, *rest), layer=layer, runtime=in_place,
+                     interpret=True)
+    assert in_place.keys == sliced.keys and len(sliced.keys) == 1
+    assert launched[0] == launched[1]
+    assert jnp.array_equal(got, want)
+    # the ref backend has no in-place read: it gets the layer sliced out
+    ref = ops.run_op(op, (x, stack, *rest), backend="ref", layer=layer)
+    assert jnp.array_equal(ref, ops.run_op(op, (x, stack[1], *rest),
+                                           backend="ref"))
+
+
+def test_harvest_keys_match_the_sliced_programs():
+    """The routed programs ask the same decision keys whether their kernels
+    read each layer in place (pallas) or get it sliced out (ref)."""
+    cfg = _cfg("deepseek_v2_lite")
+
+    def keys(backend):
+        got = harvest_decision_keys(
+            dataclasses.replace(cfg, gemm_backend=backend), batch_size=2,
+            seq_len=16)
+        return sorted(k[1:] for k in got)
+
+    assert keys("pallas") == keys("ref")
+
+
+def _decode_trace(cfg, *, mesh=None) -> AdsalaRuntime:
+    rt = AdsalaRuntime()
+    params = jax.eval_shape(lambda: tf.init_params(jax.random.PRNGKey(0),
+                                                   cfg))
+    caches = jax.eval_shape(lambda: tf.init_decode_state(cfg, 2, 20))
+    token = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    jax.eval_shape(lambda p, t, c: tf.decode_step(p, t, c, cfg, mesh=mesh,
+                                                  runtime=rt),
+                   params, token, caches)
+    return rt
+
+
+def test_routed_decode_reads_layer_weights_in_place():
+    """One trace of qwen's decode step: the segment's seven routed weights
+    (q, k, v, o, gate, up, down) in place, the output head, which is not
+    stacked, materialised."""
+    cfg = dataclasses.replace(_cfg("qwen15_4b"), use_pallas_gemm=True)
+    assert len(cfg.segments()) == 1 and not cfg.tie_embeddings
+    stats = _decode_trace(cfg).stats
+    assert (stats.routed_in_place, stats.routed_materialised) == (7, 1)
+
+
+@pytest.mark.parametrize("where", ["mesh", "unrouted"])
+def test_nothing_read_in_place_off_the_routed_path(where, monkeypatch):
+    """Under a mesh (GSPMD partitions plain matmuls) and in an unrouted
+    config the scan keeps slicing the stacked params: no layer view is made
+    and no call is routed."""
+    views = []
+    real = tf._layer_params
+    monkeypatch.setattr(tf, "_layer_params",
+                        lambda *a: views.append(1) or real(*a))
+    cfg = _cfg("qwen15_4b")
+    mesh = None
+    if where == "mesh":
+        cfg = dataclasses.replace(cfg, use_pallas_gemm=True)
+        auto = jax.sharding.AxisType.Auto
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(auto, auto))
+    stats = _decode_trace(cfg, mesh=mesh).stats
+    assert (stats.routed_in_place, stats.routed_materialised) == (0, 0)
+    assert not views
 
 
 # ---------------------------------------------------------------------------

@@ -3,9 +3,10 @@
 The TPU compiler is installed with jaxlib and compiles for a described
 topology: it refuses what interpret mode accepts (unaligned slices, kernels
 that need more VMEM than a core has, programs that do not fit HBM).  These
-tests compile the Pallas kernels of the main path at real widths, and one
-whole routed qwen1.5-4b prefill step at full width, for ``v5e:2x2``'s first
-chip.  Nothing executes.
+tests compile the Pallas kernels of the main path at real widths, and
+whole routed programs at full width (qwen1.5-4b's prefill and decode step,
+DeepSeek-V2-Lite's first pipeline stage's prefill), for ``v5e:2x2``'s
+first chip.  Nothing executes.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -16,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -169,30 +171,61 @@ def test_grouped_gemm_compiles_for_v5e(one_chip, m, k, n, bm, bk, bn):
     assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
 
 
-def test_routed_deepseek_v2_lite_stage_prefill_fits_one_v5e(one_chip):
+def _on_chip(tree, one_chip):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), tree)
+
+
+@pytest.fixture(scope="module")
+def deepseek_stage_prefill(one_chip):
     """The benchmark cell's program: the routed prefill of DeepSeek-V2-Lite's
     first pipeline stage (9 layers at published widths, all 64 experts,
-    bf16) over 4 x 2048 tokens into a 2056-long latent cache; the grouped
-    and dense kernels compile and everything fits one chip's HBM."""
+    bf16) over 4 x 2048 tokens into a 2056-long latent cache, compiled."""
     from repro.configs import get_config
     from repro.models import init_decode_state, init_params, prefill
 
     cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"), n_layers=9,
                               param_dtype="bfloat16", use_pallas_gemm=True,
                               gemm_interpret=False)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), tree)
-
-    params = on_chip(jax.eval_shape(
-        lambda: init_params(jax.random.PRNGKey(0), cfg)))
-    caches = on_chip(jax.eval_shape(
-        lambda: init_decode_state(cfg, 4, 2056, dtype=jnp.bfloat16)))
-    batch = on_chip({"tokens": jax.ShapeDtypeStruct((4, 2048), jnp.int32)})
+    params = _on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+    caches = _on_chip(jax.eval_shape(
+        lambda: init_decode_state(cfg, 4, 2056, dtype=jnp.bfloat16)),
+        one_chip)
+    batch = _on_chip({"tokens": jax.ShapeDtypeStruct((4, 2048), jnp.int32)},
+                     one_chip)
     step = jax.jit(lambda p, b, c: prefill(p, b, c, cfg, return_rows=True),
                    donate_argnums=(2,))
-    compiled = step.lower(params, batch, caches).compile()
+    return step.lower(params, batch, caches).compile()
+
+
+@pytest.fixture(scope="module")
+def qwen15_4b_decode(one_chip):
+    """The decode cell's program: one routed greedy step of qwen1.5-4b at
+    published width and depth, 4 sequences against a 1096-long cache,
+    compiled."""
+    from repro.configs import get_config
+    from repro.models import decode_step, init_decode_state, init_params
+
+    cfg = dataclasses.replace(get_config("qwen1.5-4b"),
+                              param_dtype="bfloat16", use_pallas_gemm=True,
+                              gemm_interpret=False)
+    params = _on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+    caches = _on_chip(jax.eval_shape(
+        lambda: init_decode_state(cfg, 4, 1096, dtype=jnp.bfloat16)),
+        one_chip)
+    token = jax.ShapeDtypeStruct((4, 1), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, t, c: decode_step(p, t, c, cfg),
+                   donate_argnums=(2,))
+    return step.lower(params, token, caches).compile()
+
+
+def test_routed_deepseek_v2_lite_stage_prefill_fits_one_v5e(
+        deepseek_stage_prefill):
+    """The grouped and dense kernels of the stage's prefill compile and
+    everything fits one chip's HBM."""
+    compiled = deepseek_stage_prefill
     assert 'name="grouped_gemm"' in compiled.as_text() or \
         "grouped_gemm" in compiled.as_text()
     mem = compiled.memory_analysis()
@@ -200,6 +233,42 @@ def test_routed_deepseek_v2_lite_stage_prefill_fits_one_v5e(one_chip):
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 10e9      # 5.2e9 bf16 parameters
     assert total < HBM_BYTES
+
+
+def _ops_with_output(hlo: str, shape: str) -> set[str]:
+    """The HLO ops whose output is a bf16 array of ``shape``."""
+    return set(re.findall(r"= bf16\[%s\]\{[^}]*\} ([\w-]+)\(" % shape, hlo))
+
+
+#: (program, one layer's routed weight shapes, their stacks' shapes, the
+#: compiled temporaries of the program that sliced every layer's weights
+#: out of their stacks: jaxlib 0.9.0, default knobs)
+IN_PLACE = {
+    "qwen15_4b_decode": (("2560,2560", "2560,6912", "6912,2560"),
+                         ("40,2560,2560", "40,2560,6912", "40,6912,2560"),
+                         3_488_468_992),
+    "deepseek_stage_prefill": (("64,2048,1408", "64,1408,2048"),
+                               ("8,64,2048,1408", "8,64,1408,2048"),
+                               3_495_348_224),
+}
+
+
+@pytest.mark.parametrize("program", sorted(IN_PLACE))
+def test_routed_weights_are_read_in_place_on_v5e(program, request):
+    """A routed kernel reads its layer's weight tiles from the segment's
+    stack: no op writes one layer's weight (a Pallas call cannot fuse the
+    slice of its operand, so a slice would be a copy on every forward
+    pass), a stack is only ever a parameter or a loop value, never copied,
+    and the temporaries are no larger than the slicing program's."""
+    compiled = request.getfixturevalue(program)
+    hlo = compiled.as_text()
+    layer_shapes, stack_shapes, sliced_temp = IN_PLACE[program]
+    for shape in layer_shapes:
+        assert not _ops_with_output(hlo, shape), shape
+    for shape in stack_shapes:
+        assert _ops_with_output(hlo, shape) <= {"parameter",
+                                                 "get-tuple-element"}, shape
+    assert compiled.memory_analysis().temp_size_in_bytes <= sliced_temp
 
 
 @pytest.mark.parametrize("m,k,n,bk", [(4, 10944, 2048, 512),
