@@ -20,8 +20,7 @@ from .preprocess import PreprocessPipeline, YeoJohnsonTransformer
 from .fastpath import CompiledPredictor, compile_predictor
 from .lof import lof_scores, remove_outliers
 from .selection import ModelReport, evaluate_candidates, select_best
-from .tuner import (TunedSubroutine, attach_knn_coreset, install_backend,
-                    install_subroutine)
+from .tuner import TunedSubroutine, install_backend, install_subroutine
 from .runtime import (AdsalaRuntime, BackendStats, BucketStats, RuntimeStats,
                       global_runtime)
 from .registry import (ModelRegistry, load_subroutine, pack_state,
@@ -37,7 +36,6 @@ __all__ = [
     "compile_predictor", "lof_scores",
     "remove_outliers", "ModelReport", "evaluate_candidates", "select_best",
     "TunedSubroutine", "install_subroutine", "install_backend",
-    "attach_knn_coreset",
     "AdsalaRuntime", "BackendStats", "BucketStats", "RuntimeStats",
     "global_runtime", "ModelRegistry", "load_subroutine", "pack_state",
     "save_subroutine", "unpack_state", "DistilledTree",

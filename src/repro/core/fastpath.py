@@ -33,8 +33,6 @@ call.  :func:`compile_predictor` folds all of that into a
   KNN                       :class:`_ScreenedKNN` — exact lookup built
                             at compile time: BLAS-speed screen with a
                             certified margin + exact canonical rescore
-                            (opt-in coreset subsample for the
-                            inexact-but-faster mode)
   ========================  ==============================================
 
 Correctness bar: for any dims, :meth:`CompiledPredictor.select` returns the
@@ -44,18 +42,6 @@ association order, float64 throughout) restricted to the surviving columns.
 Tree descent and k-NN lookup are comparisons plus table gathers, so the
 re-layouts cannot perturb a single bit.  ``tests/test_fastpath.py`` asserts
 exact equality of the predicted-time vectors on every persisted artifact.
-
-Two opt-in, install-analysis-backed shortcuts ride on the artifact:
-
-* dominated-candidate prune (``prune=True``) drops candidates the tuned
-  model never argmin-selects over the install-time dataset's dims
-  (persisted as ``fast_live_idx``); ``prune="band"`` instead keeps every
-  candidate whose predicted time ever comes within ``fast_band_pct`` % of
-  the winner (a superset — robust to interpolation wobble).  Dims outside
-  the dataset's bounding box fall back to full-K evaluation.
-* KNN coreset (``coreset=True``) serves the k-NN lookup from a persisted
-  subsample (``fast_knn_coreset``) — faster, deliberately *not* bit-exact,
-  and never enabled by default.
 """
 
 from __future__ import annotations
@@ -98,8 +84,8 @@ class _PredicatedTree:
     identical routing for every input including ``inf``/NaN.  Bit-exact:
     comparisons and table lookups only.
 
-    Layouts are materialised per row count (the compiled K, the pruned
-    live-K) and capped — oversized requests (large batches) fall through to
+    Layouts are materialised per row count (the compiled K, a batch's rows)
+    and capped — oversized requests (large batches) fall through to
     the shared :class:`_StackedForest` path, which is equally exact.
     """
 
@@ -266,17 +252,10 @@ class _ScreenedKNN:
 
     Non-finite queries (feature overflow at extreme dims) skip the screen
     and rescore against every point — still exact, just slower.
-
-    ``coreset`` mode runs the same lookup over a persisted subsample —
-    equivalent to a KNN *fit on that subsample* (deliberately inexact
-    w.r.t. the full model; opt-in only).
     """
 
-    def __init__(self, model, *, coreset_idx=None) -> None:
+    def __init__(self, model) -> None:
         X, y = model.X_, model.y_
-        if coreset_idx is not None:
-            sel = np.asarray(coreset_idx, dtype=np.int64)
-            X, y = X[sel], y[sel]
         self.model = model
         self.k = int(model.k)
         self.weights = str(model.weights)
@@ -429,24 +408,21 @@ def _invert_monotone_thresholds(tfun, thr: np.ndarray,
 # model folding
 # ---------------------------------------------------------------------------
 
-def _fold_model(model, knn_coreset=None):
+def _fold_model(model):
     """``(predict, lowering, engine)``: the model's predict lowered to the
     uniform table-driven form, a short name of the lowering used (for
     introspection and the decision bench), and the table engine behind it
     (None for plain ``model.predict``).  Combination rules replicate the
     reference predicts operation for operation, so outputs are
-    bit-identical (except the opt-in KNN coreset mode, which is documented
-    as inexact)."""
+    bit-identical."""
     single = getattr(model, "tree_", None)
     if single is not None and hasattr(single, "predicated_arrays") \
             and hasattr(single, "depth"):
         tree = _PredicatedTree(single)
         return tree.predict, "predicated-tree", tree
     if getattr(model, "NAME", None) == "KNN" and model.X_ is not None:
-        mode = "screened-knn" if knn_coreset is None \
-            else "screened-knn-coreset"
-        knn = _ScreenedKNN(model, coreset_idx=knn_coreset)
-        return knn.predict, mode, knn
+        knn = _ScreenedKNN(model)
+        return knn.predict, "screened-knn", knn
     trees = getattr(model, "trees_", None)
     if not trees or not all(hasattr(t, "predicated_arrays")
                             and hasattr(t, "depth") for t in trees):
@@ -491,17 +467,12 @@ class CompiledPredictor:
     """
 
     def __init__(self, op: str, knob_space, pipeline, model,
-                 log_target: bool, *, live_idx=None, dims_lo=None,
-                 dims_hi=None, prune=False, band_idx=None,
-                 knn_coreset=None, coreset: bool = False) -> None:
+                 log_target: bool) -> None:
         self.op = op
         self.knob_space = knob_space
         self.model = model
         self.artifact_version = 0       # stamped by compile_predictor
-        self.coreset = bool(coreset) and knn_coreset is not None \
-            and getattr(model, "NAME", None) == "KNN"
-        self._predict, self.lowering, self._engine = _fold_model(
-            model, knn_coreset=knn_coreset if self.coreset else None)
+        self._predict, self.lowering, self._engine = _fold_model(model)
         self.log_target = bool(log_target)
         self.candidates = list(knob_space.candidates)
         self.K = len(self.candidates)
@@ -553,27 +524,6 @@ class CompiledPredictor:
             except Exception:
                 pass        # exotic space: per-call parallelism_vec fallback
 
-        # -- optional dominated-candidate prune ------------------------------
-        # prune=True: the argmin live set; prune="band": every candidate
-        # whose prediction ever came within the persisted band of the winner
-        self._live = None
-        pick = band_idx if prune == "band" else live_idx
-        if prune and pick is not None and dims_lo is not None \
-                and dims_hi is not None:
-            live = np.unique(np.asarray(pick, dtype=np.int64))
-            if 0 < live.size < self.K \
-                    and live[0] >= 0 and live[-1] < self.K:
-                self._live = live
-                self._dims_lo = np.asarray(dims_lo).reshape(-1)
-                self._dims_hi = np.asarray(dims_hi).reshape(-1)
-                if self._nt_mode == "grid":
-                    self._bm_live = self._bm[live]
-                    self._bn_live = self._bn[live]
-                    self._packed_live = self._packed[live]
-                    self._has_packed_live = bool(self._packed_live.any())
-                elif self._nt_mode == "const":
-                    self._nt_const_live = self._nt_const[live]
-
         # element-bound lowerings get the duplicate-row fold: candidates
         # whose nt coincides produce byte-identical feature rows, and every
         # lowered predict is row-pure, so each distinct row is evaluated
@@ -581,13 +531,9 @@ class CompiledPredictor:
         # Call-overhead-bound lowerings (predicated tree descent, linear
         # matvec) are excluded — fewer rows there saves nothing and the
         # unique() would be pure overhead.
-        self._dedup = self.lowering in (
-            "screened-knn", "screened-knn-coreset", "stacked-forest")
+        self._dedup = self.lowering in ("screened-knn", "stacked-forest")
         if self._dedup and self._nt_mode == "const":
             self._const_fold = np.unique(self._nt_const, return_inverse=True)
-            if self._live is not None:
-                self._const_fold_live = np.unique(self._nt_const_live,
-                                                  return_inverse=True)
 
         # tree lowerings get their thresholds inverted through the (per
         # column strictly monotone) preprocess at compile time, so descents
@@ -608,8 +554,6 @@ class CompiledPredictor:
         warm = getattr(self._engine, "warm", None)
         if warm is not None:
             warm(self.K)
-            if self._live is not None:
-                warm(int(self._live.size))
 
         self._tls = threading.local()
 
@@ -690,34 +634,21 @@ class CompiledPredictor:
         np.divide(Z, self._scale, out=Z)
         return Z
 
-    def _times(self, dims: tuple, rows_idx: np.ndarray | None) -> np.ndarray:
-        """Predicted time per candidate (all K, or the live subset)."""
-        if rows_idx is None:
-            rows = self.K
-            bm = getattr(self, "_bm", None)
-            bn = getattr(self, "_bn", None)
-            packed = self._packed if getattr(self, "_has_packed", False) \
-                else None
-            nt_const = self._nt_const
-            const_fold = getattr(self, "_const_fold", None)
-        else:
-            rows = int(rows_idx.size)
-            bm = getattr(self, "_bm_live", None)
-            bn = getattr(self, "_bn_live", None)
-            packed = self._packed_live \
-                if getattr(self, "_has_packed_live", False) else None
-            nt_const = getattr(self, "_nt_const_live", None)
-            const_fold = getattr(self, "_const_fold_live", None)
+    def _times(self, dims: tuple) -> np.ndarray:
+        """Predicted time per knob candidate."""
+        rows = self.K
         inv = None
         if self._nt_mode == "const":
-            nt = nt_const
+            nt = self._nt_const
+            const_fold = getattr(self, "_const_fold", None)
             if const_fold is not None and const_fold[0].size < rows:
                 nt, inv = const_fold
         else:
             _, _, ntb = self._buffers(rows)
-            nt = self._nt_into(dims, ntb, bm, bn, packed)
-            if rows_idx is not None and self._nt_mode == "generic":
-                nt = nt[rows_idx]
+            packed = self._packed if getattr(self, "_has_packed", False) \
+                else None
+            nt = self._nt_into(dims, ntb, getattr(self, "_bm", None),
+                               getattr(self, "_bn", None), packed)
             if self._dedup:
                 # dict-based exact fold: ~4x cheaper than np.unique at
                 # candidate-set sizes, and keeps first-seen order
@@ -745,24 +676,13 @@ class CompiledPredictor:
     def predict_times(self, dims: tuple) -> np.ndarray:
         """Predicted runtime for every knob candidate (= reference
         ``TunedSubroutine.predict_times``, bit-identical)."""
-        return self._times(tuple(dims), None)
+        return self._times(tuple(dims))
 
     def select_index(self, dims: tuple) -> int:
-        dims = tuple(dims)
-        live = self._live
-        if live is not None and self._in_bounds(dims):
-            return int(live[int(np.argmin(self._times(dims, live)))])
-        return int(np.argmin(self._times(dims, None)))
+        return int(np.argmin(self._times(tuple(dims))))
 
     def select(self, dims: tuple):
         return self.candidates[self.select_index(dims)]
-
-    def _in_bounds(self, dims: tuple) -> bool:
-        lo, hi = self._dims_lo, self._dims_hi
-        for i, d in enumerate(dims):
-            if d < lo[i] or d > hi[i]:
-                return False
-        return True
 
     # -- batched API ----------------------------------------------------------
     def predict_times_batch(self, dims_list) -> np.ndarray:
@@ -828,32 +748,15 @@ class CompiledPredictor:
         return t.reshape(B, self.K)
 
     def select_many(self, dims_list) -> list:
-        """Argmin knob per dims, vectorised across the whole batch.
-
-        Applies the same dominated-candidate restriction as :meth:`select`
-        (per item, honouring the bounds fallback), so batched and
-        one-at-a-time decisions agree."""
+        """Argmin knob per dims, vectorised across the whole batch; agrees
+        with one-at-a-time :meth:`select`."""
         t = self.predict_times_batch(dims_list)
-        live = self._live
-        out = []
-        for b, dims in enumerate(dims_list):
-            if live is not None and self._in_bounds(tuple(dims)):
-                i = int(live[int(np.argmin(t[b, live]))])
-            else:
-                i = int(np.argmin(t[b]))
-            out.append(self.candidates[i])
-        return out
+        return [self.candidates[int(np.argmin(row))] for row in t]
 
 
-def compile_predictor(sub, *, prune=False,
-                      coreset: bool = False) -> CompiledPredictor | None:
+def compile_predictor(sub) -> CompiledPredictor | None:
     """Fold a :class:`~repro.core.tuner.TunedSubroutine`-like artifact into a
     :class:`CompiledPredictor`.
-
-    ``prune``: ``False`` (full candidate set), ``True`` (argmin live set),
-    or ``"band"`` (confidence-band live set — candidates ever within the
-    persisted ``fast_band_pct`` % of the winner).  ``coreset=True`` opts a
-    KNN artifact into its persisted inexact subsample.
 
     Returns ``None`` when the artifact lacks the required pieces (stub
     subroutines in tests, partially constructed objects) or compilation
@@ -870,13 +773,7 @@ def compile_predictor(sub, *, prune=False,
     try:
         cp = CompiledPredictor(
             op, space, pipeline, model,
-            getattr(sub, "log_target", False),
-            live_idx=getattr(sub, "fast_live_idx", None),
-            dims_lo=getattr(sub, "fast_dims_lo", None),
-            dims_hi=getattr(sub, "fast_dims_hi", None),
-            band_idx=getattr(sub, "fast_band_idx", None),
-            knn_coreset=getattr(sub, "fast_knn_coreset", None),
-            prune=prune, coreset=coreset)
+            getattr(sub, "log_target", False))
         # carried through so hot-swap/telemetry consumers (the online
         # retuner, the decision-cache export) can attribute a prediction to
         # the artifact generation that produced it without reaching back

@@ -128,21 +128,7 @@ def load_subroutine(path: str | Path) -> TunedSubroutine:
         knob_space=knobs, pipeline=pipeline, model=model,
         model_name=state["model_name"], log_target=bool(state["log_target"]),
         backend=str(state.get("backend", _LEGACY_BACKEND)))
-    # optional fast-path dominated-candidate analysis (absent on artifacts
-    # installed before the compiled decision engine)
-    if "fast_live_idx" in state:
-        sub.fast_live_idx = np.asarray(state["fast_live_idx"],
-                                       dtype=np.int64)
-        sub.fast_dims_lo = np.asarray(state["fast_dims_lo"], dtype=np.int64)
-        sub.fast_dims_hi = np.asarray(state["fast_dims_hi"], dtype=np.int64)
-    # optional confidence-band live set and opt-in KNN coreset (PR 4)
-    if "fast_band_idx" in state:
-        sub.fast_band_idx = np.asarray(state["fast_band_idx"],
-                                       dtype=np.int64)
-        sub.fast_band_pct = float(state["fast_band_pct"])
-    if "fast_knn_coreset" in state:
-        sub.fast_knn_coreset = np.asarray(state["fast_knn_coreset"],
-                                          dtype=np.int64)
+    # unread: legacy fast_live_idx/fast_dims_*/fast_band_*/fast_knn_coreset
     # registry-stamped artifact generation (absent on artifacts persisted
     # before versioning, or never saved through a ModelRegistry → 0)
     sub.artifact_version = int(state.get("artifact_version", 0))

@@ -269,21 +269,11 @@ class RuntimeStats:
 
 
 class AdsalaRuntime:
-    """Per-process decision engine for all tuned (backend, subroutine) pairs.
+    """Per-process decision engine for all tuned (backend, subroutine)
+    pairs."""
 
-    ``fast_prune=True`` opts registered artifacts into dominated-candidate
-    pruning (see :mod:`~repro.core.fastpath`): the compiled fast path then
-    evaluates only the knobs the install-time dataset ever argmin-selected,
-    falling back to the full candidate set outside the dataset's dims
-    range.  ``fast_prune="band"`` uses the confidence-band live set instead
-    (every knob whose prediction ever came within the persisted band of the
-    winner — a robust superset).  ``fast_knn_coreset=True`` opts KNN
-    artifacts into their persisted inexact subsample.
-    """
-
-    def __init__(self, *, cache_size: int = 256, fast_prune=False,
-                 touch_sample: int = 16,
-                 fast_knn_coreset: bool = False, faults=None) -> None:
+    def __init__(self, *, cache_size: int = 256, touch_sample: int = 16,
+                 faults=None) -> None:
         # paper's behaviour = cache_size 1 (last call only)
         #: optional repro.serving.faults.FaultPlan; every site is guarded by
         #: an `is not None` check so the disabled (default) path is free
@@ -309,8 +299,6 @@ class AdsalaRuntime:
             collections.OrderedDict()      # authoritative LRU, lock-guarded
         self._cache_mirror: dict[tuple, Knob] = {}   # lock-free read mirror
         self._cache_size = max(1, cache_size)
-        self._fast_prune = fast_prune
-        self._fast_knn_coreset = bool(fast_knn_coreset)
         self._lock = threading.RLock()
         self._touches: list[tuple] = []    # lock-free hit log (relaxed LRU)
         # hits log a recency touch every `touch_sample`-th hit of a thread's
@@ -474,8 +462,7 @@ class AdsalaRuntime:
         name = backend or getattr(sub, "backend", None) or DEFAULT_BACKEND
         # compile the fast path up front (None for stubs/uncompilable subs:
         # select() then falls back to the artifact's reference path)
-        compiled = compile_predictor(sub, prune=self._fast_prune,
-                                     coreset=self._fast_knn_coreset)
+        compiled = compile_predictor(sub)
         sub_key = (name, sub.op, sub.dtype_bytes)
         with self._lock:
             if sub_key in self._subs:
@@ -503,8 +490,7 @@ class AdsalaRuntime:
         compiled *before* the lock is taken, so the critical section is a
         few dict operations regardless of model family."""
         name = backend or getattr(sub, "backend", None) or DEFAULT_BACKEND
-        compiled = compile_predictor(sub, prune=self._fast_prune,
-                                     coreset=self._fast_knn_coreset)
+        compiled = compile_predictor(sub)
         sub_key = (name, sub.op, sub.dtype_bytes)
         with self._lock:
             self._swap_epochs[sub_key] = self._swap_epochs.get(sub_key, 0) + 1

@@ -22,8 +22,7 @@ from .preprocess import PreprocessPipeline
 from .selection import ModelReport, evaluate_candidates, select_best
 from .split import stratified_split
 
-__all__ = ["TunedSubroutine", "install_subroutine", "install_backend",
-           "attach_knn_coreset"]
+__all__ = ["TunedSubroutine", "install_subroutine", "install_backend"]
 
 #: persisted artifact schema: v1 = single-backend (implicit pallas),
 #: v2 = backend-tagged
@@ -50,21 +49,6 @@ class TunedSubroutine:
     #: decisions made by a different generation of this model instead of
     #: silently replaying a predecessor's knobs.
     artifact_version: int = 0
-    #: dominated-candidate analysis for the compiled fast path (optional,
-    #: persisted): knob indices the model ever argmin-selects over the
-    #: install dataset's dims, and that dataset's dims bounding box
-    fast_live_idx: np.ndarray | None = None
-    fast_dims_lo: np.ndarray | None = None
-    fast_dims_hi: np.ndarray | None = None
-    #: confidence-band variant of the live set (optional, persisted): knob
-    #: indices whose predicted time ever comes within ``fast_band_pct`` % of
-    #: the per-dims winner over the install dataset — a superset of
-    #: ``fast_live_idx`` that tolerates interpolation wobble
-    fast_band_idx: np.ndarray | None = None
-    fast_band_pct: float | None = None
-    #: opt-in KNN coreset (optional, persisted): indices into the fitted
-    #: KNN's training set for the inexact-but-faster compiled lookup
-    fast_knn_coreset: np.ndarray | None = None
 
     # -- runtime decision --------------------------------------------------
     def predict_times(self, dims: tuple[int, ...]) -> np.ndarray:
@@ -82,21 +66,15 @@ class TunedSubroutine:
     def select(self, dims: tuple[int, ...]) -> Knob:
         return self.knob_space.candidates[int(np.argmin(self.predict_times(dims)))]
 
-    def compiled(self, *, prune=False, coreset: bool = False):
+    def compiled(self):
         """The cached :class:`~repro.core.fastpath.CompiledPredictor` for
-        this artifact (None when uncompilable).  ``prune`` may be ``False``,
-        ``True`` (argmin live set) or ``"band"`` (confidence-band live
-        set); ``coreset=True`` opts a KNN artifact into its persisted
-        subsample."""
-        cache = getattr(self, "_compiled_cache", None)
-        if cache is None:
-            cache = self._compiled_cache = {}
-        key = (prune, coreset)
-        if key not in cache:
+        this artifact (None when uncompilable)."""
+        try:
+            return self._compiled
+        except AttributeError:
             from .fastpath import compile_predictor
-            cache[key] = compile_predictor(self, prune=prune,
-                                           coreset=coreset)
-        return cache[key]
+            self._compiled = compile_predictor(self)
+            return self._compiled
 
     # -- persistence ---------------------------------------------------------
     def get_state(self) -> dict:
@@ -112,24 +90,9 @@ class TunedSubroutine:
             "log_target": self.log_target,
             "reports": [r.row() for r in self.reports],
         }
-        # optional keys: absent on pre-fast-path artifacts, ignored by
-        # older readers — no schema bump needed
+        # optional key: ignored by older readers — no schema bump needed
         if self.artifact_version:
             state["artifact_version"] = int(self.artifact_version)
-        if self.fast_live_idx is not None:
-            state["fast_live_idx"] = np.asarray(self.fast_live_idx,
-                                                dtype=np.int64)
-            state["fast_dims_lo"] = np.asarray(self.fast_dims_lo,
-                                               dtype=np.int64)
-            state["fast_dims_hi"] = np.asarray(self.fast_dims_hi,
-                                               dtype=np.int64)
-        if self.fast_band_idx is not None:
-            state["fast_band_idx"] = np.asarray(self.fast_band_idx,
-                                                dtype=np.int64)
-            state["fast_band_pct"] = float(self.fast_band_pct)
-        if self.fast_knn_coreset is not None:
-            state["fast_knn_coreset"] = np.asarray(self.fast_knn_coreset,
-                                                   dtype=np.int64)
         return state
 
 
@@ -154,8 +117,6 @@ def install_subroutine(
     keep_dataset: bool = True,
     progress: Callable[[int, int], None] | None = None,
     backend: str = "pallas",
-    band_pct: float = 10.0,
-    knn_coreset_frac: float | None = None,
 ) -> TunedSubroutine:
     """Run the full ADSALA install for one subroutine; returns the artifact."""
     ds = dataset if dataset is not None else gather(
@@ -190,73 +151,7 @@ def install_subroutine(
         pipeline=pipeline, model=best.model, model_name=best.name,
         log_target=log_target, reports=reports,
         dataset=ds if keep_dataset else None, backend=backend)
-    _analyze_dominated(sub, ds, band_pct=band_pct)
-    if knn_coreset_frac is not None:
-        attach_knn_coreset(sub, frac=knn_coreset_frac, seed=seed)
     return sub
-
-
-def _analyze_dominated(sub: TunedSubroutine, ds: TimingDataset,
-                       chunk: int = 32, band_pct: float = 10.0) -> None:
-    """Record which knob candidates the selected model ever argmin-picks
-    over the gathered dims (plus the dims bounding box) on the artifact, so
-    the compiled fast path can optionally drop the dominated candidates
-    (``prune=True``) inside the regime that validated the drop.
-
-    Additionally records the confidence-band live set: candidates whose
-    predicted time ever comes within ``band_pct`` % of the per-dims winner.
-    A candidate outside the band on EVERY install dims is dominated with
-    margin — dropping it is robust to the interpolation wobble that makes
-    the argmin-only set brittle — while near-winners survive, so
-    ``prune="band"`` trades less latency for more safety."""
-    cp = sub.compiled()
-    if cp is None or ds.n_samples == 0:
-        return
-    chosen: list[np.ndarray] = []
-    K = len(sub.knob_space)
-    ratio_min = np.full(K, np.inf)
-    for i in range(0, ds.n_samples, chunk):     # chunked: bounds KNN memory
-        dims_list = [tuple(int(v) for v in d) for d in ds.dims[i:i + chunk]]
-        t = cp.predict_times_batch(dims_list)
-        chosen.append(np.argmin(t, axis=1))
-        # per-candidate closest approach to the winner in this chunk
-        ratio = t / np.maximum(t.min(axis=1, keepdims=True), 1e-300)
-        np.minimum(ratio_min, ratio.min(axis=0), out=ratio_min)
-    sub.fast_live_idx = np.unique(np.concatenate(chosen)).astype(np.int64)
-    sub.fast_dims_lo = ds.dims.min(axis=0).astype(np.int64)
-    sub.fast_dims_hi = ds.dims.max(axis=0).astype(np.int64)
-    sub.fast_band_idx = np.flatnonzero(
-        ratio_min <= 1.0 + band_pct / 100.0).astype(np.int64)
-    sub.fast_band_pct = float(band_pct)
-
-
-def attach_knn_coreset(sub: TunedSubroutine, *, frac: float = 0.25,
-                       min_size: int = 64, seed: int = 0) -> bool:
-    """Persist an opt-in coreset subsample on a KNN artifact.
-
-    The subsample is stratified over the fitted targets (equal-count y
-    quantiles, uniform within each), so fast/slow timing regimes stay
-    represented.  The compiled fast path only consults it under
-    ``coreset=True`` — default decisions are unchanged.  Returns False for
-    non-KNN models (nothing to attach)."""
-    model = sub.model
-    if getattr(model, "NAME", None) != "KNN" or model.X_ is None:
-        return False
-    n = model.X_.shape[0]
-    size = int(np.clip(round(frac * n), min(min_size, n), n))
-    if size >= n:
-        sub.fast_knn_coreset = np.arange(n, dtype=np.int64)
-        return True
-    rng = np.random.default_rng(seed)
-    strata = max(1, size // 8)
-    order = np.argsort(model.y_, kind="stable")
-    picks: list[np.ndarray] = []
-    for part, quota in zip(np.array_split(order, strata),
-                           np.array_split(np.arange(size), strata)):
-        take = min(len(quota), part.size)
-        picks.append(rng.choice(part, size=take, replace=False))
-    sub.fast_knn_coreset = np.sort(np.concatenate(picks)).astype(np.int64)
-    return True
 
 
 def install_backend(

@@ -24,10 +24,7 @@ and the executor can never disagree about the candidate set.
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
-import time
 from typing import Optional
 
 import jax
@@ -49,8 +46,7 @@ from .trsm import trsm_pallas
 __all__ = [
     "gemm", "symm", "syrk", "syr2k", "trmm", "trsm", "grouped_gemm",
     "knob_space_for", "default_knob", "dims_of", "run_op", "DTYPE_BYTES",
-    "PALLAS_OPS", "trace_batching", "enable_trace_batching",
-    "disable_trace_batching",
+    "PALLAS_OPS",
 ]
 
 
@@ -155,152 +151,12 @@ def dims_of(op: str, shapes: tuple[tuple[int, ...], ...]) -> tuple[int, ...]:
     return (m, n)
 
 
-# ---------------------------------------------------------------------------
-# trace-time decision batching (jit-friendly hook)
-# ---------------------------------------------------------------------------
-
-class _TraceBatcher:
-    """Cross-thread combining window for trace-time knob decisions.
-
-    The pallas executors resolve their knob at jit *trace* time with
-    concrete dims, one key at a time.  When several shapes trace
-    concurrently (serving warmup, multi-threaded jit, vmapped model
-    stacks), each tracer used to pay its own full model evaluation.  With a
-    batcher installed, cache hits and untuned ops stay on the direct
-    lock-free path, but true misses park in a shared window for a sub-ms
-    linger; the first thread in becomes the leader, drains the window
-    through ONE :meth:`AdsalaRuntime.select_many` call (a single fused
-    feature-build + model-predict for all distinct keys), and wakes the
-    rest.  Followers then re-read their now-cached key through the normal
-    hit path, so statistics stay faithful: one model eval per distinct key,
-    everything else a hit.
-
-    Purely trace-time Python — nothing jax sees changes, so jit tracing and
-    AOT caching behave exactly as without the hook.  Any failure or timeout
-    falls back to the direct per-key path; the batcher can only ever add
-    latency (bounded by the linger), never wrong decisions.
-    """
-
-    def __init__(self, linger_ms: float = 0.25, max_keys: int = 64) -> None:
-        self.linger_s = max(linger_ms, 0.01) / 1000.0
-        self.max_keys = max(int(max_keys), 1)
-        self._lock = threading.Lock()
-        self._pending: dict[tuple, threading.Event] = {}
-        self._leader_active = False
-        self.batches = 0          # introspection: flushes performed
-        self.batched_keys = 0     # keys resolved through select_many
-
-    def select_or_default(self, rt: AdsalaRuntime, op: str, dims: tuple,
-                          dtype_bytes: int, default: Knob,
-                          backend: str) -> Knob:
-        if not rt.has(op, dtype_bytes, backend) \
-                or rt.peek(op, dims, dtype_bytes, backend) is not None:
-            # untuned op or cache hit: the direct lock-free path
-            return rt.select_or_default(op, dims, dtype_bytes, default,
-                                        backend=backend)
-        key = (backend, op, dtype_bytes, dims)
-        with self._lock:
-            event = self._pending.get(key)
-            if event is None:
-                event = self._pending[key] = threading.Event()
-            leader = not self._leader_active
-            if leader:
-                self._leader_active = True
-        if leader:
-            owned = True
-            try:
-                while True:
-                    self._drain(rt)
-                    with self._lock:
-                        if not self._pending:
-                            # hand the leader role off atomically with the
-                            # emptiness check: late arrivals either saw a
-                            # live leader AND are in a batch this loop will
-                            # drain, or they elect themselves
-                            self._leader_active = False
-                            owned = False
-                            break
-            finally:
-                if owned:                  # exception safety — but never
-                    with self._lock:       # clear a successor's leadership
-                        self._leader_active = False
-        else:
-            event.wait(timeout=max(0.25, self.linger_s * 100))
-        # the key is (almost surely) cached now, so this records a hit —
-        # the same accounting shape as the serving layer's select_many
-        # prewarm (one fused eval per distinct key, each caller a hit); on
-        # any timeout/failure it is a normal single-key miss instead
-        return rt.select_or_default(op, dims, dtype_bytes, default,
-                                    backend=backend)
-
-    def _drain(self, rt: AdsalaRuntime) -> None:
-        deadline = time.perf_counter() + self.linger_s
-        while time.perf_counter() < deadline:
-            with self._lock:
-                if len(self._pending) >= self.max_keys:
-                    break
-            time.sleep(self.linger_s / 5.0)       # yields the GIL to peers
-        with self._lock:
-            batch = self._pending
-            self._pending = {}
-        try:
-            if batch:
-                rt.select_many([(op, dims, db, be)
-                                for (be, op, db, dims) in batch],
-                               record_hits=False)
-                self.batches += 1
-                self.batched_keys += len(batch)
-        finally:
-            for event in batch.values():
-                event.set()
-
-
-_TRACE_BATCHER: Optional[_TraceBatcher] = None
-
-
-def enable_trace_batching(linger_ms: float = 0.25,
-                          max_keys: int = 64) -> _TraceBatcher:
-    """Install a process-wide trace-time decision batcher (see
-    :class:`_TraceBatcher`); returns it for introspection."""
-    global _TRACE_BATCHER
-    _TRACE_BATCHER = _TraceBatcher(linger_ms=linger_ms, max_keys=max_keys)
-    return _TRACE_BATCHER
-
-
-def disable_trace_batching() -> None:
-    global _TRACE_BATCHER
-    _TRACE_BATCHER = None
-
-
-@contextlib.contextmanager
-def trace_batching(linger_ms: float = 0.25, max_keys: int = 64):
-    """Scoped :func:`enable_trace_batching` — concurrently-traced shapes
-    inside the block batch their uncached knob decisions through
-    ``select_many``::
-
-        with ops.trace_batching():
-            pool.map(lambda s: ops.run_op("gemm", mk(s)), shapes)
-    """
-    global _TRACE_BATCHER
-    prev = _TRACE_BATCHER
-    batcher = _TraceBatcher(linger_ms=linger_ms, max_keys=max_keys)
-    _TRACE_BATCHER = batcher
-    try:
-        yield batcher
-    finally:
-        _TRACE_BATCHER = prev
-
-
 def _select(op: str, dims: tuple[int, ...], dtype,
             knob: Optional[Knob], runtime: Optional[AdsalaRuntime]) -> Knob:
     if knob is not None:
         return knob
     rt = runtime if runtime is not None else global_runtime()
-    batcher = _TRACE_BATCHER
     with TraceAnnotation("adsala.select"):
-        if batcher is not None:
-            return batcher.select_or_default(rt, op, dims, DTYPE_BYTES(dtype),
-                                             default_knob(op), "pallas")
         return rt.select_or_default(op, dims, DTYPE_BYTES(dtype),
                                     default_knob(op), backend="pallas")
 
@@ -400,7 +256,6 @@ def grouped_gemm(x, w, group_sizes, *, layer=None, knob=None, runtime=None,
 #: the pallas-path executors (what the ``pallas`` backend dispatches to)
 PALLAS_OPS = {"gemm": gemm, "symm": symm, "syrk": syrk, "syr2k": syr2k,
               "trmm": trmm, "trsm": trsm, "grouped_gemm": grouped_gemm}
-_OPS = PALLAS_OPS   # back-compat alias
 
 
 def run_op(op: str, operands: tuple, *, backend: str = "pallas",

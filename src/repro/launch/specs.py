@@ -38,7 +38,7 @@ def rules_for(mesh: Mesh, shape_kind: str,
     """Default rules per execution kind: train uses FSDP over 'data' and
     Megatron-style sequence parallelism (seq → 'model' between blocks: the
     saved scan carries shrink by the TP degree — the difference between
-    fitting HBM and not at 4k×256; see EXPERIMENTS.md §Perf); serve keeps
+    fitting HBM and not at 4k×256); serve keeps
     weights replicated across 'data' (no per-step gather) UNLESS the bf16
     weights would exceed ~6 GB/chip under TP alone (internvl2-76b), in which
     case serve keeps the FSDP axis and pays the per-layer gather."""

@@ -21,8 +21,8 @@ See ``repro/serving/service.py`` for the life-of-a-request diagram and the
 budget-gated degradation ladder, ``repro/serving/budget.py`` for the error
 budgets, ``repro/serving/retune.py`` for the drift/refit/hot-swap
 semantics, ``repro/serving/faults.py`` for the named injection sites, and
-``benchmarks/chaos_bench.py`` / ``benchmarks/recovery_bench.py`` for the
-seeded fault and crash-recovery scenarios.
+``tests/test_resilience.py`` / ``tests/test_durable.py`` for the seeded
+fault and crash-recovery scenarios.
 """
 
 from .budget import BudgetConfig, ErrorBudgetLedger

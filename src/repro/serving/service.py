@@ -153,20 +153,6 @@ class ServeConfig:
     min_steal: Optional[int] = None   # smallest bucket an *idle* worker may
                                   # flush before its linger expires (work-
                                   # conserving scheduling); None = max_batch/2
-    trace_batching: bool | str = False
-                                  # "auto"/True: install the process-wide
-                                  # trace-time decision batcher
-                                  # (ops.trace_batching) around the worker
-                                  # pool for the service's lifetime, so
-                                  # buckets whose workers trace new shapes
-                                  # concurrently batch their uncached knob
-                                  # decisions through ONE select_many call.
-                                  # Scoped: the previous batcher (usually
-                                  # none) is restored on close().  Off by
-                                  # default — the combining window adds its
-                                  # linger (sub-ms) to every COLD trace, a
-                                  # poor trade when traffic is single-
-                                  # threaded or shapes rarely repeat.
     # -- resilience (the degradation ladder) --
     exec_retries: int = 1         # same-backend/knob retries after a crash
     retry_backoff_s: float = 0.005    # backoff base, doubled per retry
@@ -190,10 +176,6 @@ class ServeConfig:
                                   # evaluations; None disables brownout
 
     def __post_init__(self) -> None:
-        if self.trace_batching not in (True, False, "auto"):
-            # any other string ("off", "no", ...) would truthiness-enable
-            # the batcher — the exact opposite of the author's intent
-            raise ValueError('trace_batching must be True, False, or "auto"')
         if self.max_batch < 1:
             raise ValueError("max_batch must be >= 1")
         if self.workers < 1:
@@ -405,24 +387,7 @@ class BlasService:
         # artifact generations.  Omit it (the default) for reproducibility
         # runs.
         self.retuner = retuner
-
-        # scoped trace-time decision batcher (ServeConfig.trace_batching):
-        # entered before the workers start, exited (previous batcher
-        # restored) after they stop
-        self._trace_cm = None
-        self.trace_batcher = None
-        if self.config.trace_batching:
-            from repro.kernels.ops import trace_batching
-            self._trace_cm = trace_batching()
-            self.trace_batcher = self._trace_cm.__enter__()
-        try:
-            self._start()
-        except BaseException:
-            # never leak the process-global batcher if startup fails
-            if self._trace_cm is not None:
-                self._trace_cm.__exit__(None, None, None)
-                self._trace_cm = None
-            raise
+        self._start()
         if self.retuner is not None:
             self.retuner.start()
 
@@ -628,9 +593,6 @@ class BlasService:
         for w in self._workers:
             w.join(timeout=join_s)
         self._abort_leftovers()
-        if self._trace_cm is not None:      # restore the previous batcher
-            self._trace_cm.__exit__(None, None, None)
-            self._trace_cm = None
         if self.retuner is not None:        # before the cache is persisted:
             # no swap may race the export — but a retuner mid-refit can
             # outlast any close budget, so the join is bounded by whatever

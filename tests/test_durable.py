@@ -5,14 +5,25 @@ round-trips, torn-tail journal semantics, injected torn writes, and
 of damaged payload instead of propagating."""
 
 import json
+import os
+import signal
+import subprocess
+import sys
+import threading
+import time
+from pathlib import Path
 
+import numpy as np
 import pytest
 
+import repro
+from repro.backends import get_backend
 from repro.core import AdsalaRuntime, ModelRegistry
 from repro.core.durable import (MAGIC, DurableStore, TornWrite,
                                 append_journal, decode_line, encode_record,
                                 is_durable, read_records, write_snapshot)
 from repro.core.knobs import Knob
+from repro.serving import BlasService, ServeConfig
 from repro.serving.faults import FaultPlan, FaultSpec
 
 
@@ -178,6 +189,7 @@ def test_torn_journal_append_is_counted_not_raised(tmp_path):
     rt.decision_journal = reg.journal_decision
     rt.select("gemm", (32, 32, 32), 4, backend="b0")   # torn append
     rt.select("gemm", (64, 64, 64), 4, backend="b0")   # clean append
+    assert plan.fired("journal_append") == 1
     assert rt.stats.journal_failures == 1              # counted, not raised
     warm = AdsalaRuntime()
     warm.register(StubSub())
@@ -235,3 +247,180 @@ def test_import_cache_counts_corrupt_quarantine_records():
     assert rt.import_cache([bad_q, good_q]) == 0     # quarantines aren't
     assert rt.stats.import_drops_corrupt == 1        # decision imports
     assert rt.is_quarantined("gemm", 4, "b0", Knob((("bm", 32),)))
+
+
+# ---------------------------------------------------------------------------
+# crash recovery end to end: a writer really killed mid-snapshot, and a
+# garbage snapshot beside an intact journal
+# ---------------------------------------------------------------------------
+
+#: shapes snapshotted before the kill vs journaled after it — recovery must
+#: warm-start the union
+SNAP_SHAPES = ((32, 32, 32), (48, 48, 48))
+JOURNAL_SHAPES = ((64, 64, 64), (80, 80, 80))
+
+#: the writer that is SIGKILLed: builds a snapshot, journals further
+#: decisions and a knob quarantine, prints JOURNALED, then prints WRITING and
+#: starts a second snapshot held before its temp file exists (an injected
+#: ``snapshot_write`` latency) — the kill lands with the old snapshot and
+#: the journal both on disk
+_WRITER = """
+import json, sys
+from repro.backends import get_backend
+from repro.core import AdsalaRuntime, ModelRegistry
+from repro.serving.faults import FaultPlan, FaultSpec
+
+class Sub:
+    backend, op, dtype_bytes, artifact_version = "cpu_blocked", "gemm", 4, 0
+    def __init__(self, knob):
+        self.knob = knob
+    def select(self, dims):
+        return self.knob
+
+root, snap, journal = sys.argv[1], *json.loads(sys.argv[2])
+be = get_backend("cpu_blocked")
+default = be.default_knob("gemm")
+bad = next(c for c in be.knob_space("gemm").candidates if c != default)
+rt = AdsalaRuntime()
+rt.register(Sub(default))
+reg = ModelRegistry(root)
+rt.decision_journal = reg.journal_decision
+for d in snap:
+    rt.select("gemm", tuple(d), 4, backend="cpu_blocked")
+reg.save_decision_cache(rt)
+for d in journal:
+    rt.select("gemm", tuple(d), 4, backend="cpu_blocked")
+rt.quarantine_knob("gemm", 4, "cpu_blocked", bad, fallback=default,
+                   ttl_s=60.0)
+print("JOURNALED", flush=True)
+plan = FaultPlan([FaultSpec(site="snapshot_write", exc=None, latency_s=30.0,
+                            times=None)])
+print("WRITING", flush=True)
+ModelRegistry(root, faults=plan).save_decision_cache(rt)
+sys.exit(3)
+"""
+
+
+def _cpu_knobs():
+    """(model knob, quarantined knob): distinct cpu_blocked candidates, so
+    the quarantine never drops a cached decision."""
+    be = get_backend("cpu_blocked")
+    default = be.default_knob("gemm")
+    bad = next(c for c in be.knob_space("gemm").candidates if c != default)
+    return default, bad
+
+
+def _counting_sub(knob):
+    sub = StubSub("cpu_blocked")
+    sub.knob = knob
+    return sub
+
+
+def _sigkill_mid_snapshot(tmp_path):
+    """SIGKILL a real writer inside the snapshot write window; a fresh
+    process recovers the snapshot+journal union, the open quarantine, and
+    serves every recovered shape with zero model evaluations."""
+    default, bad = _cpu_knobs()
+    src = str(Path(repro.__file__).resolve().parents[1])
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": os.pathsep.join(
+               [src] + [p for p in [os.environ.get("PYTHONPATH")] if p])}
+    proc = subprocess.Popen(
+        [sys.executable, "-c", _WRITER, str(tmp_path),
+         json.dumps([SNAP_SHAPES, JOURNAL_SHAPES])],
+        stdout=subprocess.PIPE, text=True, env=env)
+    # the test's own timeout: a writer that never reaches WRITING is killed,
+    # which ends the read below
+    watchdog = threading.Timer(120.0, proc.kill)
+    watchdog.start()
+    try:
+        writing = any(line.strip() == "WRITING" for line in proc.stdout)
+        time.sleep(0.3)                    # well inside the 30 s hold
+        os.kill(proc.pid, signal.SIGKILL)
+        proc.wait(timeout=30)
+    finally:
+        watchdog.cancel()
+        proc.stdout.close()
+        if proc.poll() is None:
+            proc.kill()
+
+    rt = AdsalaRuntime()
+    sub = _counting_sub(default)
+    rt.register(sub)
+    reg = ModelRegistry(tmp_path)
+    imported = reg.load_decision_cache(rt)
+    rec = reg.last_recovery
+    shapes = SNAP_SHAPES + JOURNAL_SHAPES
+    cfg = ServeConfig(backend="cpu_blocked", max_batch=1, linger_ms=0.5,
+                      workers=1, min_steal=1, exec_retries=0,
+                      retry_backoff_s=0.0)
+    reqs = [get_backend("ref").make_operands("gemm", d, np.float32, seed=i)
+            for i, d in enumerate(shapes)]
+    with BlasService(runtime=rt, config=cfg) as svc:
+        futs = [svc.submit("gemm", r) for r in reqs]
+        outs = [np.asarray(f.result(timeout=120), np.float64) for f in futs]
+    correct = all(
+        np.max(np.abs(out - np.asarray(r[0] @ r[1], np.float64)))
+        / (np.max(np.abs(np.asarray(r[0] @ r[1], np.float64))) + 1e-9)
+        < 5e-4 for r, out in zip(reqs, outs))
+    return {
+        "killed_mid_write": writing
+        and proc.returncode == -signal.SIGKILL,
+        "recovered_decisions": imported,
+        "snapshot_records": rec.get("snapshot_records"),
+        "journal_records": rec.get("journal_records"),
+        "dropped_records": rec.get("dropped_records"),
+        "quarantine_recovered": rt.is_quarantined("gemm", 4, "cpu_blocked",
+                                                  bad),
+        "model_evals": sub.evals + rt.stats.model_evals,
+        "lost_futures": sum(not f.done() for f in futs),
+        "served_correct": correct and svc.stats.failed == 0,
+    }
+
+
+def _garbage_snapshot(tmp_path):
+    """A non-JSON snapshot degrades to a counted cold start while the intact
+    journal written after it still replays — never an exception."""
+    default, _bad = _cpu_knobs()
+    reg = ModelRegistry(tmp_path)
+    rt = AdsalaRuntime()
+    rt.register(_counting_sub(default))
+    rt.decision_journal = reg.journal_decision
+    rt.select("gemm", (32, 32, 32), 4, backend="cpu_blocked")
+    reg.save_decision_cache(rt)            # the journal is truncated here
+    rt.select("gemm", (64, 64, 64), 4, backend="cpu_blocked")  # journaled
+    reg.decision_cache_path.write_bytes(b"garbage {{{ not json")
+    warm = AdsalaRuntime()
+    warm.register(_counting_sub(default))
+    reg2 = ModelRegistry(tmp_path)
+    imported = reg2.load_decision_cache(warm)
+    return {
+        "cold_start": reg2.last_recovery.get("cold_start"),
+        "recovered_decisions": imported,
+        "recovered_dims": [tuple(e["dims"]) for e in warm.export_cache()],
+    }
+
+
+#: scenario -> (run, the exact outcome it must produce)
+RECOVERY_SCENARIOS = {
+    "sigkill_mid_snapshot": (_sigkill_mid_snapshot, {
+        "killed_mid_write": True,
+        "recovered_decisions": len(SNAP_SHAPES) + len(JOURNAL_SHAPES),
+        "snapshot_records": len(SNAP_SHAPES),
+        "journal_records": len(JOURNAL_SHAPES) + 1,   # + the quarantine
+        "dropped_records": 0,
+        "quarantine_recovered": True,
+        "model_evals": 0,
+        "lost_futures": 0,
+        "served_correct": True}),
+    "garbage_snapshot": (_garbage_snapshot, {
+        "cold_start": True,
+        "recovered_decisions": 1,
+        "recovered_dims": [(64, 64, 64)]}),
+}
+
+
+@pytest.mark.parametrize("scenario", sorted(RECOVERY_SCENARIOS))
+def test_crash_recovery_scenario(scenario, tmp_path):
+    run, want = RECOVERY_SCENARIOS[scenario]
+    assert run(tmp_path) == want

@@ -5,11 +5,13 @@ individual selects."""
 
 import random
 import threading
+from pathlib import Path
 
 import numpy as np
 import pytest
 
-from repro.core import AdsalaRuntime, ModelRegistry, install_subroutine
+from repro.core import (SUBROUTINE_NDIMS, AdsalaRuntime, install_subroutine,
+                        load_subroutine)
 from repro.core.fastpath import CompiledPredictor, compile_predictor
 from repro.core.knobs import Knob, thread_knob_space
 from repro.kernels import ops
@@ -36,13 +38,16 @@ PERSISTED_FAMILIES = ("LinearRegression", "DecisionTree")
 
 OPS = ("gemm", "symm", "syrk", "syr2k", "trmm", "trsm")
 
-ARTIFACTS = ModelRegistry("runs/adsala/models")
+#: the frozen chip installs the benchmark loads (read here, never written)
+INSTALL_ROOT = Path(__file__).resolve().parents[1] / "bench" / "data" / \
+    "install"
+FROZEN_ARTIFACTS = sorted(INSTALL_ROOT.rglob("*.adsala"))
 
 
 def _dims_sweep(op: str, n_random: int = 12, seed: int = 7):
-    nd = 3 if op == "gemm" else 2
+    nd = SUBROUTINE_NDIMS[op]
     fixed = [(16,) * nd, (64,) * nd, (512,) * nd, (2048,) * nd,
-             (33, 257, 1023)[:nd], (1024, 48, 640)[:nd]]
+             (33, 257, 1023, 7)[:nd], (1024, 48, 640, 64)[:nd]]
     rng = np.random.default_rng(seed)
     rand = [tuple(int(v) for v in rng.integers(8, 2048, size=nd))
             for _ in range(n_random)]
@@ -97,21 +102,21 @@ def test_parity_installed(installed, op, dtype_bytes, family):
         assert cp.select(dims) == sub.select(dims), (op, family, dims)
 
 
-@pytest.mark.skipif(not ARTIFACTS.root.exists(),
-                    reason="no persisted artifact store")
-def test_parity_persisted_artifacts():
-    """Zero argmin decision changes on every artifact the repo ships."""
-    subs = ARTIFACTS.load_all()
-    assert subs, "artifact store exists but is empty"
-    for sub in subs:
-        cp = compile_predictor(sub)
-        assert cp is not None, (sub.backend, sub.op)
-        for dims in _dims_sweep(sub.op, n_random=20, seed=11):
-            assert np.array_equal(cp.predict_times(dims),
-                                  sub.predict_times(dims)), \
-                (sub.backend, sub.op, dims)
-            assert cp.select(dims) == sub.select(dims), \
-                (sub.backend, sub.op, dims)
+@pytest.mark.parametrize(
+    "path", FROZEN_ARTIFACTS,
+    ids=[str(p.relative_to(INSTALL_ROOT)) for p in FROZEN_ARTIFACTS])
+def test_parity_persisted_artifacts(path):
+    """Zero argmin decision changes on every artifact the repo ships: the
+    frozen chip installs, loaded as the benchmark loads them."""
+    sub = load_subroutine(path)
+    cp = compile_predictor(sub)
+    assert cp is not None, (sub.backend, sub.op)
+    for dims in _dims_sweep(sub.op, n_random=20, seed=11):
+        assert np.array_equal(cp.predict_times(dims),
+                              sub.predict_times(dims)), \
+            (sub.backend, sub.op, dims)
+        assert cp.select(dims) == sub.select(dims), \
+            (sub.backend, sub.op, dims)
 
 
 # ---------------------------------------------------------------------------
@@ -360,143 +365,6 @@ def test_uncompilable_sub_falls_back_to_reference():
 
 
 # ---------------------------------------------------------------------------
-# dominated-candidate pruning (opt-in)
-# ---------------------------------------------------------------------------
-
-def test_dominated_prune_analysis_persisted(installed, tmp_path):
-    sub = installed[("gemm", 4, "LinearRegression")]
-    assert sub.fast_live_idx is not None
-    assert 0 < sub.fast_live_idx.size <= len(sub.knob_space)
-    assert sub.fast_dims_lo.shape == (3,) and sub.fast_dims_hi.shape == (3,)
-    # round-trips through the registry
-    reg = ModelRegistry(tmp_path)
-    reg.save(sub)
-    back = reg.load_all()[0]
-    assert np.array_equal(back.fast_live_idx, sub.fast_live_idx)
-    assert np.array_equal(back.fast_dims_lo, sub.fast_dims_lo)
-    assert np.array_equal(back.fast_dims_hi, sub.fast_dims_hi)
-
-
-def test_dominated_prune_semantics(installed):
-    sub = installed[("gemm", 4, "LinearRegression")]
-    cp = compile_predictor(sub, prune=True)
-    full = compile_predictor(sub)
-    lo, hi = sub.fast_dims_lo, sub.fast_dims_hi
-    live = set(int(i) for i in sub.fast_live_idx)
-    if len(live) < len(sub.knob_space):
-        assert cp._live is not None
-        # in-bounds dims: decision restricted to the live set, equal to the
-        # argmin over the live candidates of the full prediction vector
-        mid = tuple(int((a + b) // 2) for a, b in zip(lo, hi))
-        idx = cp.select_index(mid)
-        assert idx in live
-        t = full.predict_times(mid)
-        live_sorted = sorted(live)
-        assert idx == live_sorted[int(np.argmin(t[live_sorted]))]
-    # out-of-bounds dims (extrapolation): full-K evaluation, exact parity
-    far = tuple(int(h * 2 + 1) for h in hi)
-    assert cp.select(far) == sub.select(far)
-
-
-# ---------------------------------------------------------------------------
-# confidence-band prune (opt-in) + KNN coreset (opt-in)
-# ---------------------------------------------------------------------------
-
-def test_band_analysis_persisted_roundtrip(installed, tmp_path):
-    sub = installed[("gemm", 4, "LinearRegression")]
-    assert sub.fast_band_idx is not None
-    assert sub.fast_band_pct == 10.0
-    # the band set contains every argmin winner (winners are within 0%)
-    assert set(sub.fast_live_idx).issubset(set(sub.fast_band_idx))
-    reg = ModelRegistry(tmp_path)
-    reg.save(sub)
-    back = reg.load_all()[0]
-    assert np.array_equal(back.fast_band_idx, sub.fast_band_idx)
-    assert back.fast_band_pct == sub.fast_band_pct
-
-
-def test_band_prune_semantics(installed):
-    sub = installed[("gemm", 4, "LinearRegression")]
-    cp = compile_predictor(sub, prune="band")
-    full = compile_predictor(sub)
-    band = set(int(i) for i in sub.fast_band_idx)
-    lo, hi = sub.fast_dims_lo, sub.fast_dims_hi
-    if len(band) < len(sub.knob_space):
-        assert cp._live is not None
-        mid = tuple(int((a + b) // 2) for a, b in zip(lo, hi))
-        idx = cp.select_index(mid)
-        assert idx in band
-        t = full.predict_times(mid)
-        band_sorted = sorted(band)
-        assert idx == band_sorted[int(np.argmin(t[band_sorted]))]
-    # out-of-range dims: full-K evaluation, exact parity with the reference
-    far = tuple(int(h * 2 + 1) for h in hi)
-    assert cp.select(far) == sub.select(far)
-    assert np.array_equal(cp.predict_times(far), sub.predict_times(far))
-
-
-def test_band_is_superset_of_argmin_live(installed):
-    """band prune keeps near-winners the argmin-only prune would drop."""
-    for key, sub in installed.items():
-        if sub.fast_band_idx is None or sub.fast_live_idx is None:
-            continue
-        assert set(sub.fast_live_idx).issubset(set(sub.fast_band_idx)), key
-
-
-def test_knn_coreset_optin(installed_v2, tmp_path):
-    from repro.core import attach_knn_coreset
-    from repro.core.ml.knn import KNN
-    sub = installed_v2[("gemm", "KNN")]
-    assert sub.fast_knn_coreset is None       # never attached by default
-    assert attach_knn_coreset(sub, frac=0.5, min_size=8)
-    idx = sub.fast_knn_coreset
-    assert idx is not None and 0 < idx.size <= sub.model.X_.shape[0]
-    # persists and round-trips
-    reg = ModelRegistry(tmp_path)
-    reg.save(sub)
-    back = reg.load_all()[0]
-    assert np.array_equal(back.fast_knn_coreset, idx)
-    # DEFAULT compile ignores the coreset: exact parity with the full model
-    cp = compile_predictor(back)
-    assert cp.lowering == "screened-knn" and not cp.coreset
-    for dims in _dims_sweep("gemm", n_random=6):
-        assert np.array_equal(cp.predict_times(dims),
-                              sub.predict_times(dims))
-    # opt-in compile == a KNN fit on the subsample (inexact vs full model)
-    cpc = compile_predictor(back, coreset=True)
-    assert cpc.lowering == "screened-knn-coreset" and cpc.coreset
-    m = sub.model
-    msub = KNN(k=m.k, weights=m.weights).fit(m.X_[idx], m.y_[idx])
-    import dataclasses
-    want = dataclasses.replace(sub, model=msub, dataset=None, reports=[],
-                               fast_knn_coreset=None)
-    for dims in _dims_sweep("gemm", n_random=6):
-        assert np.array_equal(cpc.predict_times(dims),
-                              want.predict_times(dims))
-
-
-def test_runtime_coreset_flag(installed_v2):
-    from repro.core import attach_knn_coreset
-    sub = installed_v2[("trsm", "KNN")]
-    if sub.fast_knn_coreset is None:
-        attach_knn_coreset(sub, frac=0.5, min_size=8)
-    rt = AdsalaRuntime(fast_knn_coreset=True)
-    rt.register(sub)
-    cp = rt.predictor("trsm", 4, backend="cpu_blocked")
-    assert cp is not None and cp.coreset
-    rt_plain = AdsalaRuntime()
-    rt_plain.register(sub)
-    assert not rt_plain.predictor("trsm", 4, backend="cpu_blocked").coreset
-
-
-def test_attach_knn_coreset_non_knn(installed):
-    from repro.core import attach_knn_coreset
-    sub = installed[("gemm", 4, "LinearRegression")]
-    assert not attach_knn_coreset(sub)
-    assert sub.fast_knn_coreset is None
-
-
-# ---------------------------------------------------------------------------
 # lock-free hit path under concurrency: stats stay exact
 # ---------------------------------------------------------------------------
 
@@ -646,58 +514,6 @@ def test_miss_shards_are_per_backend_op():
     assert s.model_evals == 3
     assert s.for_backend("b0").model_evals == 2
     assert s.for_backend("b1").model_evals == 1
-
-
-# ---------------------------------------------------------------------------
-# trace-time decision batching (ops._select hook)
-# ---------------------------------------------------------------------------
-
-def test_trace_batching_batches_concurrent_misses(installed):
-    sub = installed[("gemm", 4, "LinearRegression")]
-    rt = AdsalaRuntime()
-    rt.register(sub, backend="pallas")
-    shapes = [(32 * i, 64, 32 * j) for i in range(1, 5) for j in range(1, 5)]
-    errors = []
-    with ops.trace_batching(linger_ms=1.0) as batcher:
-        def worker(tid):
-            rng = np.random.default_rng(tid)
-            try:
-                for _ in range(30):
-                    d = shapes[int(rng.integers(len(shapes)))]
-                    got = ops._select("gemm", d, np.float32, None, rt)
-                    assert got == sub.select(d), d
-            except Exception as e:        # noqa: BLE001
-                errors.append(e)
-
-        threads = [threading.Thread(target=worker, args=(t,))
-                   for t in range(4)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-    assert not errors
-    # the per-thread rngs are seeded, so the requested key set is exact
-    drawn = set()
-    for t in range(4):
-        rng = np.random.default_rng(t)
-        for _ in range(30):
-            drawn.add(shapes[int(rng.integers(len(shapes)))])
-    # every distinct key evaluated exactly once, through select_many
-    s = rt.stats
-    assert s.model_evals == len(drawn)
-    assert batcher.batches >= 1
-    assert batcher.batched_keys >= 1
-    assert s.calls == s.cache_hits + s.model_evals + s.default_calls
-    # the hook uninstalls on context exit
-    assert ops._TRACE_BATCHER is None
-
-
-def test_trace_batching_untuned_falls_back_to_default():
-    rt = AdsalaRuntime()
-    with ops.trace_batching(linger_ms=0.1):
-        knob = ops._select("gemm", (64, 64, 64), np.float32, None, rt)
-    assert knob == ops.default_knob("gemm")
-    assert rt.stats.default_calls == 1
 
 
 # ---------------------------------------------------------------------------

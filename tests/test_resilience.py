@@ -104,6 +104,38 @@ def test_kernel_fault_degrades_to_ref_bit_identical(op, backend):
         assert np.array_equal(out, clean[i]), (op, backend, i)
 
 
+def test_crash_storm_lands_every_op_on_ref_bit_identical():
+    """Every accelerator launch of all six ops crashes while two workers
+    serve them together: each bucket walks pallas → cpu_blocked → ref,
+    every future resolves, none fails, and each result is bit-identical to
+    a clean stacked ref run of its bucket."""
+    n = 4
+    plan = FaultPlan([FaultSpec(site="kernel_execute", times=None,
+                                match=lambda c: c["backend"] != "ref")],
+                     seed=0)
+    rt = AdsalaRuntime(faults=plan)
+    cfg = ServeConfig(backend="pallas", max_batch=n, linger_ms=20.0,
+                      workers=2, min_steal=n, exec_retries=0,
+                      retry_backoff_s=0.0)
+    reqs = {op: [make(op, DIMS[op], seed=i) for i in range(n)] for op in OPS}
+    with BlasService(runtime=rt, config=cfg, faults=plan) as svc:
+        futs = {op: [svc.submit(op, r) for r in reqs[op]] for op in OPS}
+        outs = {op: [np.asarray(f.result(timeout=120)) for f in futs[op]]
+                for op in OPS}
+    assert all(f.done() for fs in futs.values() for f in fs)
+    assert svc.stats.failed == 0
+    assert svc.stats.completed == n * len(OPS)
+    assert svc.stats.fallback_executions >= len(OPS)
+    # both accelerator rungs crash before dispatch, once per bucket
+    assert plan.fired("kernel_execute") == 2 * svc.stats.fallback_executions
+    for op in OPS:
+        stacked = tuple(np.stack([r[i] for r in reqs[op]])
+                        for i in range(len(reqs[op][0])))
+        clean = np.asarray(run_op(op, stacked, backend="ref", stacked=True))
+        for i, out in enumerate(outs[op]):
+            assert np.array_equal(out, clean[i]), (op, i)
+
+
 def test_transient_crash_retries_same_backend():
     plan = FaultPlan([FaultSpec(site="stacked_execute", times=1)])
     cfg = ServeConfig(backend="ref", max_batch=2, linger_ms=1.0, workers=1,
@@ -406,6 +438,28 @@ def test_retuner_survives_refit_faults(tuned):
     assert r.stats.refit_failures == 1 and r.stats.errors == 1
     assert r.stats.retunes == 0
     assert "InjectedFault" in r.stats.last_error
+
+
+def test_refit_failure_keeps_old_model_then_retunes_next_step(tuned):
+    """A drift-triggered refit that raises once is counted, the old model's
+    decisions keep serving, and the next step completes the retune."""
+    pool = [(32, 32, 32), (48, 32, 64), (64, 48, 32), (32, 64, 48)]
+    plan = FaultPlan([FaultSpec(site="retuner_refit", times=1)], seed=0)
+    rt = AdsalaRuntime()
+    rt.register(tuned)
+    ret = Retuner(rt, config=RetuneConfig(min_samples=len(pool),
+                                          tune_trials=1, seed=0),
+                  faults=plan)
+    before = {d: rt.select("gemm", d, 4, backend="pallas") for d in pool}
+    for d in pool:                    # measured 4x the (flat) prediction
+        rt.record_batch("gemm", d, 4, "pallas", 1, exec_seconds=4e-3,
+                        exec_items=1)
+    assert ret.step() == []
+    assert ret.stats.refit_failures == 1 and ret.stats.retunes == 0
+    assert {d: rt.select("gemm", d, 4, backend="pallas")
+            for d in pool} == before
+    assert ret.step() == [("pallas", "gemm", 4)]
+    assert ret.stats.retunes == 1
 
 
 def test_exploration_overrides_one_bucket_then_restores(tuned):

@@ -207,47 +207,3 @@ def test_warm_start_skips_model_evals(tmp_path):
     assert warm_rt.stats.model_evals == 0
     assert warm_rt.stats.cache_hits == warm_rt.stats.calls
 
-
-def test_trace_batching_auto_installs_and_restores():
-    """ServeConfig(trace_batching="auto") installs the process-wide
-    trace-time decision batcher for the service's lifetime and restores
-    the previous one (normally none) on close."""
-    from repro.kernels import ops as kops
-
-    assert kops._TRACE_BATCHER is None
-    svc = BlasService(runtime=AdsalaRuntime(),
-                      config=ServeConfig(backend="ref",
-                                         trace_batching="auto"))
-    try:
-        assert kops._TRACE_BATCHER is svc.trace_batcher is not None
-        futs = [svc.submit("gemm", make("gemm", (32, 32, 32), seed=i))
-                for i in range(6)]
-        for f in futs:
-            out = f.result(timeout=30)
-            assert out.shape == (32, 32)
-    finally:
-        svc.close()
-    assert kops._TRACE_BATCHER is None
-    assert svc.trace_batcher.batches >= 0     # introspection stays readable
-
-
-def test_trace_batching_defaults_off():
-    from repro.kernels import ops as kops
-    with BlasService(runtime=AdsalaRuntime(),
-                     config=ServeConfig(backend="ref")) as svc:
-        assert svc.trace_batcher is None
-        assert kops._TRACE_BATCHER is None
-
-
-def test_trace_batching_restores_previous_batcher():
-    """A service-scoped batcher nests inside an explicitly installed one."""
-    from repro.kernels import ops as kops
-    outer = kops.enable_trace_batching()
-    try:
-        with BlasService(runtime=AdsalaRuntime(),
-                         config=ServeConfig(backend="ref",
-                                            trace_batching=True)) as svc:
-            assert kops._TRACE_BATCHER is svc.trace_batcher is not outer
-        assert kops._TRACE_BATCHER is outer
-    finally:
-        kops.disable_trace_batching()

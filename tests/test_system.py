@@ -58,7 +58,7 @@ def test_measured_speedup_vs_default_on_holdout(tuned_gemm):
     default = tuned_gemm.dataset.knob_space.candidates[
         tuned_gemm.dataset.default_knob_index()]
     # dims ≥96 keep op time ≳10× the eval time — below that regime the
-    # memo cache is the amortiser (see EXPERIMENTS.md Table VII note)
+    # memo cache is the amortiser
     dims_list = sample_dims(8, 3, lo=96, hi=256, seed=99)
     # a median of 5 drops the runs a loaded host preempts (a median of 2
     # is their mean)

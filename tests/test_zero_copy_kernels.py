@@ -14,6 +14,8 @@ Three properties, each asserted at its own level:
     from their own (smaller) persisted spaces.
 """
 
+import math
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -157,6 +159,17 @@ def test_no_pad_or_slice_in_dispatch(op):
     assert counts == {}, (op, counts)
 
 
+@pytest.mark.parametrize("variant", ("full", "tri"))
+@pytest.mark.parametrize("op", TRI_OPS)
+def test_no_pad_or_slice_in_unpacked_variants(op, variant):
+    """The rectangular-grid variants of the triangular ops are zero-copy
+    too: no host-side pad or slice at a ragged shape."""
+    operands = _jops(op, RAGGED[op], seed=3)
+    counts = copy_op_counts(ops.PALLAS_OPS[op], *operands,
+                            knob=_knob(variant), interpret=True)
+    assert counts == {}, (op, variant, counts)
+
+
 def test_trsm_has_no_pad():
     """TRSM's substitution loop slices block rows (that's the algorithm)
     but never pads an operand — the identity-padded diagonal is gone."""
@@ -215,6 +228,27 @@ def test_packed_grid_is_exactly_triangular(op):
     nb = -(-dims[0] // 128)
     packed = packed_grid_for(op, dims, 128, 128, 128)
     assert tri_count(nb) in packed       # n(n+1)/2 really is a grid dim
+
+
+#: full-grid slots over tri_packed slots at 128-blocks, to 3 places:
+#: 16²·16 over 136·(16 + 1) for the rank-k updates, 16·16·8 over 8·136
+#: for trmm
+PACKED_SLOT_RATIO = {"syrk": ((2048, 2048), 1.772),
+                     "syr2k": ((2048, 2048), 1.772),
+                     "trmm": ((2048, 1024), 1.882)}
+
+
+@pytest.mark.parametrize("op", TRI_OPS)
+def test_packed_slot_saving_at_2048(op):
+    """The packed grid's saving in launched grid slots over the full grid,
+    at n = 2048 (traced, not executed)."""
+    dims, want = PACKED_SLOT_RATIO[op]
+    operands = _jops(op, dims)
+    (full,) = pallas_grids(ops.PALLAS_OPS[op], *operands, knob=_knob(),
+                           interpret=True)
+    (packed,) = pallas_grids(ops.PALLAS_OPS[op], *operands,
+                             knob=_knob("tri_packed"), interpret=True)
+    assert round(math.prod(full) / math.prod(packed), 3) == want
 
 
 def test_detri_is_exact():
