@@ -242,6 +242,11 @@ class RuntimeStats:
     #: (:meth:`AdsalaRuntime.count_routed`)
     routed_in_place: int = 0
     routed_materialised: int = 0
+    #: model blocks traced writing their decode cache into the segment's
+    #: stack carried through the layer scan: only their new rows, or their
+    #: whole state at the layer's index (:meth:`AdsalaRuntime.count_cache`)
+    cache_rows_in_place: int = 0
+    cache_written_whole: int = 0
     #: process-global resolve-time backend fallbacks, per
     #: (requested, resolved) pair (from repro.backends.registry) — how often
     #: dispatch silently degraded, e.g. pallas→ref when pallas is absent
@@ -364,6 +369,8 @@ class AdsalaRuntime:
                 journal_absorbed=base.journal_absorbed,
                 routed_in_place=base.routed_in_place,
                 routed_materialised=base.routed_materialised,
+                cache_rows_in_place=base.cache_rows_in_place,
+                cache_written_whole=base.cache_written_whole,
                 backends={n: dataclasses.replace(b)
                           for n, b in base.backends.items()},
                 buckets={k: dataclasses.replace(b)
@@ -401,6 +408,15 @@ class AdsalaRuntime:
                 self._base.routed_in_place += 1
             else:
                 self._base.routed_materialised += 1
+
+    def count_cache(self, rows: bool) -> None:
+        """Count one model block's decode-cache write at trace time: its
+        new rows written into the carried stack, or its whole state."""
+        with self._lock:
+            if rows:
+                self._base.cache_rows_in_place += 1
+            else:
+                self._base.cache_written_whole += 1
 
     def _stripe(self) -> _HitStripe:
         """This thread's hit stripe (registered for aggregation on first

@@ -19,9 +19,9 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from .sharding import ShardingRules, DEFAULT_RULES, constrain
 
-__all__ = ["Ctx", "LayerSlice", "weight_array", "routed_operand",
-           "init_linear", "linear", "routed_matmul", "init_norm",
-           "rmsnorm", "init_embedding", "embed", "rope", "yarn",
+__all__ = ["Ctx", "LayerSlice", "LayerCache", "weight_array",
+           "routed_operand", "init_linear", "linear", "routed_matmul",
+           "init_norm", "rmsnorm", "init_embedding", "embed", "rope", "yarn",
            "yarn_softmax_factor", "init_attention",
            "attention", "init_mlp", "mlp", "cross_entropy",
            "flash_attention"]
@@ -85,6 +85,78 @@ class LayerSlice:
                                             keepdims=False)
 
 
+#: the positions in one row tile of a TPU layout (the second-minor dim of
+#: an (8, 128) tile): :meth:`LayerCache.write` writes a single row as its
+#: whole tile
+ROW_TILE = 8
+
+
+class LayerCache:
+    """Layer ``index`` of a segment's decode cache ``stack``, which the layer
+    scan carries whole: ``{name: (L, B, T, ...), "len": (L,)}``.  A block
+    reads the layer's length with :attr:`len`, writes its new rows with
+    :meth:`write` (one ``dynamic_update_slice`` into each stack, in place),
+    and reads the layer's rows back from the updated view by name, a
+    ``dynamic_index_in_dim`` that XLA can fuse into the consumer."""
+
+    __slots__ = ("stack", "index")
+
+    def __init__(self, stack: dict, index):
+        self.stack, self.index = stack, index
+
+    @property
+    def len(self):
+        return jax.lax.dynamic_index_in_dim(self.stack["len"], self.index,
+                                            keepdims=False)
+
+    def __getitem__(self, name: str):
+        return jax.lax.dynamic_index_in_dim(self.stack[name], self.index,
+                                            keepdims=False)
+
+    def write(self, start, **rows) -> "LayerCache":
+        """The view after writing each leaf's new rows ``(B, S, ...)`` at
+        positions ``start .. start + S`` of this layer, one
+        ``dynamic_update_slice`` into its stack, and setting the layer's
+        length to ``start + S``.
+
+        A single row (a decode step) goes in as the whole row tile that
+        holds it, :data:`ROW_TILE` positions whose other rows are read and
+        written back unchanged.  Written alone, a row makes XLA give the
+        carried stack another layout than the one it arrives in, and the
+        layer loop then copies the whole cache into that layout and back
+        out on every step (a TPU v5e compile of qwen1.5-4b's decode
+        step)."""
+        stack = dict(self.stack)
+        for name, new in rows.items():
+            t = stack[name]
+            new = new.astype(t.dtype)[None]
+            S, T = new.shape[2], t.shape[2]
+            at = start
+            if S == 1 and T >= ROW_TILE:
+                at = jnp.clip(start // ROW_TILE * ROW_TILE, 0, T - ROW_TILE)
+                tile = jax.lax.dynamic_slice(
+                    t, (self.index, 0, at) + (0,) * (t.ndim - 3),
+                    new.shape[:2] + (ROW_TILE,) + new.shape[3:])
+                pos = jnp.arange(ROW_TILE).reshape(
+                    (1, 1, ROW_TILE) + (1,) * (t.ndim - 3))
+                # the row where dynamic_update_slice would put it: clamped
+                # into the cache
+                row = jnp.minimum(start, T - 1) - at
+                new = jnp.where(pos == row, new, tile)
+            stack[name] = jax.lax.dynamic_update_slice(
+                t, new, (self.index, 0, at) + (0,) * (t.ndim - 3))
+        stack["len"] = jax.lax.dynamic_update_index_in_dim(
+            stack["len"], start + S, self.index, 0)
+        return LayerCache(stack, self.index)
+
+
+def runtime_of(ctx: Ctx):
+    """The runtime that serves ``ctx``'s routed calls and counts its
+    trace-time events (``None`` → the process-global runtime)."""
+    from repro.core.runtime import global_runtime
+    return ctx.runtime if ctx.runtime is not None else global_runtime()
+
+
 def weight_array(w):
     """``w`` as an array: a :class:`LayerSlice` sliced out of its stack."""
     return w.array() if isinstance(w, LayerSlice) else w
@@ -95,10 +167,8 @@ def routed_operand(w, ctx: Ctx) -> tuple:
     :class:`LayerSlice` passes its whole stack with ``layer=``.  Counts the
     call on the runtime's ``routed_in_place`` or ``routed_materialised``
     (at trace time, once per traced call site)."""
-    from repro.core.runtime import global_runtime
-    rt = ctx.runtime if ctx.runtime is not None else global_runtime()
     in_place = isinstance(w, LayerSlice)
-    rt.count_routed(in_place)
+    runtime_of(ctx).count_routed(in_place)
     kw = {}
     if ctx.cfg.gemm_interpret is not None:
         kw["interpret"] = ctx.cfg.gemm_interpret
@@ -414,10 +484,11 @@ def init_attention(key, cfg: ModelConfig, *, d_model: int | None = None,
 
 
 def attention(p: dict, x, ctx: Ctx, *, kv_x=None, causal: bool = True,
-              positions=None, cache: dict | None = None,
+              positions=None, cache: LayerCache | None = None,
               use_rope: bool = True):
-    """GQA attention. ``cache`` (decode): {k, v, (B,T,KH,D); len (B,)} —
-    functional update returned alongside the output."""
+    """GQA attention. ``cache`` (decode): a :class:`LayerCache` of ``k``,
+    ``v`` ``(L, B, T, KH, D)`` and ``len`` — the new rows are written into
+    it and the updated view is returned alongside the output."""
     cfg = ctx.cfg
     B, S, _ = x.shape
     hd = cfg.hd()
@@ -437,19 +508,14 @@ def attention(p: dict, x, ctx: Ctx, *, kv_x=None, causal: bool = True,
 
     new_cache = None
     if cache is not None:
-        start = cache["len"]                          # scalar int32
+        start = cache.len                             # scalar int32
         if positions is None:
             positions = start + jnp.arange(S)[None, :]
         if use_rope:
             q = rope(q, positions, theta=cfg.rope_theta)
             k = rope(k, positions, theta=cfg.rope_theta)
-        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"],
-                                                 k.astype(cache["k"].dtype),
-                                                 start, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"],
-                                                 v.astype(cache["v"].dtype),
-                                                 start, axis=1)
-        new_cache = {"k": ck, "v": cv, "len": start + S}
+        new_cache = cache.write(start, k=k, v=v)
+        ck, cv = new_cache["k"], new_cache["v"]
         if S == 1:
             out = _dense_decode_attention(q, ck.astype(q.dtype),
                                           cv.astype(q.dtype), start)

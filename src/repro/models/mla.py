@@ -24,8 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from .layers import Ctx, init_linear, init_norm, linear, rmsnorm, rope, \
-    flash_attention, weight_array, yarn_softmax_factor
+from .layers import Ctx, LayerCache, init_linear, init_norm, linear, \
+    rmsnorm, rope, flash_attention, weight_array, yarn_softmax_factor
 
 __all__ = ["init_mla", "mla_attention", "init_mla_cache"]
 
@@ -63,8 +63,10 @@ def _project_q(p, x, cfg, ctx):
     return q[..., :nope], q[..., nope:]
 
 
-def mla_attention(p: dict, x, ctx: Ctx, *, cache: dict | None = None):
-    """Returns (out, new_cache|None)."""
+def mla_attention(p: dict, x, ctx: Ctx, *,
+                  cache: LayerCache | None = None):
+    """Returns (out, new_cache|None); ``cache`` is a :class:`LayerCache`
+    of ``c_kv``, ``k_rope`` and ``len``."""
     with jax.named_scope("mla_attn"):
         return _mla(p, x, ctx, cache)
 
@@ -107,17 +109,13 @@ def _mla(p: dict, x, ctx: Ctx, cache):
         return out, None
 
     # ---- cached path: update the latent cache, then attend ------------------
-    start = cache["len"]
+    start = cache.len
     positions = start + jnp.arange(S)[None, :]
     q_rope = rope(q_rope, positions, theta=cfg.rope_theta, scaling=ys)
     k_rope_new = rope(k_rope_new[:, :, None, :], positions,
                       theta=cfg.rope_theta, scaling=ys)[:, :, 0]
-    c = jax.lax.dynamic_update_slice_in_dim(
-        cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), start, axis=1)
-    kr = jax.lax.dynamic_update_slice_in_dim(
-        cache["k_rope"], k_rope_new.astype(cache["k_rope"].dtype), start,
-        axis=1)
-    new_cache = {"c_kv": c, "k_rope": kr, "len": start + S}
+    new_cache = cache.write(start, c_kv=c_kv, k_rope=k_rope_new)
+    c, kr = new_cache["c_kv"], new_cache["k_rope"]
 
     if S > 1:
         # prefill: expand latent → per-head K/V, blockwise flash (the

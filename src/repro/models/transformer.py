@@ -3,16 +3,27 @@
 The layer stack is a list of (block_kind, repeat) segments (configs/base.py);
 per-segment params are stacked along a leading layer axis and consumed by
 ``jax.lax.scan`` — HLO size stays O(#segments) regardless of depth, which is
-what keeps 512-device dry-run compiles tractable.  Decode caches are pytrees
-stacked the same way and threaded through the scan as xs/ys.
+what keeps 512-device dry-run compiles tractable.
 
-Where the matmuls are routed (one host, ``use_pallas_gemm``) the scan runs
-over the layer index and the segment's params stay whole, loop-invariant:
-each routed weight reaches its kernel as a :class:`~.layers.LayerSlice`
-and is read in place, since a Pallas call cannot fuse a slice of its
-operand and XLA would otherwise copy every layer's weights out of the stack
-on every forward pass.  Everything else takes its layer with
-``dynamic_index_in_dim``, which XLA fuses into the consumer.
+The scan runs over the layer index.  Decode caches are pytrees stacked the
+same way, and each segment's stack is part of the scan's carry: a block
+gets layer ``i``'s view of it (:class:`~.layers.LayerCache`), writes only
+its new rows into the stack in place and reads the layer back from the
+updated stack, so a step neither slices each layer's cache out nor restacks
+it.  A state that a block replaces whole (mamba2, rwkv6) is sliced out and
+written back at ``i``.  A scan that carries a cache runs flat, whatever the
+config's remat: serving has no backward pass, and under a nested scan XLA
+lays the carried stack out anew and copies the whole cache in and out on
+every call.  Counted at trace time on the runtime: ``cache_rows_in_place``
+and ``cache_written_whole``, one per block traced.
+
+Where the matmuls are routed (one host, ``use_pallas_gemm``) the segment's
+params stay whole, loop-invariant: each routed weight reaches its kernel as
+a :class:`~.layers.LayerSlice` and is read in place, since a Pallas call
+cannot fuse a slice of its operand and XLA would otherwise copy every
+layer's weights out of the stack on every forward pass.  Everything else
+takes its layer with ``dynamic_index_in_dim``, which XLA fuses into the
+consumer.
 
 Block kinds:
   attn        pre-LN GQA attention + MLP            (dense, vlm backbone)
@@ -35,9 +46,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from .layers import (Ctx, LayerSlice, attention, cross_entropy, embed,
-                     init_attention, init_embedding, init_mlp, init_norm,
-                     linear, mlp, rmsnorm, routed_matmul)
+from .layers import (Ctx, LayerCache, LayerSlice, attention, cross_entropy,
+                     embed, init_attention, init_embedding, init_mlp,
+                     init_norm, linear, mlp, rmsnorm, routed_matmul,
+                     runtime_of)
 from .mamba2 import init_mamba2, init_mamba2_state, mamba2_mixer
 from .mla import init_mla, init_mla_cache, mla_attention
 from .moe import init_moe, moe_layer
@@ -272,13 +284,55 @@ def _layer_params(seg_p, i):
         seg_p)
 
 
+def _layer_cache(stack, i):
+    """Layer ``i``'s view of a segment's carried cache: each row-written
+    cache (a dict with a ``len``) a :class:`LayerCache`, each leaf of a
+    state that a block replaces whole sliced out."""
+    if isinstance(stack, dict) and "len" in stack:
+        return LayerCache(stack, i)
+    if isinstance(stack, dict):
+        return {name: _layer_cache(t, i) for name, t in stack.items()}
+    return jax.lax.dynamic_index_in_dim(stack, i, keepdims=False)
+
+
+def _write_layer_cache(stack, new, i, wrote: set):
+    """The carried stack after a block's cache ``new`` (its
+    :func:`_layer_cache` view, updated): a row-written cache is already
+    its stack; a whole state is written back at layer ``i``.  Adds
+    ``"rows"`` or ``"whole"`` to ``wrote``."""
+    if isinstance(new, LayerCache):
+        wrote.add("rows")
+        return new.stack
+    if isinstance(stack, dict):
+        return {name: _write_layer_cache(t, new[name], i, wrote)
+                for name, t in stack.items()}
+    wrote.add("whole")
+    return jax.lax.dynamic_update_index_in_dim(stack, new, i, 0)
+
+
+def _apply_layer(kind: str, p: dict, x, ctx: Ctx, stack, i, **kw):
+    """:func:`_apply_block` on layer ``i`` of a segment whose decode cache
+    ``stack`` (or None) is carried whole; returns (x, new stack, aux,
+    rows) and counts the block's cache writes on the runtime."""
+    cache = _layer_cache(stack, i) if stack is not None else None
+    x, nc, aux, rows = _apply_block(kind, p, x, ctx, cache, **kw)
+    if stack is None:
+        return x, None, aux, rows
+    wrote: set = set()
+    stack = _write_layer_cache(stack, nc, i, wrote)
+    for how in sorted(wrote):
+        runtime_of(ctx).count_cache(rows=how == "rows")
+    return x, stack, aux, rows
+
+
 def _run_segments(params, x, ctx: Ctx, caches=None, *, x0=None,
                   enc_out=None):
     """Scan every segment; returns (x, new_caches|None, aux_sum, rows):
     ``rows`` holds each MoE layer's token-slots per expert, ``(L_moe, E)``
-    int32 over the MoE segments in order, or None without one.  A routed
-    config scans the layer index with the params closed over (module
-    docstring); any other scans the stacked params."""
+    int32 over the MoE segments in order, or None without one.  The scan
+    runs over the layer index and carries the segment's cache stack whole
+    (module docstring).  A routed config closes over the params; any other
+    scans the stacked params too."""
     cfg = ctx.cfg
     in_place = ctx.routes_gemm(x)
     aux_total = jnp.zeros((), jnp.float32)
@@ -290,20 +344,25 @@ def _run_segments(params, x, ctx: Ctx, caches=None, *, x0=None,
         seg_c = caches[si] if caches is not None else None
 
         def body(carry, xs, kind=kind, seg_p=seg_p):
-            h, aux = carry
-            pp, cc = xs
+            h, aux, cc = carry
+            pp, i = xs
             if in_place:
-                pp = _layer_params(seg_p, pp)
-            h, nc, a, r = _apply_block(kind, pp, h, ctx, cc, shared=shared,
-                                       x0=x0, enc_out=enc_out)
+                pp = _layer_params(seg_p, i)
+            h, cc, a, r = _apply_layer(kind, pp, h, ctx, cc, i,
+                                       shared=shared, x0=x0,
+                                       enc_out=enc_out)
             # inter-block activation layout (SP shards seq here) — this is
             # also the layout of the saved scan carries
             h = ctx.cons(h, "batch", "seq", "embed")
-            return (h, aux + a), (nc, r)
+            return (h, aux + a, cc), r
 
-        xs = (jnp.arange(repeat) if in_place else seg_p, seg_c)
-        (x, aux_total), (seg_nc, seg_rows) = _scan_stack(
-            body, (x, aux_total), xs, repeat, cfg)
+        xs = (None if in_place else seg_p, jnp.arange(repeat))
+        carry = (x, aux_total, seg_c)
+        if seg_c is None:
+            carry, seg_rows = _scan_stack(body, carry, xs, repeat, cfg)
+        else:
+            carry, seg_rows = jax.lax.scan(body, carry, xs)  # flat: above
+        x, aux_total, seg_nc = carry
         if caches is not None:
             new_caches.append(seg_nc)
         if seg_rows is not None:

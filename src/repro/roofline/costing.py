@@ -162,13 +162,24 @@ def _compile_cost(fn, args, mesh) -> dict:
 # unit cost
 # ---------------------------------------------------------------------------
 
+def _one_layer(cache):
+    """One layer's cache as the one-layer stack a block's cache view reads
+    (None stays None)."""
+    return None if cache is None else jax.tree.map(lambda t: t[None], cache)
+
+
+def _layer_of(stack):
+    return jax.tree.map(lambda t: t[0], stack)
+
+
 def unit_costs(cfg: ModelConfig, unit: Unit, shape: Shape, mesh,
                rules: ShardingRules, params_abstract,
                caches_abstract) -> dict:
     """Returns {"once": cost, "total": per-execution corrected cost}."""
     from repro.launch.specs import param_specs, cache_specs
-    from repro.models.transformer import (_apply_block, _shared_attn_block)
-    from repro.models.layers import chunked_cross_entropy
+    from repro.models.transformer import (_apply_block, _apply_layer,
+                                          _shared_attn_block)
+    from repro.models.layers import LayerCache, chunked_cross_entropy
 
     B = shape.global_batch
     S = 1 if shape.kind == "decode" else shape.seq_len
@@ -227,7 +238,9 @@ def unit_costs(cfg: ModelConfig, unit: Unit, shape: Shape, mesh,
                 if train:
                     return jax.grad(jax.checkpoint(f), argnums=(0, 1, 2))(
                         sh_v, ip_v, x_v)
-                return _shared_attn_block(sh_v, ip_v, x_v, x_v, ctx, cc_v)
+                h, view = _shared_attn_block(sh_v, ip_v, x_v, x_v, ctx,
+                                             LayerCache(_one_layer(cc_v), 0))
+                return h, _layer_of(view.stack)
 
             args = [shared, in_proj, x] + ([cc] if cc is not None else [])
             return sh_fn, tuple(args)
@@ -276,9 +289,9 @@ def unit_costs(cfg: ModelConfig, unit: Unit, shape: Shape, mesh,
 
             if train:
                 return jax.grad(jax.checkpoint(f), argnums=(0, 1))(pp_v, x_v)
-            h, nc2, _, _ = _apply_block(unit.kind, pp_v, x_v, ctx, cc_v,
-                                        enc_out=enc_v)
-            return (h, nc2) if nc2 is not None else h
+            h, nc2, _, _ = _apply_layer(unit.kind, pp_v, x_v, ctx,
+                                        _one_layer(cc_v), 0, enc_out=enc_v)
+            return (h, _layer_of(nc2)) if nc2 is not None else h
 
         args = [pp, x]
         if cc is not None:

@@ -30,6 +30,7 @@ Contracts:
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -115,23 +116,104 @@ def test_routed_forward_bit_identical(arch, held_experts):
     assert rt.stats.for_backend("pallas").default_calls > 0
 
 
+class _SlicedCache:
+    """The cache a block got before the layer scan carried the stack: one
+    layer's leaves, sliced out of the stack, updated with one
+    ``dynamic_update_slice`` each and restacked by the caller."""
+
+    def __init__(self, layer: dict):
+        self.layer = layer
+
+    @property
+    def len(self):
+        return self.layer["len"]
+
+    def __getitem__(self, name):
+        return self.layer[name]
+
+    def write(self, start, **rows):
+        S = next(iter(rows.values())).shape[1]
+        layer = dict(self.layer, len=start + S)
+        for name, new in rows.items():
+            layer[name] = jax.lax.dynamic_update_slice_in_dim(
+                layer[name], new.astype(layer[name].dtype), start, axis=1)
+        return _SlicedCache(layer)
+
+
+def _layer_by_layer(params, x, caches, ctx):
+    """Every segment's blocks in a Python loop, each layer's params and
+    cache sliced out and the caches restacked: the reference for the
+    carried cache.  Returns (x, new caches)."""
+    new_caches = []
+    for si, (kind, repeat) in enumerate(ctx.cfg.segments()):
+        layers = []
+        for i in range(repeat):
+            at = lambda t: t[i]                             # noqa: E731
+            x, view, _, _ = tf._apply_block(
+                kind, jax.tree.map(at, params["segments"][si]), x, ctx,
+                _SlicedCache(jax.tree.map(at, caches[si])))
+            layers.append(view.layer)
+        new_caches.append(jax.tree.map(lambda *t: jnp.stack(t), *layers))
+    return x, new_caches
+
+
+@partial(jax.jit, static_argnums=3)
+def _reference_prefill(params, batch, caches, cfg):
+    ctx = Ctx(cfg)
+    x, caches = _layer_by_layer(params, tf._embed_inputs(params, batch, ctx),
+                                caches, ctx)
+    return tf._logits(params, x[:, -1:], ctx), caches
+
+
+@partial(jax.jit, static_argnums=3)
+def _reference_decode_step(params, token, caches, cfg):
+    ctx = Ctx(cfg)
+    x, caches = _layer_by_layer(params, tf.embed(params["embed"], token, ctx),
+                                caches, ctx)
+    return tf._logits(params, x, ctx), caches
+
+
+def _assert_same_caches(got, want):
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert jnp.array_equal(g, w)
+
+
+@pytest.mark.parametrize("S,T", [(16, 20), (13, 28)],
+                         ids=["last-tile", "mid-tile"])
 @pytest.mark.parametrize("arch", ARCHS)
-def test_routed_prefill_decode_bit_identical(arch, held_experts):
+def test_routed_prefill_decode_bit_identical(arch, S, T, held_experts):
+    """Routed and unrouted, the scan that carries each segment's cache gives
+    the logits and every cache leaf, bit for bit, of the blocks run layer by
+    layer on caches sliced out and restacked: after a prefill of ``S``
+    tokens and after each of two decode steps from that nonzero length,
+    whose rows land in the cache's last row tile (shifted back to fit the
+    ``T`` positions) or inside a tile."""
     cfg = _cfg(arch)
     rcfg = dataclasses.replace(cfg, use_pallas_gemm=True)
     params = tf.init_params(jax.random.PRNGKey(0), cfg)
-    B, S = 2, 16
+    B = 2
     batch = _batch(cfg, B, S)
-    lu, cu = tf.prefill(params, batch, tf.init_decode_state(cfg, B, S + 4),
-                        cfg)
+    lu, cu = tf.prefill(params, batch, tf.init_decode_state(cfg, B, T), cfg)
     rt = AdsalaRuntime()
-    lr, cr = tf.prefill(params, batch, tf.init_decode_state(rcfg, B, S + 4),
+    lr, cr = tf.prefill(params, batch, tf.init_decode_state(rcfg, B, T),
                         rcfg, runtime=rt)
-    assert jnp.array_equal(lu, lr)
+    lw, cw = _reference_prefill(params, batch, tf.init_decode_state(cfg, B, T),
+                                cfg)
+    assert jnp.array_equal(lu, lr) and jnp.array_equal(lu, lw)
+    _assert_same_caches(cu, cw)
+    _assert_same_caches(cr, cw)
     tok = jnp.argmax(lu[:, -1:], -1).astype(jnp.int32)
-    du, _ = tf.decode_step(params, tok, cu, cfg)
-    dr, _ = tf.decode_step(params, tok, cr, rcfg, runtime=rt)
-    assert jnp.array_equal(du, dr)
+    for _ in range(2):
+        du, cu = tf.decode_step(params, tok, cu, cfg)
+        dr, cr = tf.decode_step(params, tok, cr, rcfg, runtime=rt)
+        dw, cw = _reference_decode_step(params, tok, cw, cfg)
+        assert jnp.array_equal(du, dr) and jnp.array_equal(du, dw)
+        _assert_same_caches(cu, cw)
+        _assert_same_caches(cr, cw)
+        tok = jnp.argmax(du[:, -1:], -1).astype(jnp.int32)
+    assert int(cw[0]["len"][0]) == S + 2
 
 
 def _expert_gemm_keys():
@@ -411,6 +493,68 @@ def test_routed_decode_reads_layer_weights_in_place():
     assert len(cfg.segments()) == 1 and not cfg.tie_embeddings
     stats = _decode_trace(cfg).stats
     assert (stats.routed_in_place, stats.routed_materialised) == (7, 1)
+
+
+def test_decode_counts_cache_writes():
+    """One trace of qwen's decode step: its one block writes its rows into
+    the carried stack and writes no state back whole; rwkv6's blocks write
+    their whole state back."""
+    cfg = dataclasses.replace(_cfg("qwen15_4b"), use_pallas_gemm=True)
+    stats = _decode_trace(cfg).stats
+    assert (stats.cache_rows_in_place, stats.cache_written_whole) == (1, 0)
+    stats = _decode_trace(get_smoke_config("rwkv6_1p6b")).stats
+    assert stats.cache_rows_in_place == 0 and stats.cache_written_whole > 0
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("routed", [False, True], ids=["unrouted", "routed"])
+@pytest.mark.parametrize("layers", [3, 8], ids=["flat", "nested-remat"])
+def test_decode_step_carries_the_cache(routed, layers):
+    """qwen's decode-step jaxpr: no scan takes a cache leaf in ``xs`` or
+    returns one in ``ys``, and every ``dynamic_update_slice`` into a k or v
+    stack writes one row tile of the new row, ``(1, B, ROW_TILE, KH, D)``,
+    never a layer.  With eight layers the config's nested remat would
+    regroup the scan."""
+    from repro.models.layers import ROW_TILE
+    cfg = dataclasses.replace(_cfg("qwen15_4b"), use_pallas_gemm=routed,
+                              n_layers=layers, remat="nested")
+    B, T = 2, 20
+    params = jax.eval_shape(lambda: tf.init_params(jax.random.PRNGKey(0),
+                                                   cfg))
+    caches = jax.eval_shape(lambda: tf.init_decode_state(cfg, B, T))
+    stack = caches[0]["k"].shape
+    assert stack == (layers, B, T, cfg.kv_heads, cfg.hd())
+    jaxpr = jax.make_jaxpr(
+        lambda p, t, c: tf.decode_step(p, t, c, cfg,
+                                       runtime=AdsalaRuntime()))(
+        params, jax.ShapeDtypeStruct((B, 1), jnp.int32), caches).jaxpr
+    writes, scans = [], 0
+    for eqn in _eqns(jaxpr):
+        if eqn.primitive.name == "scan":
+            n = eqn.params["num_consts"] + eqn.params["num_carry"]
+            sliced = (eqn.invars[n:]
+                      + eqn.outvars[eqn.params["num_carry"]:])
+            assert not [v for v in sliced
+                        if v.aval.shape[-4:] == stack[1:]], eqn
+            scans += 1
+        if eqn.primitive.name == "dynamic_update_slice":
+            operand, update = (v.aval.shape for v in eqn.invars[:2])
+            assert operand != stack[1:]
+            if operand == stack:
+                writes.append(update)
+    assert scans >= 1
+    assert writes == [(1, B, ROW_TILE) + stack[3:]] * 2
 
 
 @pytest.mark.parametrize("where", ["mesh", "unrouted"])

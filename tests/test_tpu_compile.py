@@ -6,7 +6,8 @@ that need more VMEM than a core has, programs that do not fit HBM).  These
 tests compile the Pallas kernels of the main path at real widths, and
 whole routed programs at full width (qwen1.5-4b's prefill and decode step,
 DeepSeek-V2-Lite's first pipeline stage's prefill), for ``v5e:2x2``'s
-first chip.  Nothing executes.
+first chip, and read what the compiler made of the weights and the decode
+cache.  Nothing executes.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -269,6 +270,19 @@ def test_routed_weights_are_read_in_place_on_v5e(program, request):
         assert _ops_with_output(hlo, shape) <= {"parameter",
                                                  "get-tuple-element"}, shape
     assert compiled.memory_analysis().temp_size_in_bytes <= sliced_temp
+
+
+def test_decode_cache_is_written_in_place_on_v5e(qwen15_4b_decode):
+    """The decode step's k and v stacks, carried through the layer loop,
+    are only ever a parameter, a loop value or the in-place write of the
+    new row's tile: no op copies the whole cache (into the layout a loop
+    would prefer, and back out), and the temporaries hold less than one
+    layer's keys."""
+    compiled = qwen15_4b_decode
+    assert _ops_with_output(compiled.as_text(), "40,4,1096,20,128") == {
+        "parameter", "get-tuple-element", "dynamic-update-slice"}
+    layer_keys = 4 * 1096 * 20 * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_keys
 
 
 @pytest.mark.parametrize("m,k,n,bk", [(4, 10944, 2048, 512),
